@@ -43,6 +43,7 @@ import time
 
 import numpy as np
 
+from repro.core.engine import cpu_children
 from repro.core.simulator import (simulate_conventional_many,
                                   simulate_dataflow_many,
                                   simulate_processor,
@@ -211,8 +212,10 @@ def run_all(*, full: bool = True, jobs: int | None = None,
                                else 1) * _MACHINE_WEIGHT[t[1]])
     sims: dict[tuple, object] = {}
     task_s: dict[str, float] = {}
-    pool = (multiprocessing.get_context("spawn").Pool(jobs)
-            if jobs > 1 else None)
+    pool = None
+    if jobs > 1:
+        with cpu_children():
+            pool = multiprocessing.get_context("spawn").Pool(jobs)
     try:
         results = (pool.imap_unordered(_sim_task, tasks) if pool
                    else map(_sim_task, tasks))

@@ -52,6 +52,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     # sections are the leading non-flag arguments; everything from the
@@ -64,6 +66,7 @@ def main() -> None:
         sections.append(a)
     sections = sections or ["fig2", "fig5", "table2", "kernels",
                             "roofline"]
+    enable_compile_cache()
 
     if "fig2" in sections:
         print("=" * 72)

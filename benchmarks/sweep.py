@@ -32,6 +32,7 @@ import time
 
 import numpy as np
 
+from repro.core.engine import cpu_children
 from repro.core.simulator import (MemAccess, SimStage, acp,
                                   simulate_conventional, simulate_dataflow,
                                   standard_memory_models)
@@ -353,8 +354,10 @@ def run_sweep(*, smoke: bool = False, jobs: int | None = None,
         jobs = 1 if smoke else min(2, multiprocessing.cpu_count())
     rows: list[dict] = []
     t0 = time.perf_counter()
-    pool = (multiprocessing.get_context("spawn").Pool(jobs)
-            if jobs > 1 else None)
+    pool = None
+    if jobs > 1:
+        with cpu_children():
+            pool = multiprocessing.get_context("spawn").Pool(jobs)
     try:
         parts = (pool.imap_unordered(_sweep_task, tasks) if pool
                  else map(_sweep_task, tasks))

@@ -15,8 +15,7 @@ from .decouple import (DecoupledProgram, decouple, decoupled_call,
                        run_stages_sequential)
 from .channels import ChannelSpec, DeviceFIFO, FIFOState, HostFIFO
 from .pipeline import (SystolicPipeline, pipeline_apply,
-                       pipeline_apply_emulated, gpipe_bubble_fraction,
-                       shard_map_compat)
+                       pipeline_apply_emulated, gpipe_bubble_fraction)
 from . import simulator
 
 __all__ = [
@@ -31,6 +30,6 @@ __all__ = [
     "run_stages_sequential",
     "ChannelSpec", "DeviceFIFO", "FIFOState", "HostFIFO",
     "SystolicPipeline", "pipeline_apply", "pipeline_apply_emulated",
-    "gpipe_bubble_fraction", "shard_map_compat",
+    "gpipe_bubble_fraction",
     "simulator",
 ]

@@ -131,8 +131,6 @@ def _worker_main(payload_bytes: bytes, task_q, result_q) -> None:
         from .simulator import _SharedResolver, _lat_itemsize
         _rc.configure(**p["rescache_cfg"])
         _rc.CHUNK_ITERS = p["C"]
-        if p.get("engine"):  # master's backend, not the worker's env
-            _eng.select(p["engine"])
         resolver = _SharedResolver(p["stages"], p["mems"], p["seed"],
                                    capture=p["capture"])
         writers = {mn: _rc.ChunkWriter(
@@ -307,7 +305,6 @@ def simulate_dataflow_sharded(
             "C": C,
             "capture": bool(plan.writers),
             "keys": {mn: plan.keys[mn] for mn in plan.writers},
-            "engine": _eng.current(),
             "effect_keys": effect_keys,
             "rescache_cfg": {
                 "enabled": _rc._cfg.enabled,
@@ -328,8 +325,9 @@ def simulate_dataflow_sharded(
                          args=(payload, task_qs[w], result_q),
                          daemon=True)
              for w in range(W)]
-    for pr in procs:
-        pr.start()
+    with _eng.cpu_children():
+        for pr in procs:
+            pr.start()
 
     #: chunk -> worker; seeded round-robin, rewritten when a dead
     #: worker's in-flight chunks are re-dispatched
@@ -562,7 +560,8 @@ def simulate_dataflow_sharded(
                         target=_worker_main,
                         args=(payload, task_qs[w], result_q),
                         daemon=True)
-                    procs[w].start()
+                    with _eng.cpu_children():
+                        procs[w].start()
                 for k in sorted(redo):
                     w = owner_of[k]
                     task_qs[w].put(

@@ -15,7 +15,8 @@ tiny selection layer:
   ``simulate_*`` entry points), :func:`select` process-wide;
 * explicit ``jax`` uses the jitted kernels even on CPU — they are
   bit-identical by construction (integer max/compare only, no floats),
-  which is what the CI ``REPRO_ENGINE=jax`` lane asserts.
+  which is what the CI ``REPRO_ENGINE=jax`` lane asserts.  An explicit
+  ``jax`` on a host where jax does not import raises.
 
 Every kernel here is exact integer arithmetic; backends may only differ
 in wall clock, never in results.  Sizes below the ``JIT_MIN_*``
@@ -26,13 +27,16 @@ calls — but an explicit selection is honoured as asked.
 The module also owns the per-phase wall-clock accounting
 (:func:`phase` / :func:`walls`) that the ``worker_scaling`` benchmark
 probe and the chunk-graph master use to attribute time to the
-effect / replay / fold / solve phases across process boundaries.
+effect / replay / fold / solve phases across process boundaries, and
+the per-kernel device dispatch counts (:func:`dispatches`), keyed by
+the platform each result came back from.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
+import threading
 import time
 
 import numpy as np
@@ -40,6 +44,7 @@ import numpy as np
 __all__ = [
     "current", "select", "use", "jax_modules",
     "phase", "walls", "reset_walls", "merge_walls",
+    "dispatches", "reset_dispatches", "CHILD_ENV", "cpu_children",
     "running_max", "nway_core", "lru_insert", "stack_compose",
 ]
 
@@ -97,8 +102,7 @@ def _x64():
     errors in the model stack), so the engine enables it around
     exactly its own traces and calls — jit caches key on the flag, so
     scoped-x64 traces never collide with the host program's."""
-    from jax.experimental import enable_x64
-    return enable_x64()
+    return jax_modules()[0].enable_x64(True)
 
 
 def current() -> str:
@@ -108,7 +112,7 @@ def current() -> str:
     then ``auto`` — which picks jax only when jax imports *and* its
     default backend is an accelerator (on CPU the serial numpy scans
     beat XLA's log-depth ones; see docs/engine.md for the measurement).
-    A jax selection without an importable jax degrades to numpy.
+    An explicit jax selection without an importable jax raises.
     """
     choice = _forced or _env_choice()
     if choice == "auto":
@@ -117,8 +121,35 @@ def current() -> str:
             return "jax"
         return "numpy"
     if choice == "jax" and jax_modules() is None:
-        return "numpy"
+        raise RuntimeError("engine 'jax' selected but jax does not import")
     return choice
+
+
+#: the environment of every process the simulator starts (the chunk-
+#: graph pool, the daemon and its workers): the parent may hold the
+#: chip, and a chip belongs to one process, so children resolve on CPU
+#: with numpy whatever the parent runs
+CHILD_ENV = {"JAX_PLATFORMS": "cpu", "REPRO_ENGINE": "numpy"}
+
+_child_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def cpu_children():
+    """Scope in which ``multiprocessing`` spawns (which copy this
+    process's environment) start their children with :data:`CHILD_ENV`;
+    the parent's environment is restored on exit."""
+    with _child_lock:
+        saved = {k: os.environ.get(k) for k in CHILD_ENV}
+        os.environ.update(CHILD_ENV)
+        try:
+            yield
+        finally:
+            for k, v in saved.items():
+                if v is None:
+                    os.environ.pop(k, None)
+                else:
+                    os.environ[k] = v
 
 
 def _explicit() -> bool:
@@ -190,6 +221,26 @@ def merge_walls(other: dict[str, float] | None) -> None:
         _WALLS[k] = _WALLS.get(k, 0.0) + float(v)
 
 
+#: ``"kernel@platform"`` -> device dispatches in this process; the
+#: platform is read off each result, so a run that claims the chip can
+#: show that its kernels came back from it
+_DISPATCH: dict[str, int] = {}
+
+
+def _count(name: str, out) -> None:
+    plat = next(iter(out.devices())).platform
+    key = f"{name}@{plat}"
+    _DISPATCH[key] = _DISPATCH.get(key, 0) + 1
+
+
+def dispatches() -> dict[str, int]:
+    return dict(_DISPATCH)
+
+
+def reset_dispatches() -> None:
+    _DISPATCH.clear()
+
+
 # ---------------------------------------------------------------------------
 # Running max (the wavefront solver's serial recurrence)
 # ---------------------------------------------------------------------------
@@ -244,23 +295,25 @@ def _running_max_np(a: np.ndarray) -> np.ndarray:
 def running_max(a: np.ndarray) -> np.ndarray:
     """In-place inclusive running maximum of a 1-D integer array.
 
-    Dispatches to the jitted ``lax.cummax`` on the jax engine (above
-    the dispatch threshold) and to the dominated-block numpy form
-    otherwise; both are exact, so results never depend on the engine.
+    On the jax engine (above the dispatch threshold) an int32 array on
+    a TPU goes to :func:`pallas_running_max`, and everything else (int64,
+    or another backend) to the jitted ``lax.cummax``; the choice follows
+    the dtype and the backend, never a caught error.  Below the
+    threshold, and on the numpy engine, the dominated-block numpy form
+    runs.  All are exact, so results never depend on the engine.
     """
     if a.size >= JIT_MIN_ELEMS and current() == "jax":
         jx, jnp, lax = jax_modules()
-        if jx.default_backend() != "cpu":
-            try:
-                a[:] = pallas_running_max(a)
-                return a
-            except Exception:
-                pass  # lowering gap on this backend: XLA scan below
+        if a.dtype == np.int32 and jx.default_backend() == "tpu":
+            a[:] = pallas_running_max(a)
+            return a
         global _cummax_jit
         if _cummax_jit is None:
             _cummax_jit = jx.jit(lambda x: lax.cummax(x, axis=0))
         with _x64():
-            a[:] = np.asarray(_cummax_jit(a))
+            out = _cummax_jit(a)
+            _count("cummax", out)
+            a[:] = np.asarray(out)
         return a
     return _running_max_np(a)
 
@@ -445,7 +498,8 @@ def _nway_core_jax(T, seg_grp, seg_first, carried, max_run):
         cp = carried
     with _x64():
         HIT, stk = _nway_jit(Tp, sg, sf, cp, max_run)
-    return np.asarray(HIT)[:, :G], np.asarray(stk)[:G]
+        _count("nway", HIT)
+        return np.asarray(HIT)[:, :G], np.asarray(stk)[:G]
 
 
 def nway_core(T: np.ndarray, seg_grp: np.ndarray, seg_first: np.ndarray,
@@ -471,52 +525,97 @@ def nway_core(T: np.ndarray, seg_grp: np.ndarray, seg_first: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Pallas (GPU/TPU only; the CPU path never reaches this)
+# Pallas running max (TPU; the CPU path never reaches this)
 # ---------------------------------------------------------------------------
 
-def pallas_running_max(x, block: int = 1024, interpret: bool = False):
-    """Blocked inclusive running max as a Pallas grid kernel.
+#: lanes of one vreg row; blocks are (rows, 128) int32, rows % 8 == 0
+_LANES = 128
 
-    Grid steps execute in order on TPU (and per-core on GPU), so the
-    carry — the running max of all earlier blocks — lives in a one-cell
-    scratch accumulator; each step scans its block with an associative
-    scan and folds the carry in.  This is the monoid-scan shape the
-    whole engine is built on, lowered to the accelerator the paper
-    targets.  ``interpret=True`` runs the kernel on CPU for tests.
+#: rows per grid step: 512 x 128 int32 = 256 KiB per block
+_RMAX_ROWS = 512
+
+_pallas_rmax = {}
+
+
+def _build_pallas_rmax(rows: int, nb: int, interpret: bool):
+    """The jitted Pallas running max over ``nb`` blocks of ``(rows, 128)``
+    int32 values in row-major order.
+
+    Each grid step scans its block in two Hillis–Steele passes —
+    across the 128 lanes of every row, then across the block's rows of
+    row totals — with ``pltpu.roll`` shifts masked by an iota, and folds
+    in the carry: the running max of all earlier blocks, kept in a VMEM
+    scratch tile.  Grid steps run in order ("arbitrary"), so the carry
+    is exact.
     """
     jx, jnp, lax = jax_modules()
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n = x.shape[0]
-    nb = -(-n // block)
+    lo = np.iinfo(np.int32).min
+    shape = (rows, _LANES)
+
+    def prefix(x, axis: int, width: int):
+        idx = lax.broadcasted_iota(jnp.int32, shape, axis)
+        k = 1
+        while k < width:
+            x = jnp.maximum(
+                x, jnp.where(idx >= k, pltpu.roll(x, k, axis), lo))
+            k *= 2
+        return x
 
     def kernel(x_ref, o_ref, carry_ref):
-        i = pl.program_id(0)
-        scanned = lax.associative_scan(jnp.maximum, x_ref[...])
-
-        @pl.when(i == 0)
+        @pl.when(pl.program_id(0) == 0)
         def _seed():
-            o_ref[...] = scanned
-            carry_ref[0] = scanned[-1]
+            carry_ref[...] = jnp.full(carry_ref.shape, lo, jnp.int32)
 
-        @pl.when(i != 0)
-        def _fold():
-            out = jnp.maximum(scanned, carry_ref[0])
-            o_ref[...] = out
-            carry_ref[0] = out[-1]
+        x = prefix(x_ref[...], 1, _LANES)
+        tot = prefix(jnp.broadcast_to(
+            jnp.max(x, axis=1, keepdims=True), shape), 0, rows)
+        row = lax.broadcasted_iota(jnp.int32, shape, 0)
+        before = jnp.where(row >= 1, pltpu.roll(tot, 1, 0), lo)
+        out = jnp.maximum(jnp.maximum(x, before), carry_ref[0:1, :])
+        o_ref[...] = out
+        carry_ref[...] = jnp.broadcast_to(
+            jnp.max(out[rows - 1:rows, :], axis=1, keepdims=True),
+            carry_ref.shape)
 
-    with _x64():
-        # padding blocks run after every real one, so their carry
-        # never reaches a kept output — any fill value works
-        xp = jnp.pad(jnp.asarray(x), (0, nb * block - n))
-        out = pl.pallas_call(
-            kernel,
-            grid=(nb,),
-            in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-            out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-            out_shape=jx.ShapeDtypeStruct((nb * block,), x.dtype),
-            scratch_shapes=[pltpu.SMEM((1,), x.dtype)],
-            interpret=interpret,
-        )(xp)
-        return np.asarray(out[:n])
+    call = pl.pallas_call(
+        kernel,
+        grid=(nb,),
+        in_specs=[pl.BlockSpec(shape, lambda i: (i, 0))],
+        out_specs=pl.BlockSpec(shape, lambda i: (i, 0)),
+        out_shape=jx.ShapeDtypeStruct((nb * rows, _LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((8, _LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )
+    return jx.jit(call)
+
+
+def pallas_running_max(x: np.ndarray, *, block_rows: int = _RMAX_ROWS,
+                       interpret: bool = False) -> np.ndarray:
+    """Inclusive running max of a 1-D int32 array as a Pallas grid kernel.
+
+    The array is padded with the int32 minimum to a power-of-two number
+    of ``(rows, 128)`` blocks (bounding recompiles) and scanned on the
+    device.  int32 is the kernel's only dtype: the wavefront solver
+    hands over chunk-relative values, which fit int32 whenever the
+    chunk's range allows (``_LaneSolver``).  ``interpret=True`` runs the
+    kernel on CPU for tests."""
+    if x.dtype != np.int32:
+        raise TypeError(f"pallas_running_max takes int32, got {x.dtype}")
+    n = x.shape[0]
+    need = -(-max(n, 1) // _LANES)
+    rows = min(block_rows, -(-need // 8) * 8)
+    nb = _pow2(-(-need // rows), 1)
+    key = (rows, nb, interpret)
+    fn = _pallas_rmax.get(key)
+    if fn is None:
+        fn = _pallas_rmax[key] = _build_pallas_rmax(rows, nb, interpret)
+    xp = np.full(nb * rows * _LANES, np.iinfo(np.int32).min, np.int32)
+    xp[:n] = x
+    out = fn(xp.reshape(nb * rows, _LANES))
+    _count("pallas_running_max", out)
+    return np.asarray(out).reshape(-1)[:n]

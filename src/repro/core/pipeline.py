@@ -29,24 +29,21 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from .channels import ChannelSpec
 from .decouple import DecoupledProgram
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map exists from ~0.6; older releases ship it in
-    jax.experimental with check_rep instead of check_vma."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+def _auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with ``Auto`` axis types.
 
-
-_shard_map = shard_map_compat
+    The executors are written in shard_map's per-device style and leave
+    placement outside the map to XLA.  Meshes from ``jax.make_mesh``
+    default to ``Explicit`` axes, whose sharding types would ride on the
+    replicated result and make ``jax.grad`` of a loss over it fail
+    outside a ``jax.set_mesh`` context."""
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 # ---------------------------------------------------------------------------
@@ -303,10 +300,10 @@ class SystolicPipeline:
                 return out_buf
 
             const_flat, const_treedef = jax.tree_util.tree_flatten(const_args)
-            shard = _shard_map(
-                per_device, mesh=mesh,
+            shard = jax.shard_map(
+                per_device, mesh=_auto_axes(mesh),
                 in_specs=(P(),) * (1 + len(const_flat)),
-                out_specs=P())
+                out_specs=P(), check_vma=False)
             return shard(tuple(stream), *const_flat)
 
         return run
@@ -364,10 +361,10 @@ def pipeline_apply(
             axis)
         return out_buf
 
-    return _shard_map(
-        per_device, mesh=mesh,
+    return jax.shard_map(
+        per_device, mesh=_auto_axes(mesh),
         in_specs=(P(axis), P()),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )(stage_params, microbatches)
 
 

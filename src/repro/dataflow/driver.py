@@ -14,7 +14,6 @@ options is a cache hit returning the *same* ``Compiled`` object.
 from __future__ import annotations
 
 import inspect
-import logging
 from typing import Any, Callable, Sequence
 
 import jax
@@ -320,15 +319,11 @@ def _abstract_key(args: tuple) -> tuple:
         (tuple(np.shape(x)), str(jnp.result_type(x))) for x in flat)
 
 
-_log = logging.getLogger("repro.dataflow")
-
-
 def dataflow_jit(
     fn: Callable | None = None,
     *,
     options: CompileOptions | None = None,
     pipeline: PassPipeline | None = None,
-    on_error: str = "raise",
     **option_kwargs: Any,
 ) -> Callable:
     """Decorator form of :func:`compile`: traces lazily on first call (per
@@ -346,15 +341,7 @@ def dataflow_jit(
     Keyword arguments to the wrapped function are bound to positional form
     via its signature (``backend`` is reserved for dispatch — pass a
     same-named function parameter positionally).
-
-    ``on_error="fallback"`` degrades gracefully: if the analysis pipeline
-    fails on some input shape, the call logs a warning and runs plain
-    ``jax.jit(fn)`` instead (``lower`` still raises, so the failure stays
-    inspectable).
     """
-    if on_error not in ("raise", "fallback"):
-        raise ValueError(f"on_error must be 'raise' or 'fallback', "
-                         f"got {on_error!r}")
     if options is None:
         opts = CompileOptions(**option_kwargs)
     elif option_kwargs:
@@ -363,10 +350,8 @@ def dataflow_jit(
         opts = options
 
     def wrap(f: Callable) -> Callable:
-        by_shape: dict[tuple, Compiled | None] = {}
-        errors: dict[tuple, Exception] = {}
+        by_shape: dict[tuple, Compiled] = {}
         state: dict[str, Any] = {}
-        _unset = object()
 
         def bind(args: tuple, kwargs: dict) -> tuple:
             if not kwargs:
@@ -388,33 +373,7 @@ def dataflow_jit(
         def wrapper(*args: Any, backend: str | None = None,
                     **kwargs: Any) -> Any:
             args = bind(args, kwargs)
-            key = _abstract_key(args)
-            compiled = by_shape.get(key, _unset)
-            if compiled is _unset:
-                try:
-                    compiled = compile(f, *args, options=opts,
-                                       pipeline=pipeline)
-                except Exception as e:
-                    if on_error != "fallback":
-                        raise
-                    _log.warning(
-                        "dataflow analysis of %s failed; falling back to "
-                        "jax.jit", getattr(f, "__name__", f), exc_info=True)
-                    compiled = None
-                    errors[key] = e
-                by_shape[key] = compiled
-            if compiled is None:  # analysis failed earlier; fused fallback
-                if backend is not None:
-                    # an explicit backend request can't be silently
-                    # rerouted to fused execution
-                    raise RuntimeError(
-                        f"dataflow analysis failed for this input shape; "
-                        f"cannot honor backend={backend!r}"
-                    ) from errors.get(key)
-                if "jit" not in state:
-                    state["jit"] = jax.jit(f)
-                return state["jit"](*args)
-            return compiled(*args, backend=backend)
+            return lower(*args)(*args, backend=backend)
 
         wrapper.__name__ = getattr(f, "__name__", "dataflow_jit")
         wrapper.__doc__ = getattr(f, "__doc__", None)

@@ -2,7 +2,7 @@
 
 Each kernel follows the package contract: <name>.py holds the
 ``pl.pallas_call`` + BlockSpec implementation, ``ops.py`` the jit'd public
-wrapper (padding, GQA plumbing, interpret fallback off-TPU), ``ref.py`` the
+wrapper (padding, GQA plumbing, interpret mode on CPU), ``ref.py`` the
 pure-jnp oracle used by the allclose test sweeps.
 """
 

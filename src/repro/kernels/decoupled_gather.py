@@ -4,17 +4,22 @@ Where ``dataflow_matmul`` relies on Pallas's automatic grid pipelining,
 this kernel writes the three template roles out by hand, one per §II
 concept:
 
-* **access stage**: at grid step *i* the kernel *issues* the async HBM→VMEM
-  copy for row ``idx[i+1]`` (the paper's memory stage running ahead,
-  "multiple outstanding requests pipelined into the memory subsystem");
+* **access stage**: at grid step *g* the kernel *issues* the async
+  HBM→VMEM copies for the rows of group ``g+1`` (the paper's memory stage
+  running ahead, "multiple outstanding requests pipelined into the memory
+  subsystem");
 * **FIFO channel**: a 2-slot VMEM ring buffer + per-slot DMA semaphores —
   the bounded BRAM queue between the stages (depth 2 = double buffering);
 * **execute stage**: waits on *this* slot's semaphore and runs the compute
-  on the resident row while the next row is in flight.
+  on the resident rows while the next group is in flight.
 
-The gather row index comes from a scalar-prefetched index array (SMEM), so
-the address stream is available ahead of the data stream — exactly the
-paper's SpMV structure (index array drives the value fetch).
+Rows move in groups of one sublane tile (8 rows of 32-bit data), so each
+grid step writes one whole ``(8, D)`` output tile.  A one-row DMA must
+slice an untiled dimension on both sides, so the table is viewed as
+``(R, 1, D)`` and the ring as ``(2, 8, 1, D)``.  The gather row index
+comes from a scalar-prefetched index array (SMEM), so the address stream
+is available ahead of the data stream — exactly the paper's SpMV
+structure (index array drives the value fetch).
 
 ``fn`` is the per-row compute; the default (tanh scale) stands in for any
 long-latency stage.
@@ -29,40 +34,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUMemorySpace -> MemorySpace around 0.5; support both.
-_ANY = getattr(pltpu, "ANY", None)
-if _ANY is None:  # pragma: no cover - newer jax
-    _ANY = pltpu.MemorySpace.ANY
 
-
-def _make_kernel(fn):
+def _make_kernel(fn, rows: int):
     def kernel(idx_ref, table_ref, o_ref, buf_ref, sem_ref):
-        i = pl.program_id(0)
-        n = pl.num_programs(0)
-        slot = i % 2
-        nxt = (i + 1) % 2
+        g = pl.program_id(0)
+        slot = g % 2
 
-        # prime the pipeline: first row's DMA issued at step 0
-        @pl.when(i == 0)
+        def copies(step, s):
+            return [pltpu.make_async_copy(
+                table_ref.at[idx_ref[step * rows + r]], buf_ref.at[s, r],
+                sem_ref.at[s]) for r in range(rows)]
+
+        # prime the pipeline: the first group's DMAs issued at step 0
+        @pl.when(g == 0)
         def _prime():
-            pltpu.make_async_copy(
-                table_ref.at[idx_ref[0]], buf_ref.at[0],
-                sem_ref.at[0]).start()
+            for c in copies(0, 0):
+                c.start()
 
-        # ACCESS stage: issue next row's DMA (runs ahead of compute)
-        @pl.when(i + 1 < n)
+        # ACCESS stage: issue the next group's DMAs (runs ahead of compute)
+        @pl.when(g + 1 < pl.num_programs(0))
         def _prefetch():
-            pltpu.make_async_copy(
-                table_ref.at[idx_ref[i + 1]], buf_ref.at[nxt],
-                sem_ref.at[nxt]).start()
+            for c in copies(g + 1, 1 - slot):
+                c.start()
 
         # FIFO pop: wait for this slot's data
-        pltpu.make_async_copy(
-            table_ref.at[idx_ref[i]], buf_ref.at[slot],
-            sem_ref.at[slot]).wait()
+        for c in copies(g, slot):
+            c.wait()
 
         # EXECUTE stage
-        o_ref[...] = fn(buf_ref[slot])[None, :]
+        for r in range(rows):
+            o_ref[pl.ds(r, 1), :] = jax.vmap(fn)(buf_ref[slot, r])
 
     return kernel
 
@@ -77,36 +78,38 @@ def decoupled_gather(
 ) -> jax.Array:
     """out[i] = fn(table[idx[i]]) with explicit access/execute decoupling."""
     if fn is None:
-        fn = lambda row: jnp.tanh(row * 2.0)
+        fn = _default_row_fn
     N = idx.shape[0]
     D = table.shape[1]
-    return pl.pallas_call(
-        _make_kernel(fn),
+    rows = 8 * 4 // table.dtype.itemsize  # one sublane tile of rows
+    Np = -(-N // rows) * rows
+    idx = jnp.pad(idx.astype(jnp.int32), (0, Np - N))
+    out = pl.pallas_call(
+        _make_kernel(fn, rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(N,),
-            in_specs=[pl.BlockSpec(memory_space=_ANY)],
-            out_specs=pl.BlockSpec((1, D), lambda i, idx: (i, 0)),
+            grid=(Np // rows,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((rows, D), lambda g, idx: (g, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, D), table.dtype),      # the 2-slot FIFO
-                pltpu.SemaphoreType.DMA((2,)),         # per-slot tokens
+                pltpu.VMEM((2, rows, 1, D), table.dtype),  # 2-slot FIFO
+                pltpu.SemaphoreType.DMA((2,)),           # per-slot tokens
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((N, D), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((Np, D), table.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), table)
+    )(idx, table.reshape(table.shape[0], 1, D))
+    return out[:N]
+
+
+def _default_row_fn(row):
+    return jnp.tanh(row * 2.0)
 
 
 def decoupled_gather_ref(idx: jax.Array, table: jax.Array,
                          fn=None) -> jax.Array:
     """Pure-jnp oracle."""
-    if fn is None:
-        fn = lambda row: jnp.tanh(row * 2.0)
-    return jax.vmap(fn)(table[idx])
-
-
-def _default_row_fn(row):
-    return jnp.tanh(row * 2.0)
+    return jax.vmap(fn or _default_row_fn)(table[idx])
 
 
 @functools.lru_cache(maxsize=None)

@@ -1,8 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Dispatch policy: on TPU the Pallas lowering runs natively; on any other
-backend the kernels execute under ``interpret=True`` (the kernel body is
-evaluated in Python/XLA-CPU — bit-accurate semantics, no TPU required).
+Dispatch policy: on CPU (tests and development) the kernels execute under
+``interpret=True`` (the kernel body is evaluated in Python/XLA-CPU —
+bit-accurate semantics, no TPU required); on any other backend they are
+compiled natively, so a backend the TPU kernels cannot target fails
+instead of quietly interpreting.
 Wrappers also handle padding to hardware-aligned block shapes and GQA
 head-group plumbing so models never see alignment constraints.
 """
@@ -21,7 +23,7 @@ from . import ref as ref  # re-exported for tests/benchmarks
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int) -> tuple[jax.Array, int]:

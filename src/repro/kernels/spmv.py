@@ -16,9 +16,14 @@ the same three decoupled stages with TPU mechanisms:
    prefetched ids, so the DMA engine performs the data-dependent gather of
    ``x[col]`` while the previous block is still being multiplied (the FIFO
    between stages is the double-buffered VMEM slot).
-3. **FMA** — MXU block dot, fp32 accumulation in VMEM scratch.
+3. **FMA** — the ``(bm, bk)`` block times the ``(1, bk)`` x tile, summed
+   across the lanes into an fp32 ``(bm, 1)`` column in VMEM scratch (a
+   matrix-vector product is bandwidth-bound: the VPU keeps up with HBM).
 
-Padding blocks (col_id == −1) are mapped to block 0 and masked in-kernel.
+Every block keeps TPU tiling: ``x`` is viewed as ``(K // bk, 1, bk)`` and
+``y`` as ``(n_block_rows, bm, 1)``, so each tile's last two dims are
+either full dims or multiples of (8, 128).  Padding blocks (col_id == −1)
+are mapped to block 0 and masked in-kernel.
 """
 
 from __future__ import annotations
@@ -33,23 +38,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _spmv_kernel(col_ref, val_ref, x_ref, y_ref, acc_ref):
-    j = pl.program_id(1)
+    i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    i = pl.program_id(0)
     valid = col_ref[i, j] >= 0
-    xblk = jnp.where(valid, x_ref[0], jnp.zeros_like(x_ref[0]))  # (bk,)
-    # (bm, bk) @ (bk, 1) on the MXU; accumulator tile is (1, bm)
-    prod = jnp.dot(val_ref[0, 0], xblk[:, None],
-                   preferred_element_type=jnp.float32)           # (bm, 1)
-    acc_ref[...] += prod[:, 0][None, :]
+    xblk = jnp.where(valid, x_ref[0].astype(jnp.float32), 0.0)   # (1, bk)
+    acc_ref[...] += jnp.sum(val_ref[0, 0].astype(jnp.float32) * xblk,
+                            axis=1, keepdims=True)               # (bm, 1)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
-        y_ref[...] = acc_ref[...].astype(y_ref.dtype)
+        y_ref[0] = acc_ref[...].astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -62,7 +64,7 @@ def spmv_bsr(
 ) -> jax.Array:
     """Block-sparse-row SpMV.
 
-    values : (n_block_rows, nnz_blocks, bm, bk)
+    values : (n_block_rows, nnz_blocks, bm, bk), bm % 8 == 0, bk % 128 == 0
     col_ids: (n_block_rows, nnz_blocks) int32, −1 = padding
     x      : (K,) with K divisible by bk
     returns (n_block_rows * bm,)
@@ -70,7 +72,7 @@ def spmv_bsr(
     nbr, nnz, bm, bk = values.shape
     K = x.shape[0]
     assert K % bk == 0
-    xb = x.reshape(K // bk, bk)
+    xb = x.reshape(K // bk, 1, bk)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -80,16 +82,19 @@ def spmv_bsr(
             # the data-dependent gather: x's tile address comes from the
             # prefetched index array (stage 1 feeding stage 2); padding
             # blocks (−1) clamp to 0 and are masked in-kernel.
-            pl.BlockSpec((1, bk),
-                         lambda i, j, cols: (jnp.maximum(cols[i, j], 0), 0)),
+            pl.BlockSpec((1, 1, bk),
+                         lambda i, j, cols: (jnp.maximum(cols[i, j], 0),
+                                             0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bm), lambda i, j, cols: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((1, bm), jnp.float32)],
+        out_specs=pl.BlockSpec((1, bm, 1), lambda i, j, cols: (i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((bm, 1), jnp.float32)],
     )
     y = pl.pallas_call(
         _spmv_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nbr, bm), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((nbr, bm, 1), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(col_ids.astype(jnp.int32), values, xb)
     return y.reshape(-1)
@@ -101,32 +106,28 @@ def csr_to_bsr(indptr: np.ndarray, indices: np.ndarray, data: np.ndarray,
     """Host-side re-blocking of CSR into the kernel's BSR layout.
 
     Returns (values, col_ids) with values (nbr, nnz_max, bm, bk) and
-    col_ids (nbr, nnz_max) int32 (−1 padding).  This is the analogue of the
-    paper's memory-space partitioning step: restructure the irregular
-    structure once, off the critical path, so the steady-state pipeline
-    sees only block-granular traffic.
+    col_ids (nbr, nnz_max) int32 (−1 padding); each block row lists its
+    touched block columns in ascending order.  This is the analogue of
+    the paper's memory-space partitioning step: restructure the
+    irregular structure once, off the critical path, so the steady-state
+    pipeline sees only block-granular traffic.
     """
     M, K = shape
     nbr = (M + bm - 1) // bm
     nbc = (K + bk - 1) // bk
-    # collect the set of touched block columns per block row
-    block_cols: list[set[int]] = [set() for _ in range(nbr)]
-    for r in range(M):
-        for p in range(indptr[r], indptr[r + 1]):
-            block_cols[r // bm].add(int(indices[p]) // bk)
-    nnz_max = max(1, max((len(s) for s in block_cols), default=1))
-    values = np.zeros((nbr, nnz_max, bm, bk), dtype=data.dtype)
+    indptr = np.asarray(indptr)
+    rows = np.repeat(np.arange(M), np.diff(indptr))
+    br, rr = np.divmod(rows, bm)
+    bc, cc = np.divmod(np.asarray(indices, np.int64), bk)
+    key = br * nbc + bc
+    blocks = np.unique(key)                      # sorted (row, col) pairs
+    ub, uc = np.divmod(blocks, nbc)
+    per_row = np.bincount(ub, minlength=nbr)
+    nnz_max = max(1, int(per_row.max(initial=0)))
+    first = np.concatenate([[0], np.cumsum(per_row)[:-1]])
+    slot_of = np.arange(len(blocks)) - first[ub]
     col_ids = np.full((nbr, nnz_max), -1, dtype=np.int32)
-    slot_of: list[dict[int, int]] = []
-    for br in range(nbr):
-        slots = {c: s for s, c in enumerate(sorted(block_cols[br]))}
-        slot_of.append(slots)
-        for c, s in slots.items():
-            col_ids[br, s] = c
-    for r in range(M):
-        br, rr = divmod(r, bm)
-        for p in range(indptr[r], indptr[r + 1]):
-            c = int(indices[p])
-            bc, cc = divmod(c, bk)
-            values[br, slot_of[br][bc], rr, cc] = data[p]
+    col_ids[ub, slot_of] = uc
+    values = np.zeros((nbr, nnz_max, bm, bk), dtype=np.asarray(data).dtype)
+    values[br, slot_of[np.searchsorted(blocks, key)], rr, cc] = data
     return values, col_ids

@@ -69,14 +69,11 @@ class BatchedServer:
         # backend executes exactly as jax.jit did, but the Compiled
         # artifact (`.lower(...)`) exposes the Algorithm-1 stage/channel
         # analysis of the serving steps — see dataflow_report().
-        # on_error="fallback": a config whose step trips the analysis
-        # passes still serves (plain jax.jit), it just loses the report.
         self._prefill = dataflow_jit(
-            lambda p, t: _prefill(p, t, cfg, max_len), backend="xla",
-            on_error="fallback")
+            lambda p, t: _prefill(p, t, cfg, max_len), backend="xla")
         self._decode = dataflow_jit(
             lambda p, tok, cache, ln: _decode(p, tok, cache, ln, cfg),
-            backend="xla", on_error="fallback")
+            backend="xla")
 
     def dataflow_report(self, requests: list["Request"]) -> str:
         """Stage/channel report of the decode step for this batch shape."""
@@ -84,28 +81,30 @@ class BatchedServer:
         import jax.numpy as jnp
         B = len(requests)
         tok = jnp.zeros((B,), jnp.int32)
-        try:
-            _, cache = jax.eval_shape(
-                lambda p, t: self._prefill_fn(p, t, self.cfg,
-                                              self.max_len),
-                self.params, jax.ShapeDtypeStruct((B, 8), jnp.int32))
-            compiled = self._decode.lower(self.params, tok, cache,
-                                          jnp.asarray(8, jnp.int32))
-            return compiled.report()
-        except Exception as e:  # noqa: BLE001 — report is best-effort
-            return f"(dataflow analysis unavailable: {type(e).__name__}: {e})"
+        _, cache = jax.eval_shape(
+            lambda p, t: self._prefill_fn(p, t, self.cfg, self.max_len),
+            self.params, jax.ShapeDtypeStruct((B, 8), jnp.int32))
+        compiled = self._decode.lower(self.params, tok, cache,
+                                      jnp.asarray(8, jnp.int32))
+        return compiled.report()
+
+    def prefill(self, requests: list[Request]):
+        """Batched prompt forward: ``(last-position logits, KV cache)``.
+        Prompts are left-aligned and right-padded with zeros (masked by
+        position)."""
+        import jax.numpy as jnp
+        S = max(len(r.prompt) for r in requests)
+        prompts = np.zeros((len(requests), S), np.int32)
+        for i, r in enumerate(requests):
+            prompts[i, :len(r.prompt)] = r.prompt
+        return self._prefill(self.params, jnp.asarray(prompts))
 
     def serve(self, requests: list[Request]) -> list[Result]:
         import jax
         import jax.numpy as jnp
-        B = len(requests)
         S = max(len(r.prompt) for r in requests)
-        # left-align prompts; pad right with zeros (masked by position)
-        prompts = np.zeros((B, S), np.int32)
-        for i, r in enumerate(requests):
-            prompts[i, :len(r.prompt)] = r.prompt
         t0 = time.time()
-        logits, cache = self._prefill(self.params, jnp.asarray(prompts))
+        logits, cache = self.prefill(requests)
         logits = jax.block_until_ready(logits)
         prefill_s = time.time() - t0
 
@@ -118,11 +117,8 @@ class BatchedServer:
         # lower once: shapes are fixed after prefill, so the decode loop
         # calls the Compiled artifact directly instead of re-keying the
         # params+cache pytree every token
-        try:
-            decode = self._decode.lower(self.params, tok.astype(jnp.int32),
-                                        cache, length)
-        except Exception:  # noqa: BLE001 — analysis failed; wrapper
-            decode = self._decode          # falls back to jax.jit per call
+        decode = self._decode.lower(self.params, tok.astype(jnp.int32),
+                                    cache, length)
         for step in range(gen):
             tokens.append(np.asarray(tok))
             logits, cache = decode(self.params, tok.astype(jnp.int32),
@@ -215,8 +211,11 @@ def _serve_cli(argv: list[str]) -> int:
 
 def _demo_main(argv: list[str]) -> None:
     import jax
+    from ..compile_cache import enable_compile_cache
     from ..configs.base import load_config, reduced as reduce_config
     from ..models import init_params
+
+    enable_compile_cache()
 
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..checkpoint.checkpointer import Checkpointer
+from ..compile_cache import enable_compile_cache
 from ..configs.base import load_config, reduced as reduce_config
 from ..data.pipeline import DataConfig, prefetched, synthetic_stream
 from ..optim import adamw
@@ -134,6 +135,7 @@ def main() -> None:
                    help="inject a failure at this step (FT demo)")
     args = p.parse_args()
 
+    enable_compile_cache()
     cfg = load_config(args.arch)
     if args.reduced:
         cfg = reduce_config(cfg)

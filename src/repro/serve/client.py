@@ -492,6 +492,7 @@ def ensure_daemon(address: str | None = None,
     under the same lock."""
     import fcntl
     import hashlib
+    from ..core import engine as _eng
     from ..core import rescache as _rc
     addr = address or protocol.default_address()
     if ping(addr):
@@ -513,7 +514,7 @@ def ensure_daemon(address: str | None = None,
                "--socket", addr, "--store-dir", _rc._dir() or ""]
         if workers is not None:
             cmd += ["--workers", str(workers)]
-        env = dict(os.environ)
+        env = dict(os.environ, **_eng.CHILD_ENV)
         env["REPRO_CHUNK_ITERS"] = str(_rc.CHUNK_ITERS)
         subprocess.Popen(cmd, stdout=subprocess.DEVNULL,
                          stderr=subprocess.DEVNULL,

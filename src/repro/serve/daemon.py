@@ -269,8 +269,10 @@ class ResolutionDaemon:
             target=worker_main,
             args=(w, self.C, self._task_qs[w], self._result_q, cfg),
             daemon=True) for w in range(self.workers)]
-        for p in self._procs:
-            p.start()
+        from ..core.engine import cpu_children
+        with cpu_children():
+            for p in self._procs:
+                p.start()
         self._known = [set() for _ in range(self.workers)]
         self._load = [0] * self.workers
         self._busy_s = [0.0] * self.workers
@@ -970,7 +972,9 @@ class ResolutionDaemon:
                 args=(w, self.C, self._task_qs[w], self._result_q,
                       self._cfg),
                 daemon=True)
-            self._procs[w].start()
+            from ..core.engine import cpu_children
+            with cpu_children():
+                self._procs[w].start()
             self._known[w] = set()
             self._load[w] = 0
         over_budget = set()

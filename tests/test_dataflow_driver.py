@@ -258,26 +258,6 @@ def test_cache_distinguishes_output_trees():
     assert isinstance(c2(x), dict)
 
 
-def test_fallback_rejects_explicit_backend():
-    """on_error='fallback' may reroute the default call to jax.jit, but an
-    explicit backend request must raise, not silently run fused."""
-    from repro.dataflow import default_pipeline
-
-    class Boom(Pass):
-        name = "partition"
-
-        def run(self, ctx):
-            raise RuntimeError("boom")
-
-    pipeline = default_pipeline().replace("partition", Boom())
-    f = dataflow_jit(lambda x: x + 1, pipeline=pipeline,
-                     on_error="fallback")
-    x = jnp.arange(3.)
-    np.testing.assert_array_equal(np.asarray(f(x)), np.asarray(x + 1))
-    with pytest.raises(RuntimeError, match="cannot honor backend"):
-        f(x, backend="simulate")
-
-
 def test_cache_miss_on_changed_shapes():
     table, idx, w = _example()
     c1 = dcompile(_quickstart_kernel, table, idx, w)

@@ -27,9 +27,11 @@ def _clean_engine():
     forced selection or wall residue behind."""
     eng.select(None)
     eng.reset_walls()
+    eng.reset_dispatches()
     yield
     eng.select(None)
     eng.reset_walls()
+    eng.reset_dispatches()
 
 
 # ---------------------------------------------------------------------------
@@ -65,11 +67,38 @@ def test_select_and_use_override(monkeypatch):
 
 
 def test_jax_without_jax_degrades(monkeypatch):
-    """An explicit jax selection on a host without jax must degrade to
-    numpy, not crash."""
+    """An explicit jax selection on a host without jax raises instead of
+    quietly running numpy; ``auto`` still degrades to numpy there."""
     monkeypatch.setattr(eng, "_jax_mods", False)
     eng.select("jax")
+    with pytest.raises(RuntimeError, match="jax"):
+        eng.current()
+    eng.select("auto")
     assert eng.current() == "numpy"
+
+
+def _child_env(q):
+    q.put({k: os.environ.get(k) for k in eng.CHILD_ENV})
+
+
+def test_cpu_children_env(monkeypatch):
+    """Spawned children start with JAX_PLATFORMS=cpu and the numpy
+    engine (the parent may hold the chip); the parent's environment is
+    restored afterwards."""
+    import multiprocessing
+    monkeypatch.setenv("REPRO_ENGINE", "jax")
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    ctx = multiprocessing.get_context("spawn")
+    q = ctx.Queue()
+    p = ctx.Process(target=_child_env, args=(q,))
+    with eng.cpu_children():
+        p.start()
+    try:
+        assert q.get(timeout=120) == eng.CHILD_ENV
+    finally:
+        p.join(timeout=60)
+    assert os.environ["REPRO_ENGINE"] == "jax"
+    assert "JAX_PLATFORMS" not in os.environ
 
 
 # ---------------------------------------------------------------------------
@@ -140,17 +169,28 @@ def test_running_max_jax_parity():
             got = eng.running_max(a.copy())
         assert got.dtype == np.int64
         assert np.array_equal(got, want)
+    assert eng.dispatches() == {"cummax@cpu": 2}
 
 
 @needs_jax
 def test_pallas_running_max_interpret():
+    """The TPU kernel's int32 block scan, run by the Pallas interpreter:
+    ragged tails, several blocks (the carry), negative values and the
+    int32 minimum used as padding."""
     rng = np.random.default_rng(3)
-    a = rng.integers(0, 1 << 40, 5000)
-    try:
-        got = eng.pallas_running_max(a, block=512, interpret=True)
-    except Exception as e:  # pragma: no cover - lowering gap on this host
-        pytest.skip(f"pallas interpret unavailable: {e}")
-    assert np.array_equal(got, np.maximum.accumulate(a))
+    lo, hi = np.iinfo(np.int32).min, np.iinfo(np.int32).max
+    cases = [rng.integers(lo, hi, 5000, dtype=np.int64).astype(np.int32),
+             (-3 * np.arange(3 * 16 * 128 + 5)).astype(np.int32),
+             np.arange(2 * 16 * 128, dtype=np.int32),
+             np.full(300, lo, np.int32),
+             np.array([7], np.int32)]
+    for a in cases:
+        got = eng.pallas_running_max(a, block_rows=16, interpret=True)
+        assert got.dtype == np.int32
+        assert np.array_equal(got, np.maximum.accumulate(a))
+    with pytest.raises(TypeError):
+        eng.pallas_running_max(cases[0].astype(np.int64), interpret=True)
+    assert eng.dispatches()["pallas_running_max@cpu"] == len(cases)
 
 
 # ---------------------------------------------------------------------------
@@ -357,9 +397,9 @@ def test_cycle_exact_engines_vs_reference(mem_mk):
     "engine",
     ["numpy"] + (["jax"] if HAVE_JAX else []))
 def test_cycle_exact_sharded_vs_streaming(small_chunks, engine):
-    """The chunk-graph executor (fused effect+replay, engine pinned via
-    the job payload) stays bit-identical to streaming on both
-    backends."""
+    """The chunk-graph executor (fused effect+replay in numpy workers,
+    fold and solve on the master's engine) stays bit-identical to
+    streaming on both backends."""
     n = 4 * 512
     stages = _paper_pipeline(n)
     mems = {"ACPC": acp_cache(), "HPC": hp_cache()}
@@ -375,9 +415,8 @@ def test_cycle_exact_sharded_vs_streaming(small_chunks, engine):
 
 
 def test_cycle_exact_served(small_chunks):
-    """Daemon-served resolution equals the library engine under the
-    session's default backend (the CI jax lane re-runs this with
-    REPRO_ENGINE=jax in the daemon workers' environment)."""
+    """Daemon-served resolution (numpy workers, see ``CHILD_ENV``)
+    equals the library engine under the session's default backend."""
     import contextlib
     import tempfile
 

@@ -108,11 +108,10 @@ def test_collectives_in_dp_tp_mesh():
 
         x = jnp.ones((8, 16), jnp.float32)
         w = jnp.ones((16, 32), jnp.float32)
-        from repro.core import shard_map_compat
-        out = jax.jit(shard_map_compat(
+        out = jax.jit(jax.shard_map(
             f, mesh=mesh,
             in_specs=(P('data', 'model'), P('model', None)),
-            out_specs=P('data', None)))(x, w)
+            out_specs=P('data', None), check_vma=False))(x, w)
         np.testing.assert_allclose(np.asarray(out), 16.0)
         print("dp-tp shard_map OK")
     """)

@@ -100,6 +100,45 @@ def test_serve_batched_deterministic():
         assert ra.tokens == rb.tokens
 
 
+def test_serve_prefill_matches_forward():
+    """``BatchedServer.prefill`` returns the model's last-position logits
+    for the batch, as the plain prefill forward gives them."""
+    import jax.numpy as jnp
+    from repro.configs import load_config, reduced
+    from repro.launch.serve import BatchedServer, Request
+    from repro.models import init_params, prefill
+
+    cfg = reduced(load_config("smollm-135m"), max_repeats=2)
+    params = init_params(jax.random.PRNGKey(1), cfg)
+    server = BatchedServer(cfg, params, max_len=32)
+    rng = np.random.default_rng(1)
+    reqs = [Request(i, rng.integers(0, cfg.vocab_size, size=(8,))
+                    .astype(np.int32), 4) for i in range(2)]
+    logits, cache = server.prefill(reqs)
+    want, _ = prefill(params, jnp.asarray(np.stack([r.prompt
+                                                    for r in reqs])),
+                      cfg, 32)
+    assert logits.shape == (2, cfg.vocab_size)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The entry points' compile cache: ``JAX_COMPILATION_CACHE_DIR``
+    wins untouched; otherwise a fixed ``.jax_cache`` in the checkout."""
+    from repro import compile_cache as cc
+    seen = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: seen.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert cc.enable_compile_cache() == "/elsewhere"
+    assert seen == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    d = cc.enable_compile_cache()
+    assert d == os.path.join(_ROOT, ".jax_cache")
+    assert seen == [("jax_compilation_cache_dir", d)]
+
+
 # ---------------------------------------------------------------------------
 # Dry-run: one full cell in a 512-device subprocess
 # ---------------------------------------------------------------------------
