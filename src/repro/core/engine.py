@@ -24,12 +24,13 @@ thresholds keep the numpy form even under ``jax`` selection *when
 auto-selected* — dispatch + host-transfer overhead dominates tiny
 calls — but an explicit selection is honoured as asked.
 
-The module also owns the per-phase wall-clock accounting
-(:func:`phase` / :func:`walls`) that the ``worker_scaling`` benchmark
-probe and the chunk-graph master use to attribute time to the
-effect / replay / fold / solve phases across process boundaries, and
-the per-kernel device dispatch counts (:func:`dispatches`), keyed by
-the platform each result came back from.
+The per-phase walls (:func:`phase` / :func:`walls`) and the per-kernel
+device dispatch counts (:func:`dispatches`, keyed by the platform each
+result came back from) are names for the process's one span-and-counter
+registry, :mod:`repro.trace`: the chunk-graph master merges its
+workers' walls into it, and every device call of a kernel here is a
+``roundtrip`` span with an ``elements.<kernel>@<platform>`` counter of
+the unpadded elements it carried.
 """
 
 from __future__ import annotations
@@ -37,9 +38,10 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
-import time
 
 import numpy as np
+
+from .. import trace as _trace
 
 __all__ = [
     "current", "select", "use", "jax_modules",
@@ -187,58 +189,38 @@ def use(name: str | None):
 
 
 # ---------------------------------------------------------------------------
-# Per-phase wall-clock accounting
+# Per-phase walls and dispatch counts: names for the repro.trace registry
 # ---------------------------------------------------------------------------
 
-#: phase name -> accumulated seconds in this process; the chunk-graph
-#: workers drain theirs into the ``done`` message and the master merges,
-#: so a sharded run's walls cover the whole pool
-_WALLS: dict[str, float] = {}
+#: ``with phase("replay"): ...`` — effect / replay / fold / solve are the
+#: canonical phases, ``windows`` and ``roundtrip`` nest inside them
+phase = _trace.span
+walls = _trace.walls
+merge_walls = _trace.merge
+
+#: prefix of the dispatch counters in the registry
+_DISPATCH = "dispatch."
 
 
-@contextlib.contextmanager
-def phase(name: str):
-    """Accumulate the wall clock of the enclosed block under ``name``
-    (effect / replay / fold / solve are the canonical phases)."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        _WALLS[name] = _WALLS.get(name, 0.0) \
-            + time.perf_counter() - t0
+#: both clear the whole registry, spans and counters: callers reset the
+#: two together where a measurement starts
+reset_walls = reset_dispatches = _trace.reset
 
 
-def walls() -> dict[str, float]:
-    return dict(_WALLS)
-
-
-def reset_walls() -> None:
-    _WALLS.clear()
-
-
-def merge_walls(other: dict[str, float] | None) -> None:
-    for k, v in (other or {}).items():
-        _WALLS[k] = _WALLS.get(k, 0.0) + float(v)
-
-
-#: ``"kernel@platform"`` -> device dispatches in this process; the
-#: platform is read off each result, so a run that claims the chip can
-#: show that its kernels came back from it
-_DISPATCH: dict[str, int] = {}
-
-
-def _count(name: str, out) -> None:
+def _dispatched(name: str, out, elements: int) -> None:
+    """Count one device call of kernel ``name`` and the ``elements`` it
+    carried, keyed ``"name@platform"`` with the platform read off the
+    result, so a run that claims the chip can show its kernels came
+    back from it."""
     plat = next(iter(out.devices())).platform
-    key = f"{name}@{plat}"
-    _DISPATCH[key] = _DISPATCH.get(key, 0) + 1
+    _trace.count(f"{_DISPATCH}{name}@{plat}")
+    _trace.count(f"elements.{name}@{plat}", elements)
 
 
 def dispatches() -> dict[str, int]:
-    return dict(_DISPATCH)
-
-
-def reset_dispatches() -> None:
-    _DISPATCH.clear()
+    """``{"kernel@platform": device calls}`` in this process."""
+    return {k[len(_DISPATCH):]: v for k, v in _trace.counts().items()
+            if k.startswith(_DISPATCH)}
 
 
 # ---------------------------------------------------------------------------
@@ -309,11 +291,13 @@ def running_max(a: np.ndarray) -> np.ndarray:
             return a
         global _cummax_jit
         if _cummax_jit is None:
-            _cummax_jit = jx.jit(lambda x: lax.cummax(x, axis=0))
-        with _x64():
+            def cummax(x):          # the trace's module: jit_cummax
+                return lax.cummax(x, axis=0)
+            _cummax_jit = jx.jit(cummax)
+        with _x64(), _trace.span("roundtrip", kernel="cummax"):
             out = _cummax_jit(a)
-            _count("cummax", out)
             a[:] = np.asarray(out)
+        _dispatched("cummax", out, a.size)
         return a
     return _running_max_np(a)
 
@@ -433,7 +417,8 @@ def _build_nway_jit():
         vals = jnp.take_along_axis(older, src, axis=1)
         return jnp.where(has, vals, newer)
 
-    def core(T, seg_grp, seg_first, carried, run):
+    # named for the trace's module, jit_nway_core
+    def nway_core(T, seg_grp, seg_first, carried, run):
         W, G = T.shape
         ways = carried.shape[1]
         stk0 = jnp.full((G, ways), -1, T.dtype)
@@ -463,7 +448,7 @@ def _build_nway_jit():
             0, W, bodyB, (E, jnp.zeros((W, G), dtype=bool)))
         return HIT, stk
 
-    return jx.jit(core)
+    return jx.jit(nway_core)
 
 
 def _pow2(n: int, floor: int = 16) -> int:
@@ -496,10 +481,11 @@ def _nway_core_jax(T, seg_grp, seg_first, carried, max_run):
         cp[:len(carried)] = carried
     else:
         cp = carried
-    with _x64():
+    with _x64(), _trace.span("roundtrip", kernel="nway"):
         HIT, stk = _nway_jit(Tp, sg, sf, cp, max_run)
-        _count("nway", HIT)
-        return np.asarray(HIT)[:, :G], np.asarray(stk)[:G]
+        out = np.asarray(HIT)[:, :G], np.asarray(stk)[:G]
+    _dispatched("nway", HIT, T.size)
+    return out
 
 
 def nway_core(T: np.ndarray, seg_grp: np.ndarray, seg_first: np.ndarray,
@@ -590,8 +576,13 @@ def _build_pallas_rmax(rows: int, nb: int, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="running_max",
     )
-    return jx.jit(call)
+
+    def running_max(x):             # the trace's module: jit_running_max
+        return call(x)
+
+    return jx.jit(running_max)
 
 
 def pallas_running_max(x: np.ndarray, *, block_rows: int = _RMAX_ROWS,
@@ -616,6 +607,8 @@ def pallas_running_max(x: np.ndarray, *, block_rows: int = _RMAX_ROWS,
         fn = _pallas_rmax[key] = _build_pallas_rmax(rows, nb, interpret)
     xp = np.full(nb * rows * _LANES, np.iinfo(np.int32).min, np.int32)
     xp[:n] = x
-    out = fn(xp.reshape(nb * rows, _LANES))
-    _count("pallas_running_max", out)
-    return np.asarray(out).reshape(-1)[:n]
+    with _trace.span("roundtrip", kernel="pallas_running_max"):
+        out = fn(xp.reshape(nb * rows, _LANES))
+        res = np.asarray(out).reshape(-1)[:n]
+    _dispatched("pallas_running_max", out, n)
+    return res

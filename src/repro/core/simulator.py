@@ -1284,13 +1284,14 @@ class _SharedResolver(_OpFolder):
                 "shared resolution requires a single burst granularity; "
                 f"got line_bytes {line_sizes} — run the models separately")
         lb = line_sizes[0] if line_sizes else DEFAULT_LINE_BYTES
-        A, _, participates, burst_words = self.chunk_arrays(
-            lo, hi, lb, self.conventional)
-        flat_p = participates.ravel()
-        addrs = A.ravel()[flat_p]
-        store_flat = np.broadcast_to(self.is_store,
-                                     (n, max(K, 1))).ravel()[flat_p] \
-            if K else np.zeros(0, dtype=bool)
+        with _engine.phase("windows"):
+            A, _, participates, burst_words = self.chunk_arrays(
+                lo, hi, lb, self.conventional)
+            flat_p = participates.ravel()
+            addrs = A.ravel()[flat_p]
+            store_flat = np.broadcast_to(
+                self.is_store, (n, max(K, 1))).ravel()[flat_p] \
+                if K else np.zeros(0, dtype=bool)
         return addrs, store_flat, flat_p, burst_words
 
     def _geo_lookup(self, sim: BatchedCacheSim, key: tuple,
@@ -2888,15 +2889,17 @@ def simulate_processor(
         if hi <= resume:
             continue
         n = hi - lo
-        A = np.full((n, K), -1, dtype=np.int64)
-        for k, acc in enumerate(accesses):
-            A[:, k] = acc._raw_window(lo, hi)
-        flat = A.ravel()
-        valid = flat >= 0
-        addrs = flat[valid]
-        l1_hit = l1.lookup(addrs)
-        to_l2 = addrs[~l1_hit]
-        l2_hit = l2.lookup(to_l2)
+        with _engine.phase("windows"):
+            A = np.full((n, K), -1, dtype=np.int64)
+            for k, acc in enumerate(accesses):
+                A[:, k] = acc._raw_window(lo, hi)
+            flat = A.ravel()
+            valid = flat >= 0
+            addrs = flat[valid]
+        with _engine.phase("replay"):
+            l1_hit = l1.lookup(addrs)
+            to_l2 = addrs[~l1_hit]
+            l2_hit = l2.lookup(to_l2)
         n_l2_hits += int(l2_hit.sum())
         n_dram += int((~l2_hit).sum())
         if not writer.dead:

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import trace
 from .backends import available_backends, get_backend
 from .options import CompileOptions
 from .passes import CompileContext, PassPipeline, default_pipeline
@@ -362,12 +363,13 @@ def dataflow_jit(
 
         def lower(*args: Any, **kwargs: Any) -> Compiled:
             args = bind(args, kwargs)
-            key = _abstract_key(args)
-            compiled = by_shape.get(key)
-            if compiled is None:
-                compiled = compile(f, *args, options=opts,
-                                   pipeline=pipeline)
-                by_shape[key] = compiled
+            with trace.span("dataflow.lower"):
+                key = _abstract_key(args)
+                compiled = by_shape.get(key)
+                if compiled is None:
+                    compiled = compile(f, *args, options=opts,
+                                       pipeline=pipeline)
+                    by_shape[key] = compiled
             return compiled
 
         def wrapper(*args: Any, backend: str | None = None,

@@ -32,6 +32,8 @@ import time
 
 import numpy as np
 
+from .. import trace
+
 log = logging.getLogger("repro.serve")
 
 
@@ -53,27 +55,38 @@ class Result:
 class BatchedServer:
     """Static-batch server: groups requests, prefills once, decodes in
     lockstep (continuous batching is a straightforward extension — slots
-    re-admit on completion; kept static for deterministic tests)."""
+    re-admit on completion; kept static for deterministic tests).
 
-    def __init__(self, cfg, params, *, max_len: int = 256,
-                 greedy: bool = True):
+    ``serve`` records its phases in :mod:`repro.trace`: spans
+    ``serve.prefill``, ``serve.decode`` and, per decode step,
+    ``serve.sync`` (waiting for the last token) and ``serve.dispatch``
+    (enqueueing the step and its argmax), each with the batch's first
+    request id as ``batch``; counters ``serve.batches``,
+    ``serve.decode_steps``, ``serve.tokens_decoded`` (batch × steps) and
+    ``serve.tokens_returned``."""
+
+    def __init__(self, cfg, params, *, max_len: int = 256):
         from ..dataflow import dataflow_jit
         from ..models import decode_step as _decode, prefill as _prefill
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
-        self.greedy = greedy
         self._prefill_fn = _prefill
         self._decode_fn = _decode
+
+        # named for the trace's modules: jit_prefill_step, jit_decode_step
+        def prefill_step(p, t):
+            return _prefill(p, t, cfg, max_len)
+
+        def decode_step(p, tok, cache, ln):
+            return _decode(p, tok, cache, ln, cfg)
+
         # Both steps go through the dataflow compiler driver.  The "xla"
         # backend executes exactly as jax.jit did, but the Compiled
         # artifact (`.lower(...)`) exposes the Algorithm-1 stage/channel
         # analysis of the serving steps — see dataflow_report().
-        self._prefill = dataflow_jit(
-            lambda p, t: _prefill(p, t, cfg, max_len), backend="xla")
-        self._decode = dataflow_jit(
-            lambda p, tok, cache, ln: _decode(p, tok, cache, ln, cfg),
-            backend="xla")
+        self._prefill = dataflow_jit(prefill_step, backend="xla")
+        self._decode = dataflow_jit(decode_step, backend="xla")
 
     def dataflow_report(self, requests: list["Request"]) -> str:
         """Stage/channel report of the decode step for this batch shape."""
@@ -103,35 +116,41 @@ class BatchedServer:
         import jax
         import jax.numpy as jnp
         S = max(len(r.prompt) for r in requests)
-        t0 = time.time()
-        logits, cache = self.prefill(requests)
-        logits = jax.block_until_ready(logits)
-        prefill_s = time.time() - t0
+        batch = requests[0].id
+        with trace.span("serve.prefill", batch=batch) as prefill:
+            logits, cache = self.prefill(requests)
+            logits = jax.block_until_ready(logits)
 
         gen = max(r.max_new_tokens for r in requests)
         tokens = []
-        tok = (jnp.argmax(logits, -1) if self.greedy
-               else jnp.argmax(logits, -1))
-        t1 = time.time()
-        length = jnp.asarray(S, jnp.int32)
-        # lower once: shapes are fixed after prefill, so the decode loop
-        # calls the Compiled artifact directly instead of re-keying the
-        # params+cache pytree every token
-        decode = self._decode.lower(self.params, tok.astype(jnp.int32),
-                                    cache, length)
-        for step in range(gen):
-            tokens.append(np.asarray(tok))
-            logits, cache = decode(self.params, tok.astype(jnp.int32),
-                                   cache, length + step)
-            tok = jnp.argmax(logits, -1)
-        jax.block_until_ready(logits)
-        decode_s = time.time() - t1
+        tok = jnp.argmax(logits, -1)
+        with trace.span("serve.decode", batch=batch) as decode_span:
+            length = jnp.asarray(S, jnp.int32)
+            # lower once: shapes are fixed after prefill, so the decode
+            # loop calls the Compiled artifact directly instead of
+            # re-keying the params+cache pytree every token
+            decode = self._decode.lower(self.params, tok.astype(jnp.int32),
+                                        cache, length)
+            for step in range(gen):
+                with trace.span("serve.sync", batch=batch):
+                    tokens.append(np.asarray(tok))
+                with trace.span("serve.dispatch", batch=batch):
+                    logits, cache = decode(self.params,
+                                           tok.astype(jnp.int32), cache,
+                                           length + step)
+                    tok = jnp.argmax(logits, -1)
+            jax.block_until_ready(logits)
 
         outs = []
         seq = np.stack(tokens, 1)  # (B, gen)
         for i, r in enumerate(requests):
             outs.append(Result(r.id, seq[i, :r.max_new_tokens].tolist(),
-                               prefill_s, decode_s / gen))
+                               prefill.seconds, decode_span.seconds / gen))
+        trace.count("serve.batches")
+        trace.count("serve.decode_steps", gen)
+        trace.count("serve.tokens_decoded", len(requests) * gen)
+        trace.count("serve.tokens_returned",
+                    sum(len(o.tokens) for o in outs))
         return outs
 
 
@@ -238,9 +257,9 @@ def _demo_main(argv: list[str]) -> None:
             for i in range(args.requests)]
     log.info("decode-step dataflow analysis:\n%s",
              server.dataflow_report(reqs))
-    t0 = time.time()
+    t0 = time.perf_counter()
     results = server.serve(reqs)
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     tok_total = sum(len(r.tokens) for r in results)
     print(f"served {len(results)} requests, {tok_total} tokens "
           f"in {dt:.2f}s ({tok_total / dt:.1f} tok/s); "
