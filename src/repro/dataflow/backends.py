@@ -171,14 +171,16 @@ class SystolicBackend(Backend):
 class XLABackend(Backend):
     """The fused baseline: hand the whole function to XLA unchanged.  This
     is the production path when the program should run as one kernel —
-    the driver still yields the partition/schedule analysis around it."""
+    the driver still yields the partition/schedule analysis around it.
+    Arguments at ``options.donate_argnums`` are donated to the call."""
 
     name = "xla"
 
     def execute(self, compiled: Any, args: Sequence[Any]) -> Any:
         jitted = compiled.runtime_cache.get(self.name)
         if jitted is None:
-            jitted = jax.jit(compiled.fn)
+            jitted = jax.jit(compiled.fn,
+                             donate_argnums=compiled.options.donate_argnums)
             compiled.runtime_cache[self.name] = jitted
         return jitted(*args)
 

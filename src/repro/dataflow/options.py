@@ -154,6 +154,10 @@ class CompileOptions:
       ``backend``        — default backend name for ``Compiled.__call__``.
       ``stream_argnums`` — argument positions that vary per microbatch when
         streaming through the systolic executors.
+      ``donate_argnums`` — argument positions whose buffers the ``xla``
+        backend donates to the call (``jax.jit``'s ``donate_argnums``):
+        XLA may write the outputs into them, and the caller's arrays there
+        are consumed.
 
     Design-space exploration:
       ``dse`` — a :class:`ResourceConstraints` block.  When set, the
@@ -203,6 +207,7 @@ class CompileOptions:
     loop: bool = False
     nonaliasing_carries: Any = ()
     stream_argnums: Any = (0,)
+    donate_argnums: Any = ()
     dse: ResourceConstraints | None = None
     transforms: Any = None
     serve: ServeOptions | None = None
@@ -213,6 +218,8 @@ class CompileOptions:
         object.__setattr__(self, "regions", _freeze(self.regions))
         object.__setattr__(self, "stream_argnums",
                            tuple(self.stream_argnums))
+        object.__setattr__(self, "donate_argnums",
+                           tuple(self.donate_argnums))
         object.__setattr__(self, "nonaliasing_carries",
                            tuple(self.nonaliasing_carries))
 

@@ -62,8 +62,13 @@ class BatchedServer:
     ``serve.sync`` (waiting for the last token) and ``serve.dispatch``
     (enqueueing the step and its argmax), each with the batch's first
     request id as ``batch``; counters ``serve.batches``,
-    ``serve.decode_steps``, ``serve.tokens_decoded`` (batch × steps) and
-    ``serve.tokens_returned``."""
+    ``serve.decode_steps``, ``serve.tokens_decoded`` (batch × steps),
+    ``serve.tokens_returned`` and ``serve.cache_donated`` (decode steps
+    whose incoming KV cache the step consumed in place).
+
+    The decode step donates its cache argument: each step writes this
+    token's entries into the cache it was given, so the cache a caller
+    passes in (the prefill's, then each step's) is consumed."""
 
     def __init__(self, cfg, params, *, max_len: int = 256):
         from ..dataflow import dataflow_jit
@@ -84,9 +89,12 @@ class BatchedServer:
         # Both steps go through the dataflow compiler driver.  The "xla"
         # backend executes exactly as jax.jit did, but the Compiled
         # artifact (`.lower(...)`) exposes the Algorithm-1 stage/channel
-        # analysis of the serving steps — see dataflow_report().
+        # analysis of the serving steps — see dataflow_report().  The
+        # decode step donates the cache (argument 2), so XLA updates it in
+        # place instead of copying it into a new buffer every token.
         self._prefill = dataflow_jit(prefill_step, backend="xla")
-        self._decode = dataflow_jit(decode_step, backend="xla")
+        self._decode = dataflow_jit(decode_step, backend="xla",
+                                    donate_argnums=(2,))
 
     def dataflow_report(self, requests: list["Request"]) -> str:
         """Stage/channel report of the decode step for this batch shape."""
@@ -128,17 +136,23 @@ class BatchedServer:
             length = jnp.asarray(S, jnp.int32)
             # lower once: shapes are fixed after prefill, so the decode
             # loop calls the Compiled artifact directly instead of
-            # re-keying the params+cache pytree every token
-            decode = self._decode.lower(self.params, tok.astype(jnp.int32),
-                                        cache, length)
+            # re-keying the params+cache pytree every token.  Lowered with
+            # shapes, so the artifact keeps no cache that the first step
+            # consumes.
+            decode = self._decode.lower(*jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                (self.params, tok.astype(jnp.int32), cache, length)))
+            donated = 0
             for step in range(gen):
                 with trace.span("serve.sync", batch=batch):
                     tokens.append(np.asarray(tok))
                 with trace.span("serve.dispatch", batch=batch):
+                    given = jax.tree_util.tree_leaves(cache)[0]
                     logits, cache = decode(self.params,
                                            tok.astype(jnp.int32), cache,
                                            length + step)
                     tok = jnp.argmax(logits, -1)
+                donated += given.is_deleted()
             jax.block_until_ready(logits)
 
         outs = []
@@ -151,6 +165,7 @@ class BatchedServer:
         trace.count("serve.tokens_decoded", len(requests) * gen)
         trace.count("serve.tokens_returned",
                     sum(len(o.tokens) for o in outs))
+        trace.count("serve.cache_donated", donated)
         return outs
 
 

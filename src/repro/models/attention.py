@@ -223,46 +223,41 @@ def gqa_init_cache(cfg, batch: int, max_len: int) -> dict:
             "v": jnp.zeros(shape, cfg.np_dtype)}
 
 
-def gqa_decode(params: dict, x: jax.Array, cache: dict, length: jax.Array,
-               cfg) -> tuple[jax.Array, dict]:
-    """One-token decode: append to cache, attend over the valid prefix.
+def gqa_decode_entries(params: dict, x: jax.Array, length: jax.Array,
+                       cfg) -> tuple[jax.Array, dict]:
+    """This token's query and its cache entries, the decode step's cache
+    write stage: ``(q (B, H, 1, d), entries)``, with every entry shaped like
+    the cache leaf of the same key but one position long (the writer casts
+    it to the cache's dtype).
 
     x: (B, 1, d); length: scalar int32 (tokens already in cache).
     """
     B = x.shape[0]
-    length = jnp.asarray(length, jnp.int32)
     positions = jnp.broadcast_to(length[None], (B,))[:, None]  # (B, 1)
     q, k, v = _project_qkv(params, x, cfg, positions)
-    lengths = jnp.full((B,), length + 1, jnp.int32)
     if cfg.kv_cache_dtype == "int8":
         kq, ks = _kv_quantize(k)
         vq, vs = _kv_quantize(v)
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice(cache["k"], kq,
-                                              (0, 0, length, 0)),
-            "v": jax.lax.dynamic_update_slice(cache["v"], vq,
-                                              (0, 0, length, 0)),
-            "k_scale": jax.lax.dynamic_update_slice(
-                cache["k_scale"], ks, (0, 0, length, 0)),
-            "v_scale": jax.lax.dynamic_update_slice(
-                cache["v_scale"], vs, (0, 0, length, 0)),
-        }
-        out = _decode_chunked(q[:, :, 0], new_cache["k"], new_cache["v"],
-                              lengths, k_scale=new_cache["k_scale"],
-                              v_scale=new_cache["v_scale"])
-        out = out.reshape(B, 1, -1)
-        return out @ params["w_o"], new_cache
-    # append new k/v at `length` (the decoupled cache write stage)
-    k_cache = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, 0, length, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, 0, length, 0))
-    if cfg.attn_impl == "pallas":
-        out = kops.decode_attention(q[:, :, 0], k_cache, v_cache, lengths)
+        return q, {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    return q, {"k": k, "v": v}
+
+
+def gqa_decode_attend(params: dict, q: jax.Array, cache: dict,
+                      length: jax.Array, cfg) -> jax.Array:
+    """Attend over the valid prefix of a layer's cache, which already holds
+    this token's entries at ``length``."""
+    B = q.shape[0]
+    lengths = jnp.full((B,), length + 1, jnp.int32)
+    if cfg.kv_cache_dtype == "int8":
+        out = _decode_chunked(q[:, :, 0], cache["k"], cache["v"], lengths,
+                              k_scale=cache["k_scale"],
+                              v_scale=cache["v_scale"])
+    elif cfg.attn_impl == "pallas":
+        out = kops.decode_attention(q[:, :, 0], cache["k"], cache["v"],
+                                    lengths)
     else:
-        out = _decode_chunked(q[:, :, 0], k_cache, v_cache, lengths)
-    out = out.reshape(B, 1, -1)
-    return out @ params["w_o"], {"k": k_cache, "v": v_cache}
+        out = _decode_chunked(q[:, :, 0], cache["k"], cache["v"], lengths)
+    return out.reshape(B, 1, -1) @ params["w_o"]
 
 
 def _decode_chunked(q, k_cache, v_cache, lengths, chunk: int = 2048,
@@ -423,24 +418,25 @@ def mla_init_cache(cfg, batch: int, max_len: int) -> dict:
     }
 
 
-def mla_decode(params: dict, x: jax.Array, cache: dict, length: jax.Array,
-               cfg) -> tuple[jax.Array, dict]:
+def mla_decode_entries(params: dict, x: jax.Array, length: jax.Array,
+                       cfg) -> tuple[tuple, dict]:
+    """MLA's query parts and this token's latent cache entries (see
+    :func:`gqa_decode_entries`)."""
     B = x.shape[0]
-    length = jnp.asarray(length, jnp.int32)
     positions = jnp.broadcast_to(length[None], (B,))[:, None]
     q_nope, q_pe, c_kv, k_pe = _mla_qkv(params, x, cfg, positions)
-    c_cache = jax.lax.dynamic_update_slice(
-        cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, length, 0))
-    p_cache = jax.lax.dynamic_update_slice(
-        cache["k_pe"], k_pe.astype(cache["k_pe"].dtype), (0, length, 0))
+    return (q_nope, q_pe), {"c_kv": c_kv, "k_pe": k_pe}
+
+
+def mla_decode_attend(params: dict, q: tuple, cache: dict,
+                      length: jax.Array, cfg) -> jax.Array:
+    q_nope, q_pe = q
     if getattr(cfg, "mla_absorbed", False):
-        out = _mla_decode_absorbed(params, q_nope, q_pe, c_cache, p_cache,
-                                   length, cfg)
-    else:
-        # naive: decompress the whole cache and attend (baseline)
-        out = _mla_attend(params, q_nope, q_pe, c_cache, p_cache, cfg,
-                          causal=True, q_offset=length)
-    return out, {"c_kv": c_cache, "k_pe": p_cache}
+        return _mla_decode_absorbed(params, q_nope, q_pe, cache["c_kv"],
+                                    cache["k_pe"], length, cfg)
+    # naive: decompress the whole cache and attend (baseline)
+    return _mla_attend(params, q_nope, q_pe, cache["c_kv"], cache["k_pe"],
+                       cfg, causal=True, q_offset=length)
 
 
 def _mla_decode_absorbed(params, q_nope, q_pe, c_cache, p_cache, length,

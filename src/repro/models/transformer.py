@@ -7,8 +7,8 @@ unit size, not the depth — 61-layer DeepSeek and 72-layer Jamba lower in
 seconds and the dry-run's compiled artifact stays tractable.
 
 Decode carries a pytree of caches with the same (segments → repeats →
-sublayer) structure; the per-repeat cache slices ride through the scan as
-``xs``/``ys``.
+sublayer) structure; each segment's stacked cache rides through the scan's
+carry and is written in place, one token's entries per layer.
 """
 
 from __future__ import annotations
@@ -127,48 +127,97 @@ def _layer_apply(params: dict, x: jax.Array, spec: LayerSpec, cfg,
 # Decode (cache-carrying) sub-layer apply
 # ---------------------------------------------------------------------------
 
-def _layer_decode(params: dict, x: jax.Array, cache: dict,
+_ATTN_DECODE = {
+    "attn": (attention.gqa_decode_entries, attention.gqa_decode_attend),
+    "mla": (attention.mla_decode_entries, attention.mla_decode_attend),
+}
+_STATE_DECODE = {"mamba": ssm.mamba_decode, "rwkv": ssm.rwkv6_decode}
+
+
+def _layer_view(stack: Any, rep: jax.Array) -> Any:
+    """One repeat's slice of a stacked (repeats-first) cache subtree."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, rep, keepdims=False),
+        stack)
+
+
+# Rows written around this token's row: a window of one row along the
+# sequence axis makes the TPU compiler lay the whole carried cache out
+# sequence-major, and so copy it in and out of the layer scan every step.
+_ROW_WINDOW = 8
+
+
+def _store(stack: Any, entries: Any, rep: jax.Array,
+           length: jax.Array) -> Any:
+    """Write one layer's new entries into the stacked cache in place.
+
+    An entry shaped like the layer's leaf is recurrent state and replaces it
+    whole; an entry one position long on an axis of the leaf is this
+    token's row, written at ``length`` on that axis (as part of a
+    ``_ROW_WINDOW``-row window read back unchanged around it).  One
+    ``dynamic_update_slice`` per leaf of the carried stack, which XLA
+    performs on the loop's buffer without copying it.
+    """
+    def put(a, e):
+        e = e.astype(a.dtype)[None]
+        rows = [k for k in range(1, a.ndim) if e.shape[k] != a.shape[k]]
+        if not rows:
+            return jax.lax.dynamic_update_index_in_dim(a, e, rep, 0)
+        (ax,) = rows
+        w = min(_ROW_WINDOW, a.shape[ax])
+        start = jnp.minimum(length - length % w, a.shape[ax] - w)
+        idx = [rep] + [start if k == ax else 0 for k in range(1, a.ndim)]
+        size = [1] + [w if k == ax else a.shape[k] for k in range(1, a.ndim)]
+        window = jax.lax.dynamic_slice(a, idx, size)
+        hit = jax.lax.broadcasted_iota(jnp.int32, size, ax) == length - start
+        return jax.lax.dynamic_update_slice(a, jnp.where(hit, e, window), idx)
+    return jax.tree_util.tree_map(put, stack, entries)
+
+
+def _layer_decode(params: dict, x: jax.Array, stack: dict, rep: jax.Array,
                   length: jax.Array, spec: LayerSpec, cfg
                   ) -> tuple[jax.Array, dict]:
+    """One sublayer of repeat ``rep`` over the segment's stacked cache
+    ``stack``; returns the stack with this token's entries written."""
     _, napply = layers.make_norm(cfg.norm)
     h1 = napply(params["norm1"], x)
-    if spec.mixer == "attn":
-        mix, mcache = attention.gqa_decode(params["mixer"], h1,
-                                           cache["mixer"], length, cfg)
-    elif spec.mixer == "mla":
-        mix, mcache = attention.mla_decode(params["mixer"], h1,
-                                           cache["mixer"], length, cfg)
-    elif spec.mixer == "mamba":
-        mix, mcache = ssm.mamba_decode(params["mixer"], h1,
-                                       cache["mixer"], cfg)
-    elif spec.mixer == "rwkv":
-        mix, mcache = ssm.rwkv6_decode(params["mixer"], h1,
-                                       cache["mixer"], cfg)
+    new_stack = dict(stack)
+    if spec.mixer in _ATTN_DECODE:
+        entries_fn, attend_fn = _ATTN_DECODE[spec.mixer]
+        q, entries = entries_fn(params["mixer"], h1, length, cfg)
+        new_stack["mixer"] = _store(stack["mixer"], entries, rep, length)
+        mix = attend_fn(params["mixer"], q,
+                        _layer_view(new_stack["mixer"], rep), length, cfg)
+    elif spec.mixer in _STATE_DECODE:
+        mix, state = _STATE_DECODE[spec.mixer](
+            params["mixer"], h1, _layer_view(stack["mixer"], rep), cfg)
+        new_stack["mixer"] = _store(stack["mixer"], state, rep, length)
     else:
         raise ValueError(spec.mixer)
 
-    new_cache = dict(cache)
-    new_cache["mixer"] = mcache
+    def cmix(h):
+        prev = _layer_view(stack["cmix_prev"], rep)
+        new_stack["cmix_prev"] = _store(stack["cmix_prev"], h, rep, length)
+        return _cmix_apply(params["mlp"], h, prev=prev)
+
     if cfg.parallel_block:
         if spec.mlp == "moe":
             ff, _ = moe.moe_apply(params["mlp"], h1, cfg)
         elif spec.mlp == "rwkv_cmix":
-            ff = _cmix_apply(params["mlp"], h1, prev=cache.get("cmix_prev"))
-            new_cache["cmix_prev"] = h1
+            ff = cmix(h1)
         else:
             ff = layers.mlp_apply(params["mlp"], h1, cfg.act)
-        return x + mix + ff, new_cache
+        return x + mix + ff, new_stack
 
     x = x + mix
     h2 = napply(params["norm2"], x)
     if spec.mlp == "moe":
         ff, _ = moe.moe_apply(params["mlp"], h2, cfg)
     elif spec.mlp == "rwkv_cmix":
-        ff = _cmix_apply(params["mlp"], h2, prev=cache.get("cmix_prev"))
-        new_cache["cmix_prev"] = h2
+        ff = cmix(h2)
     else:
         ff = layers.mlp_apply(params["mlp"], h2, cfg.act)
-    return x + ff, new_cache
+    return x + ff, new_stack
 
 
 def _layer_prefill(params: dict, x: jax.Array, spec: LayerSpec, cfg,
@@ -348,24 +397,30 @@ def decode_step(params: dict, token: jax.Array, cache: dict,
                 length: jax.Array, cfg: ModelConfig
                 ) -> tuple[jax.Array, dict]:
     """One new token for every sequence.  token: (B,) int32; returns
-    (logits (B, vocab), new_cache)."""
+    (logits (B, vocab), new_cache).
+
+    Each segment's stacked cache rides through the layer scan in the carry,
+    and every layer writes only this token's entries into it, so the step
+    never copies a layer's cache; jitted with the cache donated, the update
+    is in place across steps too."""
     x = layers.embedding_apply(params["embed"], token[:, None])
+    length = jnp.asarray(length, jnp.int32)
     new_cache: dict[str, Any] = {}
     for si, seg in enumerate(cfg.segments):
         stacked = params[f"segment_{si}"]
-        seg_cache = cache[f"segment_{si}"]
 
-        def body(x, inp, seg=seg):
-            rep_params, rep_cache = inp
-            new_rep_cache = []
+        def body(carry, rep_params, seg=seg):
+            x, seg_cache, rep = carry
+            seg_cache = list(seg_cache)
             for j, spec in enumerate(seg.unit):
-                x, c = _layer_decode(rep_params[j], x, rep_cache[j],
-                                     length, spec, cfg)
-                new_rep_cache.append(c)
-            return x, new_rep_cache
+                x, seg_cache[j] = _layer_decode(rep_params[j], x,
+                                                seg_cache[j], rep, length,
+                                                spec, cfg)
+            return (x, seg_cache, rep + 1), None
 
-        x, new_seg_cache = jax.lax.scan(body, x, (stacked, seg_cache))
-        new_cache[f"segment_{si}"] = new_seg_cache
+        (x, new_cache[f"segment_{si}"], _), _ = jax.lax.scan(
+            body, (x, cache[f"segment_{si}"], jnp.zeros((), jnp.int32)),
+            stacked)
 
     _, napply = layers.make_norm(cfg.norm)
     x = napply(params["final_norm"], x)

@@ -100,6 +100,54 @@ def test_serve_batched_deterministic():
         assert ra.tokens == rb.tokens
 
 
+def test_serve_decode_donates_cache_and_matches_reference():
+    """``serve`` decodes with the cache donated: the prefill's cache is
+    consumed, every step counts in ``serve.cache_donated``, and the greedy
+    tokens are those of a plain loop over ``models.decode_step`` that
+    donates nothing, and the argmax of the full forward over them."""
+    import jax.numpy as jnp
+    from repro import trace
+    from repro.configs import load_config, reduced
+    from repro.launch.serve import BatchedServer, Request
+    from repro.models import decode_step, forward, init_params, prefill
+
+    cfg = reduced(load_config("olmo-1b"), max_repeats=2)
+    params = init_params(jax.random.PRNGKey(2), cfg)
+    server = BatchedServer(cfg, params, max_len=32)
+    rng = np.random.default_rng(2)
+    reqs = [Request(i, rng.integers(0, cfg.vocab_size, size=(8,))
+                    .astype(np.int32), 6) for i in range(3)]
+    prefilled = []
+    served_prefill = server.prefill
+
+    def keep_cache(requests):
+        logits, cache = served_prefill(requests)
+        prefilled.append(cache)
+        return logits, cache
+
+    server.prefill = keep_cache
+    trace.reset()
+    got = [r.tokens for r in server.serve(reqs)]
+
+    assert all(a.is_deleted()
+               for a in jax.tree_util.tree_leaves(prefilled[0]))
+    c = trace.counts()
+    assert c["serve.cache_donated"] == c["serve.decode_steps"] == 6
+
+    prompts = jnp.asarray(np.stack([r.prompt for r in reqs]))
+    logits, cache = prefill(params, prompts, cfg, 32)
+    tok, want = jnp.argmax(logits, -1), []
+    for step in range(6):
+        want.append(np.asarray(tok))
+        logits, cache = decode_step(params, tok, cache,
+                                    jnp.asarray(8 + step, jnp.int32), cfg)
+        tok = jnp.argmax(logits, -1)
+    assert got == np.stack(want, 1).tolist()
+    full, _ = forward(params, jnp.concatenate(
+        [prompts, jnp.asarray(got)[:, :-1]], 1), cfg)
+    assert got == np.asarray(jnp.argmax(full[:, 7:], -1)).tolist()
+
+
 def test_serve_prefill_matches_forward():
     """``BatchedServer.prefill`` returns the model's last-position logits
     for the batch, as the plain prefill forward gives them."""

@@ -7,7 +7,9 @@ Mosaic cannot lower) fails here at no chip time.  Nothing runs: these
 tests say nothing about results or speed.  Each kernel compiles at the
 size the system uses it: the resolution engine's kernels at full-scale
 Table-I spmv shapes, the paper's spmv at Table-I size, and the LM kernels
-at SmolLM-135M widths (d_model 576, 9/3 heads of 64, d_ff 1536).
+at SmolLM-135M widths (d_model 576, 9/3 heads of 64, d_ff 1536).  The
+served decode step compiles whole, at 128-wide heads, to check that it
+updates its KV cache in place.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -17,6 +19,7 @@ because the ``ops`` wrappers pick interpret mode on a CPU backend.
 
 import importlib
 import os
+import re
 
 import pytest
 
@@ -154,3 +157,86 @@ def test_dataflow_matmul_compiles(one_chip):
                _sds(one_chip, (B * S, 1024), jnp.bfloat16),
                _sds(one_chip, (1024, D_FF), jnp.bfloat16))
     assert "tpu_custom_call" in hlo
+
+
+# ---------------------------------------------------------------------------
+# The served decode step: the KV cache updated in place
+# ---------------------------------------------------------------------------
+
+def _decode_config(kind: str):
+    """Two repeats of a decoder at TPU head widths (128), bf16: OLMo's
+    layer with a bf16 or an int8 cache, or DeepSeek-V3's MLA layers with
+    the absorbed decode (the naive one decompresses every layer's cache by
+    design)."""
+    import dataclasses
+    from repro.configs.base import MLAConfig, load_config, reduced
+    arch = "deepseek-v3-671b" if kind == "mla" else "olmo-1b"
+    cfg = reduced(load_config(arch), d_model=512)
+    cfg = dataclasses.replace(cfg, dtype="bfloat16", attn_impl="auto")
+    if kind == "int8":
+        cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
+    if kind == "mla":
+        cfg = dataclasses.replace(cfg, mla=MLAConfig(
+            q_lora_rank=128, kv_lora_rank=128, qk_nope_head_dim=64,
+            qk_rope_head_dim=64, v_head_dim=64), mla_absorbed=True)
+    return cfg
+
+
+def _hbm_buffers(hlo: str) -> list[tuple[str, str]]:
+    """(opcode, dims) of every instruction outside fused computations whose
+    result lives in HBM (no memory-space mark such as VMEM's ``S(1)``):
+    the buffers the step materialises there."""
+    fused = set(re.findall(r"fusion\(.*?calls=(%[\w.\-]+)", hlo))
+    out, skip = [], False
+    for line in hlo.splitlines():
+        if line and not line.startswith(" "):
+            skip = line.split(" ", 1)[0] in fused
+            continue
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\{([^}]*)\} "
+                     r"([\w\-]+)\(", line)
+        if m and not skip and "S(" not in m.group(2):
+            out.append((m.group(3), m.group(1)))
+    return out
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8", "mla"])
+def test_decode_step_updates_cache_in_place(one_chip, kind):
+    """``jit_decode_step`` as the server builds it (cache donated) aliases
+    every cache leaf to its output, materialises no HBM buffer with the
+    shape of one layer's cache, and needs less scratch than one layer's K: the
+    layer scan writes this token's entries into the carried cache and
+    never copies a layer or the stack.  One layer's K is 64 MiB here, too
+    large for the compiler to stage a copy of it in VMEM."""
+    from repro.launch.serve import BatchedServer
+    from repro.models import init_cache, init_params
+    cfg = _decode_config(kind)
+    batch, max_len = 32, 2048
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = sds(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = sds(jax.eval_shape(lambda: init_cache(cfg, batch, max_len)))
+    args = (params, _sds(one_chip, (batch,), jnp.int32), cache,
+            _sds(one_chip, (), jnp.int32))
+    step = BatchedServer(cfg, params, max_len=max_len)._decode.lower(*args)
+    assert step.options.donate_argnums == (2,)
+    compiled = jax.jit(step.fn, donate_argnums=step.options.donate_argnums
+                       ).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_decode_step")
+
+    leaves = jax.tree_util.tree_leaves(cache)
+    nbytes = [a.size * a.dtype.itemsize for a in leaves]
+    aliased = re.findall(r"\{\d+\}: \(\d+, \{\}",
+                         hlo.split("\n", 1)[0])
+    memory = compiled.memory_analysis()
+    assert len(aliased) == len(leaves)
+    assert memory.alias_size_in_bytes == sum(nbytes)
+    layer = {",".join(map(str, a.shape[1:])) for a in leaves}
+    assert [(op, d) for op, d in _hbm_buffers(hlo)
+            if d in layer and op != "bitcast"] == []
+    assert memory.temp_size_in_bytes < max(
+        n // a.shape[0] for n, a in zip(nbytes, leaves))
