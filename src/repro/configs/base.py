@@ -54,10 +54,30 @@ class MoEConfig:
     #: (groups = EP devices), bounding all-to-all fan-out.  0 = unlimited.
     route_groups: int = 0
     route_device_limit: int = 0
+    #: the chip's share under expert parallelism: this layer holds the
+    #: routed experts ``first_held .. first_held + held_experts - 1`` of
+    #: ``num_experts`` (0 = all of them).  The router still scores all
+    #: ``num_experts``; pairs routed to experts held elsewhere add nothing
+    #: here.
+    held_experts: int = 0
+    first_held: int = 0
+    #: DeepSeek-V3's selection-only correction bias (``noaux_tc``): a
+    #: per-expert bias added to the scores for the top-k choice only
+    score_bias: bool = False
+    #: factor on the combine weights after their normalisation
+    #: (DeepSeek-V3's ``routed_scaling_factor``)
+    routed_scale: float = 1.0
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights this layer holds."""
+        return self.held_experts or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
+    #: 0: no query LoRA, a direct query projection (DeepSeek-V2-Lite,
+    #: Moonlight)
     q_lora_rank: int = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
@@ -94,6 +114,7 @@ class ModelConfig:
     attn_impl: str = "auto"           # "auto" | "full" | "chunked" | "pallas"
     qkv_bias: bool = False
     norm: str = "rmsnorm"
+    norm_eps: float | None = None     # None: the norm's own default
     act: str = "silu"
     rope_theta: float = 1e4
     parallel_block: bool = False      # Cohere-style attn ∥ mlp
@@ -151,7 +172,8 @@ class ModelConfig:
             elif spec.mixer == "mla":
                 m = self.mla
                 qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-                p += d * m.q_lora_rank + m.q_lora_rank * self.num_heads * qk
+                p += (d * m.q_lora_rank + m.q_lora_rank * self.num_heads * qk
+                      if m.q_lora_rank else d * self.num_heads * qk)
                 p += d * (m.kv_lora_rank + m.qk_rope_head_dim)
                 p += m.kv_lora_rank * self.num_heads * (
                     m.qk_nope_head_dim + m.v_head_dim)
@@ -167,8 +189,9 @@ class ModelConfig:
                 p += (3 if self.act == "silu" else 2) * d * self.d_ff
             elif spec.mlp == "moe":
                 m = self.moe
-                p += d * m.num_experts
-                p += m.num_experts * 3 * d * m.d_ff
+                p += d * m.num_experts + (m.num_experts if m.score_bias
+                                          else 0)
+                p += m.held * 3 * d * m.d_ff
                 p += m.num_shared * 3 * d * m.d_ff
             elif spec.mlp == "rwkv_cmix":
                 p += 2 * d * int(3.5 * d) + d * d
@@ -184,8 +207,8 @@ class ModelConfig:
             return self._param_count_exact()
         d = self.d_model
         m = self.moe
-        full_expert = m.num_experts * 3 * d * m.d_ff
-        active_expert = m.top_k * 3 * d * m.d_ff
+        full_expert = m.held * 3 * d * m.d_ff
+        active_expert = min(m.top_k, m.held) * 3 * d * m.d_ff
         n_moe_layers = sum(
             seg.repeats * sum(1 for s in seg.unit if s.mlp == "moe")
             for seg in self.segments)
@@ -224,6 +247,7 @@ ARCH_IDS = [
     "llama4-scout-17b-a16e",
     "musicgen-large",
     "chameleon-34b",
+    "moonlight-16b-a3b",
 ]
 
 
@@ -269,10 +293,12 @@ def reduced(cfg: ModelConfig, *, d_model: int = 64,
     if cfg.moe is not None:
         changes["moe"] = dataclasses.replace(
             cfg.moe, num_experts=4, top_k=min(cfg.moe.top_k, 2),
-            d_ff=2 * d_model)
+            d_ff=2 * d_model,
+            held_experts=2 if cfg.moe.held_experts else 0, first_held=0)
     if cfg.mla is not None:
         changes["mla"] = MLAConfig(
-            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            q_lora_rank=32 if cfg.mla.q_lora_rank else 0,
+            kv_lora_rank=16, qk_nope_head_dim=16,
             qk_rope_head_dim=8, v_head_dim=16)
         changes["head_dim"] = 16
     if cfg.ssm is not None:

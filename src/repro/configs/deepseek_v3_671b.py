@@ -2,7 +2,9 @@
 
 MLA attention (q_lora 1536, kv_lora 512, rope 64), 61 layers with the first
 3 dense (d_ff 18432), then MoE: 1 shared + 256 routed experts (d_ff 2048),
-top-8, sigmoid router; MTP head depth 1.
+top-8, sigmoid router with the selection-only correction bias and routed
+scale 2.5; MTP head depth 1.  Not modelled: the group-limited choice
+(8 groups, top-4), which the published config also states.
 """
 
 from .base import (LayerSpec, MLAConfig, ModelConfig, MoEConfig, Segment)
@@ -26,7 +28,8 @@ CONFIG = ModelConfig(
                   qk_nope_head_dim=128, qk_rope_head_dim=64,
                   v_head_dim=128),
     moe=MoEConfig(num_experts=256, top_k=8, d_ff=2048, num_shared=1,
-                  router_fn="sigmoid", normalize_weights=True),
+                  router_fn="sigmoid", normalize_weights=True,
+                  score_bias=True, routed_scale=2.5),
     mtp_depth=1,
     rope_theta=1e4,
     source="arXiv:2412.19437; hf",

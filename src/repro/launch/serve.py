@@ -64,7 +64,13 @@ class BatchedServer:
     request id as ``batch``; counters ``serve.batches``,
     ``serve.decode_steps``, ``serve.tokens_decoded`` (batch × steps),
     ``serve.tokens_returned`` and ``serve.cache_donated`` (decode steps
-    whose incoming KV cache the step consumed in place).
+    whose incoming KV cache the step consumed in place).  A model with
+    expert layers also counts, over its prefills and decode steps,
+    ``moe.pairs_routed`` (tokens × top-k × expert layers),
+    ``moe.pairs_held`` (the pairs routed to an expert this model holds)
+    and ``moe.pairs_max_expert`` (the sum over layer-steps of the busiest
+    held expert's pairs); each step yields its pairs per layer and held
+    expert, read with the token the loop already waits for.
 
     The decode step donates its cache argument: each step writes this
     token's entries into the cache it was given, so the cache a caller
@@ -78,13 +84,18 @@ class BatchedServer:
         self.max_len = max_len
         self._prefill_fn = _prefill
         self._decode_fn = _decode
+        #: expert layers, whose steps also yield their routed pairs
+        self._moe_layers = sum(seg.repeats * sum(s.mlp == "moe"
+                                                 for s in seg.unit)
+                               for seg in cfg.segments)
+        load = {"expert_load": True} if self._moe_layers else {}
 
         # named for the trace's modules: jit_prefill_step, jit_decode_step
         def prefill_step(p, t):
-            return _prefill(p, t, cfg, max_len)
+            return _prefill(p, t, cfg, max_len, **load)
 
         def decode_step(p, tok, cache, ln):
-            return _decode(p, tok, cache, ln, cfg)
+            return _decode(p, tok, cache, ln, cfg, **load)
 
         # Both steps go through the dataflow compiler driver.  The "xla"
         # backend executes exactly as jax.jit did, but the Compiled
@@ -110,7 +121,8 @@ class BatchedServer:
         return compiled.report()
 
     def prefill(self, requests: list[Request]):
-        """Batched prompt forward: ``(last-position logits, KV cache)``.
+        """Batched prompt forward: ``(last-position logits, KV cache)``,
+        and for a model with expert layers their pairs per held expert.
         Prompts are left-aligned and right-padded with zeros (masked by
         position)."""
         import jax.numpy as jnp
@@ -125,9 +137,11 @@ class BatchedServer:
         import jax.numpy as jnp
         S = max(len(r.prompt) for r in requests)
         batch = requests[0].id
+        moe = self._moe_layers > 0
         with trace.span("serve.prefill", batch=batch) as prefill:
-            logits, cache = self.prefill(requests)
+            logits, cache, *load = self.prefill(requests)
             logits = jax.block_until_ready(logits)
+        loads = [(len(requests) * S, np.asarray(load[0]))] if moe else []
 
         gen = max(r.max_new_tokens for r in requests)
         tokens = []
@@ -145,15 +159,22 @@ class BatchedServer:
             donated = 0
             for step in range(gen):
                 with trace.span("serve.sync", batch=batch):
-                    tokens.append(np.asarray(tok))
+                    if step and moe:
+                        t, lo = jax.device_get((tok, load[0]))
+                        tokens.append(t)
+                        loads.append((len(requests), lo))
+                    else:
+                        tokens.append(np.asarray(tok))
                 with trace.span("serve.dispatch", batch=batch):
                     given = jax.tree_util.tree_leaves(cache)[0]
-                    logits, cache = decode(self.params,
-                                           tok.astype(jnp.int32), cache,
-                                           length + step)
+                    logits, cache, *load = decode(
+                        self.params, tok.astype(jnp.int32), cache,
+                        length + step)
                     tok = jnp.argmax(logits, -1)
                 donated += given.is_deleted()
             jax.block_until_ready(logits)
+            if moe:
+                loads.append((len(requests), np.asarray(load[0])))
 
         outs = []
         seq = np.stack(tokens, 1)  # (B, gen)
@@ -166,7 +187,19 @@ class BatchedServer:
         trace.count("serve.tokens_returned",
                     sum(len(o.tokens) for o in outs))
         trace.count("serve.cache_donated", donated)
+        if moe:
+            self._count_pairs(loads)
         return outs
+
+    def _count_pairs(self, loads: list[tuple[int, np.ndarray]]) -> None:
+        """The ``moe.*`` counters of a batch's steps: ``(tokens, pairs per
+        expert layer and held expert)`` for each."""
+        k = self.cfg.moe.top_k
+        trace.count("moe.pairs_routed",
+                    sum(t for t, _ in loads) * k * self._moe_layers)
+        trace.count("moe.pairs_held", sum(int(a.sum()) for _, a in loads))
+        trace.count("moe.pairs_max_expert",
+                    sum(int(a.max(axis=1).sum()) for _, a in loads))
 
 
 # ---------------------------------------------------------------------------

@@ -189,9 +189,11 @@ def mla_prefill(params: dict, x: jax.Array, cfg, max_len: int
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     q_nope, q_pe, c_kv, k_pe = _mla_qkv(params, x, cfg, positions)
     out = _mla_attend(params, q_nope, q_pe, c_kv, k_pe, cfg, causal=True)
-    pad = ((0, 0), (0, max_len - S), (0, 0))
-    cache = {"c_kv": jnp.pad(c_kv, pad).astype(cfg.np_dtype),
-             "k_pe": jnp.pad(k_pe, pad).astype(cfg.np_dtype)}
+    pe_pad = ((0, 0), (0, 0), (0, _rope_len(max_len) - S))
+    cache = {"c_kv": jnp.pad(c_kv, ((0, 0), (0, max_len - S), (0, 0))
+                             ).astype(cfg.np_dtype),
+             "k_pe": jnp.pad(k_pe.transpose(0, 2, 1), pe_pad
+                             ).astype(cfg.np_dtype)}
     return out, cache
 
 
@@ -333,7 +335,17 @@ def _decode_masked_scan(q, k_cache, v_cache, lengths, chunk: int,
 # The KV cache stores only the compressed latent c_kv (kv_lora_rank) plus the
 # decoupled RoPE key (rope_head_dim) — the memory stage shrinks by ~an order
 # of magnitude, which is precisely the paper's "customize the memory
-# interface per access stream" (§III-B2) applied to the KV cache.
+# interface per access stream" (§III-B2) applied to the KV cache.  The RoPE
+# key is kept position-minor, (B, rope, S'), with S' the cache length
+# rounded up to whole 128-lane tiles: with a 64-wide or a ragged minor axis
+# the TPU's default layout puts the batch minor, unlike the layout the
+# decode scan reads the key in, and the compiler then copies the whole
+# stacked cache in and out of the scan every step.
+
+def _rope_len(max_len: int) -> int:
+    """Positions of the RoPE key cache: ``max_len`` in whole 128s."""
+    return -(-max_len // 128) * 128
+
 
 def mla_init(rng, cfg) -> dict:
     m = cfg.mla
@@ -341,11 +353,18 @@ def mla_init(rng, cfg) -> dict:
     H = cfg.num_heads
     ks = jax.random.split(rng, 8)
     qk_head = m.qk_nope_head_dim + m.qk_rope_head_dim
+    if m.q_lora_rank:
+        query = {
+            "w_dq": layers._dense_init(ks[0], d, m.q_lora_rank,
+                                       cfg.np_dtype),
+            "q_norm": layers.rmsnorm_init(m.q_lora_rank, cfg.np_dtype),
+            "w_uq": layers._dense_init(ks[1], m.q_lora_rank, H * qk_head,
+                                       cfg.np_dtype)}
+    else:
+        query = {"w_q": layers._dense_init(ks[0], d, H * qk_head,
+                                           cfg.np_dtype)}
     return {
-        "w_dq": layers._dense_init(ks[0], d, m.q_lora_rank, cfg.np_dtype),
-        "q_norm": layers.rmsnorm_init(m.q_lora_rank, cfg.np_dtype),
-        "w_uq": layers._dense_init(ks[1], m.q_lora_rank, H * qk_head,
-                                   cfg.np_dtype),
+        **query,
         "w_dkv": layers._dense_init(
             ks[2], d, m.kv_lora_rank + m.qk_rope_head_dim, cfg.np_dtype),
         "kv_norm": layers.rmsnorm_init(m.kv_lora_rank, cfg.np_dtype),
@@ -360,10 +379,13 @@ def _mla_qkv(params, x, cfg, positions):
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.num_heads
-    # query path
-    cq = layers.rmsnorm_apply(params["q_norm"], x @ params["w_dq"])
-    q = (cq @ params["w_uq"]).reshape(
-        B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
+    norm = layers.make_norm("rmsnorm", cfg.norm_eps)[1]
+    # query path: through the query LoRA, or projected directly
+    if m.q_lora_rank:
+        q = norm(params["q_norm"], x @ params["w_dq"]) @ params["w_uq"]
+    else:
+        q = x @ params["w_q"]
+    q = q.reshape(B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_pe = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
     q_pe = layers.apply_rope(
         q_pe.transpose(0, 2, 1, 3), positions[:, None, :],
@@ -371,7 +393,7 @@ def _mla_qkv(params, x, cfg, positions):
     # kv latent path
     ckv_full = x @ params["w_dkv"]
     c_kv, k_pe = jnp.split(ckv_full, [m.kv_lora_rank], axis=-1)
-    c_kv = layers.rmsnorm_apply(params["kv_norm"], c_kv)
+    c_kv = norm(params["kv_norm"], c_kv)
     k_pe = layers.apply_rope(k_pe[:, None], positions[:, None, :],
                              cfg.rope_theta)[:, 0]
     return q_nope, q_pe, c_kv, k_pe
@@ -413,7 +435,7 @@ def mla_init_cache(cfg, batch: int, max_len: int) -> dict:
     m = cfg.mla
     return {
         "c_kv": jnp.zeros((batch, max_len, m.kv_lora_rank), cfg.np_dtype),
-        "k_pe": jnp.zeros((batch, max_len, m.qk_rope_head_dim),
+        "k_pe": jnp.zeros((batch, m.qk_rope_head_dim, _rope_len(max_len)),
                           cfg.np_dtype),
     }
 
@@ -425,7 +447,7 @@ def mla_decode_entries(params: dict, x: jax.Array, length: jax.Array,
     B = x.shape[0]
     positions = jnp.broadcast_to(length[None], (B,))[:, None]
     q_nope, q_pe, c_kv, k_pe = _mla_qkv(params, x, cfg, positions)
-    return (q_nope, q_pe), {"c_kv": c_kv, "k_pe": k_pe}
+    return (q_nope, q_pe), {"c_kv": c_kv, "k_pe": k_pe.transpose(0, 2, 1)}
 
 
 def mla_decode_attend(params: dict, q: tuple, cache: dict,
@@ -435,8 +457,10 @@ def mla_decode_attend(params: dict, q: tuple, cache: dict,
         return _mla_decode_absorbed(params, q_nope, q_pe, cache["c_kv"],
                                     cache["k_pe"], length, cfg)
     # naive: decompress the whole cache and attend (baseline)
-    return _mla_attend(params, q_nope, q_pe, cache["c_kv"], cache["k_pe"],
-                       cfg, causal=True, q_offset=length)
+    S = cache["c_kv"].shape[1]
+    return _mla_attend(params, q_nope, q_pe, cache["c_kv"],
+                       cache["k_pe"][:, :, :S].transpose(0, 2, 1), cfg,
+                       causal=True, q_offset=length)
 
 
 def _mla_decode_absorbed(params, q_nope, q_pe, c_cache, p_cache, length,
@@ -461,11 +485,11 @@ def _mla_decode_absorbed(params, q_nope, q_pe, c_cache, p_cache, length,
     q_lat = jnp.einsum("bqhn,rhn->bhr", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32))
     cf = c_cache.astype(jnp.float32)                 # (B, S, r)
-    pf = p_cache.astype(jnp.float32)                 # (B, S, rope)
+    pf = p_cache.astype(jnp.float32)                 # (B, rope, S')
     scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
     logits = (jnp.einsum("bhr,bsr->bhs", q_lat, cf)
-              + jnp.einsum("bqhp,bsp->bhs",
-                           q_pe.astype(jnp.float32), pf)) * scale
+              + jnp.einsum("bqhp,bps->bhs", q_pe.astype(jnp.float32),
+                           pf)[..., :S]) * scale
     mask = jnp.arange(S)[None, None, :] <= length
     logits = jnp.where(mask, logits, -1e30)
     w = jax.nn.softmax(logits, axis=-1)              # (B, H, S)

@@ -7,6 +7,8 @@ All inits work under ``jax.eval_shape`` (the dry-run never allocates).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -56,15 +58,17 @@ def nonparametric_ln_apply(x: jax.Array, eps: float = 1e-5) -> jax.Array:
     return layernorm_apply({}, x, eps)
 
 
-def make_norm(kind: str):
-    """Returns (init(d, dtype) -> params, apply(params, x) -> y)."""
+def make_norm(kind: str, eps: float | None = None):
+    """Returns (init(d, dtype) -> params, apply(params, x) -> y); ``eps``
+    None keeps the norm's own default."""
+    kw = {} if eps is None else {"eps": eps}
     if kind == "rmsnorm":
-        return rmsnorm_init, rmsnorm_apply
+        return rmsnorm_init, functools.partial(rmsnorm_apply, **kw)
     if kind == "layernorm":
-        return layernorm_init, layernorm_apply
+        return layernorm_init, functools.partial(layernorm_apply, **kw)
     if kind == "nonparametric_ln":
         return (lambda d, dtype: {}), (
-            lambda params, x: nonparametric_ln_apply(x))
+            lambda params, x: nonparametric_ln_apply(x, **kw))
     raise ValueError(f"unknown norm {kind!r}")
 
 

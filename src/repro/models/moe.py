@@ -1,18 +1,32 @@
-"""Mixture-of-Experts with top-k routing, shared experts, capacity dispatch.
+"""Mixture-of-Experts: top-k routing over all experts, the held experts'
+FFNs, shared experts.
 
 MoE dispatch is the framework's second canonical "memory operation" in the
 paper's taxonomy: a data-dependent scatter (tokens → expert buffers)
 followed by a gather (expert outputs → token order), with the expert GEMMs
 as the long-latency compute stage in between.  Algorithm 1 therefore cuts
 stages exactly at dispatch and combine — which is how the layer is written:
-scatter → batched expert FFN → gather, so the all-to-all traffic induced by
-expert-parallel sharding (experts on the ``model`` axis) overlaps with the
-expert GEMMs under the XLA scheduler.
+dispatch → batched expert FFN → combine.
 
-Dispatch is sort-free scatter-add with per-expert capacity
-``C = ceil(k·T/E · capacity_factor)``; overflow tokens are dropped (their
-residual passes through — standard Switch behaviour), and the combine
-re-weights by the router probabilities.
+The router scores all ``num_experts`` experts (softmax, or DeepSeek-V3's
+sigmoid with a selection-only correction bias and a routed scale).  A
+layer may hold only some of them — the chip's share under expert
+parallelism (``MoEConfig.held_experts``): it computes the pairs routed to
+its own experts, and pairs routed to experts held elsewhere add nothing
+here.  Two dispatches:
+
+* **grouped** (serving: prefill and decode, ``layer`` given): the
+  (token, held expert) pairs are sorted by expert and each expert's FFN
+  runs once over its own rows (``jax.lax.ragged_dot``) — every pair is
+  computed exactly once, none is dropped, no expert sees a token that did
+  not choose it.  The experts' weights come stacked over the layers of a
+  segment, and ``layer`` picks this one.
+* **capacity** (training ``forward``): per-expert buffers of
+  ``C = ceil(k·T/E · capacity_factor)`` rows, overflow pairs dropped
+  (their residual passes through — standard Switch behaviour).  The
+  fixed-shape (E, C, d) buffer is what GSPMD partitions over the expert
+  axis as an all-to-all, which the dry-run's int8 wire and device-limited
+  routing knobs act on.
 """
 
 from __future__ import annotations
@@ -24,14 +38,20 @@ import numpy as np
 
 from . import layers
 
+#: tokens per grouped dispatch: a longer input (a long prefill) runs in
+#: blocks of this many tokens, so that its k·T sorted rows and their FFN
+#: activations stay a bounded buffer
+GROUP_TOKENS = 4096
+
 
 def moe_init(rng, cfg) -> dict:
     m = cfg.moe
     d = cfg.d_model
     ks = jax.random.split(rng, 5)
-    E = m.num_experts
+    E = m.held
     p = {
-        "router": layers._dense_init(ks[0], d, E, jnp.float32, scale=0.02),
+        "router": layers._dense_init(ks[0], d, m.num_experts, jnp.float32,
+                                     scale=0.02),
         "w_gate": (jax.random.normal(ks[1], (E, d, m.d_ff), jnp.float32)
                    / np.sqrt(d)).astype(cfg.np_dtype),
         "w_up": (jax.random.normal(ks[2], (E, d, m.d_ff), jnp.float32)
@@ -39,48 +59,152 @@ def moe_init(rng, cfg) -> dict:
         "w_down": (jax.random.normal(ks[3], (E, m.d_ff, d), jnp.float32)
                    / np.sqrt(m.d_ff)).astype(cfg.np_dtype),
     }
+    if m.score_bias:
+        p["router_bias"] = jnp.zeros((m.num_experts,), jnp.float32)
     if m.num_shared > 0:
         p["shared"] = layers.mlp_init(ks[4], d, m.d_ff * m.num_shared,
                                       cfg.act, cfg.np_dtype)
     return p
 
 
-def moe_apply(params: dict, x: jax.Array, cfg) -> tuple[jax.Array, dict]:
-    """x: (B, S, d) → (y, aux) with load-balance metrics in aux."""
-    m = cfg.moe
-    B, S, d = x.shape
-    T = B * S
+def route(params: dict, xt: jax.Array, m
+          ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Top-k over all ``m.num_experts``: ``(ids (T, k), weights (T, k) f32,
+    scores (T, E))``.  The correction bias, if any, moves the choice only;
+    the weights are the chosen experts' scores, normalised if configured,
+    times ``m.routed_scale``."""
+    T = xt.shape[0]
     E, k = m.num_experts, m.top_k
-    xt = x.reshape(T, d)
-
-    # --- router (fp32 for numerics) ---------------------------------------
-    logits = xt.astype(jnp.float32) @ params["router"]     # (T, E)
+    logits = xt.astype(jnp.float32) @ params["router"].astype(jnp.float32)
     if m.router_fn == "sigmoid":   # DeepSeek-V3 style
         scores = jax.nn.sigmoid(logits)
     else:
         scores = jax.nn.softmax(logits, axis=-1)
+    choice = scores
+    if m.score_bias:
+        choice = choice + params["router_bias"].astype(jnp.float32)
     if m.route_groups > 1 and m.route_device_limit > 0:
         # §Perf: device-limited routing (DeepSeek-V3 node-limited routing):
         # keep only the top-M expert groups per token before the top-k, so
         # each token's dispatch fans out to ≤ M EP devices.
         G = m.route_groups
-        gs = scores.reshape(T, G, E // G).max(axis=-1)      # (T, G)
+        gs = choice.reshape(T, G, E // G).max(axis=-1)      # (T, G)
         _, top_g = jax.lax.top_k(gs, m.route_device_limit)
-        gmask = jax.nn.one_hot(top_g, G, dtype=scores.dtype).sum(1)
-        scores = (scores.reshape(T, G, E // G)
-                  * gmask[..., None]).reshape(T, E)
-    top_w, top_ids = jax.lax.top_k(scores, k)              # (T, k)
+        gmask = jax.nn.one_hot(top_g, G, dtype=jnp.bool_).any(1)
+        choice = jnp.where(jnp.repeat(gmask, E // G, axis=1), choice,
+                           -jnp.inf)
+    _, top_ids = jax.lax.top_k(choice, k)                  # (T, k)
+    top_w = jnp.take_along_axis(scores, top_ids, axis=1)
     if m.normalize_weights:
         top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    return top_ids, top_w * m.routed_scale, scores
+
+
+def _held_key(top_ids: jax.Array, m) -> jax.Array:
+    """Each pair's held expert (0 .. held-1), or ``held`` for a pair routed
+    to an expert held elsewhere."""
+    local = top_ids - m.first_held
+    return jnp.where((local >= 0) & (local < m.held), local, m.held)
+
+
+def _grouped(params: dict, xt: jax.Array, top_ids: jax.Array,
+             top_w: jax.Array, m, layer) -> tuple[jax.Array, jax.Array]:
+    """Every (token, held expert) pair once: ``(y (T, d) f32, pairs per held
+    expert (held,) int32)``.  The expert weights are stacked over layers
+    and this is layer ``layer``: the grouped matmuls take the whole stack,
+    every other layer's groups empty (a layer's slice of the stack would be
+    copied out of it for the custom call)."""
+    T, d = xt.shape
+    k = top_ids.shape[1]
+    pairs = T * k
+    key = _held_key(top_ids, m).reshape(pairs)
+    # a counting sort by expert (pairs held elsewhere last), stable: each
+    # pair's row ``back`` in the grouped order, and the pair at each row
+    counts = jnp.bincount(key, length=m.held + 1).astype(jnp.int32)
+    onehot = (key[:, None] == jnp.arange(m.held + 1)).astype(jnp.int32)
+    rank = (jnp.cumsum(onehot, axis=0) * onehot).sum(axis=1) - 1
+    back = (jnp.cumsum(counts) - counts)[key] + rank
+    order = jnp.zeros((pairs,), jnp.int32).at[back].set(
+        jnp.arange(pairs, dtype=jnp.int32))
+    sizes = counts[:m.held]
+    n_all = params["w_gate"].shape[0] * m.held
+    w = {n: params[n].reshape((n_all,) + params[n].shape[2:])
+         for n in ("w_gate", "w_up", "w_down")}
+    groups = jax.lax.dynamic_update_slice(
+        jnp.zeros((n_all,), jnp.int32), sizes, (layer * m.held,))
+    rows = xt[order // k]
+    gate = jax.lax.ragged_dot(rows, w["w_gate"], groups)
+    up = jax.lax.ragged_dot(rows, w["w_up"], groups)
+    h = (jax.nn.silu(gate.astype(jnp.float32))
+         * up.astype(jnp.float32)).astype(xt.dtype)
+    out = jax.lax.ragged_dot(h, w["w_down"], groups)       # (T·k, d)
+    # each pair's output back in token order; a row past the held pairs
+    # belongs to no group and holds anything: select, never scale
+    yk = out[back].reshape(T, k, d).astype(jnp.float32)
+    held = (key < m.held).reshape(T, k, 1)
+    return jnp.where(held, yk * top_w[..., None], 0.0).sum(axis=1), sizes
+
+
+def _grouped_blocks(params, xt, top_ids, top_w, m, layer):
+    """:func:`_grouped` over blocks of at most :data:`GROUP_TOKENS`."""
+    T, d = xt.shape
+    if T <= GROUP_TOKENS:
+        return _grouped(params, xt, top_ids, top_w, m, layer)
+    n = -(-T // GROUP_TOKENS)
+    pad = n * GROUP_TOKENS - T
+    # padded tokens are routed to no held expert
+    ids = jnp.pad(top_ids, ((0, pad), (0, 0)),
+                  constant_values=m.first_held + m.held)
+    blocks = (jnp.pad(xt, ((0, pad), (0, 0))), ids,
+              jnp.pad(top_w, ((0, pad), (0, 0))))
+    y, sizes = jax.lax.map(
+        lambda b: _grouped(params, *b, m, layer),
+        jax.tree_util.tree_map(
+            lambda a: a.reshape((n, GROUP_TOKENS) + a.shape[1:]), blocks))
+    return y.reshape(n * GROUP_TOKENS, d)[:T], sizes.sum(axis=0)
+
+
+def moe_apply(params: dict, x: jax.Array, cfg, *,
+              layer: jax.Array | None = None) -> tuple[jax.Array, dict]:
+    """x: (B, S, d) → (y, aux), y in the model's dtype.  The router reads
+    ``x`` as given (float32 for exact routing), the experts in the model's
+    dtype.  With ``layer``, the grouped dispatch: the expert weights are
+    stacked over layers and this is that one.  Without, the capacity
+    dispatch over one layer's weights, and ``aux`` also has the
+    load-balance loss and the share of held pairs dropped.
+    ``aux["load"]`` (held,) int32 is the pairs routed to each held
+    expert."""
+    m = cfg.moe
+    B, S, d = x.shape
+    top_ids, top_w, scores = route(params, x.reshape(B * S, d), m)
+    xt = x.reshape(B * S, d).astype(cfg.np_dtype)
+    if layer is not None:
+        y, load = _grouped_blocks(params, xt, top_ids, top_w, m, layer)
+        aux = {"load": load}
+    else:
+        y, aux = _capacity(params, xt, top_ids, top_w, scores, m)
+    y = y.astype(cfg.np_dtype)
+    # --- shared experts (always-on streaming partition) ---------------------
+    if m.num_shared > 0:
+        y = y + layers.mlp_apply(params["shared"], xt, cfg.act)
+    return y.reshape(B, S, d), aux
+
+
+def _capacity(params, xt, top_ids, top_w, scores, m):
+    """The capacity dispatch over the held experts: ``(y (T, d), aux)``."""
+    T, d = xt.shape
+    E, k = m.held, m.top_k
+    key = _held_key(top_ids, m)                            # (T, k)
+    held = key < E
 
     # --- capacity + position within expert --------------------------------
-    cap = int(np.ceil(k * T / E * m.capacity_factor))
-    onehot = jax.nn.one_hot(top_ids, E, dtype=jnp.int32)   # (T, k, E)
+    cap = int(np.ceil(k * T / m.num_experts * m.capacity_factor))
+    onehot = jax.nn.one_hot(key, E, dtype=jnp.int32)       # (T, k, E)
     flat = onehot.reshape(T * k, E)
     pos = jnp.cumsum(flat, axis=0) - flat                  # pos in expert
     pos = (pos * flat).sum(-1).reshape(T, k)               # (T, k)
-    keep = pos < cap
-    slot = top_ids * cap + pos                             # (T, k) in [0,E*cap)
+    keep = held & (pos < cap)
+    slot = jnp.where(held, key, 0) * cap + pos      # (T, k) in [0,E*cap)
 
     # --- scatter (dispatch: the memory stage) ------------------------------
     # §Perf knob: int8 dispatch — quantize the token payload before the
@@ -100,9 +224,9 @@ def moe_apply(params: dict, x: jax.Array, cfg) -> tuple[jax.Array, dict]:
         se = se.at[slot.reshape(-1)].add(
             s8.reshape(T * k, 1).astype(jnp.float16))
         xe = (xe_q.astype(jnp.float32)
-              * se.astype(jnp.float32)).astype(x.dtype)
+              * se.astype(jnp.float32)).astype(xt.dtype)
     else:
-        xe = jnp.zeros((E * cap, d), x.dtype)
+        xe = jnp.zeros((E * cap, d), xt.dtype)
         xe = xe.at[slot.reshape(-1)].add(src.reshape(T * k, d))
     xe = xe.reshape(E, cap, d)
 
@@ -110,23 +234,21 @@ def moe_apply(params: dict, x: jax.Array, cfg) -> tuple[jax.Array, dict]:
     gate = jnp.einsum("ecd,edf->ecf", xe, params["w_gate"])
     up = jnp.einsum("ecd,edf->ecf", xe, params["w_up"])
     h = (jax.nn.silu(gate.astype(jnp.float32))
-         * up.astype(jnp.float32)).astype(x.dtype)
+         * up.astype(jnp.float32)).astype(xt.dtype)
     ye = jnp.einsum("ecf,efd->ecd", h, params["w_down"])   # (E, cap, d)
 
     # --- gather (combine: the second memory stage) --------------------------
     yk = ye.reshape(E * cap, d)[slot.reshape(-1)].reshape(T, k, d)
     yk = yk * (top_w * keep).astype(jnp.float32)[..., None]
-    y = yk.sum(axis=1).astype(x.dtype)
-
-    # --- shared experts (always-on streaming partition) ---------------------
-    if m.num_shared > 0:
-        y = y + layers.mlp_apply(params["shared"], xt, cfg.act)
+    y = yk.sum(axis=1)
 
     # --- aux: load-balance loss (Switch-style) ------------------------------
-    me = scores.mean(axis=0)                                # (E,)
-    ce = (onehot.sum(axis=1).astype(jnp.float32)).mean(axis=0) * (E / k)
+    me = scores[:, m.first_held:m.first_held + E].mean(axis=0)   # (E,)
+    load = onehot.sum(axis=(0, 1))
+    ce = load.astype(jnp.float32) / T * (m.num_experts / k)
     aux = {
-        "lb_loss": (me * ce).sum() * E,
-        "dropped_frac": 1.0 - keep.mean(),
+        "lb_loss": (me * ce).sum() * m.num_experts,
+        "dropped_frac": 1.0 - keep.sum() / jnp.maximum(held.sum(), 1),
+        "load": load,
     }
-    return y.reshape(B, S, d), aux
+    return y, aux

@@ -76,7 +76,7 @@ def _mlp_init(rng, spec: LayerSpec, cfg) -> dict:
 
 def _layer_init(rng, spec: LayerSpec, cfg) -> dict:
     k1, k2, k3, k4 = jax.random.split(rng, 4)
-    ninit, _ = layers.make_norm(cfg.norm)
+    ninit, _ = layers.make_norm(cfg.norm, cfg.norm_eps)
     return {
         "norm1": ninit(cfg.d_model, cfg.np_dtype),
         "mixer": _mixer_init(k1, spec, cfg),
@@ -87,7 +87,7 @@ def _layer_init(rng, spec: LayerSpec, cfg) -> dict:
 
 def _layer_apply(params: dict, x: jax.Array, spec: LayerSpec, cfg,
                  aux_acc: dict) -> jax.Array:
-    _, napply = layers.make_norm(cfg.norm)
+    _, napply = layers.make_norm(cfg.norm, cfg.norm_eps)
     h1 = napply(params["norm1"], x)
     if spec.mixer == "attn":
         mix = attention.gqa_apply(params["mixer"], h1, cfg)
@@ -174,12 +174,55 @@ def _store(stack: Any, entries: Any, rep: jax.Array,
     return jax.tree_util.tree_map(put, stack, entries)
 
 
+#: an expert layer's weights that the serving path reads from the whole
+#: stack of the segment's repeats (see :func:`_with_expert_stacks`)
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def _expert_stacks(stacked: list, seg) -> list:
+    """Each sublayer's expert weights stacked over the segment's repeats
+    (None for a sublayer without experts)."""
+    return [{n: stacked[j]["mlp"][n] for n in _EXPERT_LEAVES}
+            if spec.mlp == "moe" else None
+            for j, spec in enumerate(seg.unit)]
+
+
+def _with_expert_stacks(rep_params: dict, stacks: dict | None) -> dict:
+    """One repeat's sublayer parameters with its experts' weights replaced
+    by their stack over the repeats.  The grouped matmul is a custom call
+    that needs its weights as one buffer: a repeat's slice of the stack
+    would be copied out of it every layer, so the dispatch takes the whole
+    stack and the repeat's index instead."""
+    if stacks is None:
+        return rep_params
+    return {**rep_params, "mlp": {**rep_params["mlp"], **stacks}}
+
+
+def _serve_mlp(params: dict, norm: dict, x: jax.Array, rep: jax.Array,
+               spec: LayerSpec, cfg, cmix
+               ) -> tuple[jax.Array, jax.Array | None]:
+    """The serving path's MLP over ``norm``-normed ``x``: ``(out, pairs per
+    held expert or None)``.  An expert layer routes the normed input in
+    float32, dispatches every pair and drops none; its experts' weights
+    are stacked over the repeats, ``rep`` this repeat."""
+    _, napply = layers.make_norm(cfg.norm, cfg.norm_eps)
+    if spec.mlp == "moe":
+        ff, aux = moe.moe_apply(params, napply(norm, x.astype(jnp.float32)),
+                                cfg, layer=rep)
+        return ff, aux["load"]
+    h = napply(norm, x)
+    if spec.mlp == "rwkv_cmix":
+        return cmix(h), None
+    return layers.mlp_apply(params, h, cfg.act), None
+
+
 def _layer_decode(params: dict, x: jax.Array, stack: dict, rep: jax.Array,
                   length: jax.Array, spec: LayerSpec, cfg
-                  ) -> tuple[jax.Array, dict]:
+                  ) -> tuple[jax.Array, dict, jax.Array | None]:
     """One sublayer of repeat ``rep`` over the segment's stacked cache
-    ``stack``; returns the stack with this token's entries written."""
-    _, napply = layers.make_norm(cfg.norm)
+    ``stack``; returns the stack with this token's entries written, and
+    an expert layer's pairs per held expert."""
+    _, napply = layers.make_norm(cfg.norm, cfg.norm_eps)
     h1 = napply(params["norm1"], x)
     new_stack = dict(stack)
     if spec.mixer in _ATTN_DECODE:
@@ -201,29 +244,22 @@ def _layer_decode(params: dict, x: jax.Array, stack: dict, rep: jax.Array,
         return _cmix_apply(params["mlp"], h, prev=prev)
 
     if cfg.parallel_block:
-        if spec.mlp == "moe":
-            ff, _ = moe.moe_apply(params["mlp"], h1, cfg)
-        elif spec.mlp == "rwkv_cmix":
-            ff = cmix(h1)
-        else:
-            ff = layers.mlp_apply(params["mlp"], h1, cfg.act)
-        return x + mix + ff, new_stack
+        ff, load = _serve_mlp(params["mlp"], params["norm1"], x, rep, spec,
+                              cfg, cmix)
+        return x + mix + ff, new_stack, load
 
     x = x + mix
-    h2 = napply(params["norm2"], x)
-    if spec.mlp == "moe":
-        ff, _ = moe.moe_apply(params["mlp"], h2, cfg)
-    elif spec.mlp == "rwkv_cmix":
-        ff = cmix(h2)
-    else:
-        ff = layers.mlp_apply(params["mlp"], h2, cfg.act)
-    return x + ff, new_stack
+    ff, load = _serve_mlp(params["mlp"], params["norm2"], x, rep, spec, cfg,
+                          cmix)
+    return x + ff, new_stack, load
 
 
-def _layer_prefill(params: dict, x: jax.Array, spec: LayerSpec, cfg,
-                   max_len: int) -> tuple[jax.Array, dict]:
-    """Forward over the prompt, emitting this layer's decode cache."""
-    _, napply = layers.make_norm(cfg.norm)
+def _layer_prefill(params: dict, x: jax.Array, rep: jax.Array | None,
+                   spec: LayerSpec, cfg, max_len: int
+                   ) -> tuple[jax.Array, dict, jax.Array | None]:
+    """Forward over the prompt, emitting this layer's decode cache (and an
+    expert layer's pairs per held expert)."""
+    _, napply = layers.make_norm(cfg.norm, cfg.norm_eps)
     h1 = napply(params["norm1"], x)
     new_cache: dict[str, Any] = {}
     if spec.mixer == "attn":
@@ -242,53 +278,68 @@ def _layer_prefill(params: dict, x: jax.Array, spec: LayerSpec, cfg,
         raise ValueError(spec.mixer)
     new_cache["mixer"] = mcache
 
+    def cmix(h):
+        new_cache["cmix_prev"] = h[:, -1:, :]
+        return _cmix_apply(params["mlp"], h)
+
     if cfg.parallel_block:
-        if spec.mlp == "moe":
-            ff, _ = moe.moe_apply(params["mlp"], h1, cfg)
-        elif spec.mlp == "rwkv_cmix":
-            ff = _cmix_apply(params["mlp"], h1)
-            new_cache["cmix_prev"] = h1[:, -1:, :]
-        else:
-            ff = layers.mlp_apply(params["mlp"], h1, cfg.act)
-        return x + mix + ff, new_cache
+        ff, load = _serve_mlp(params["mlp"], params["norm1"], x, rep, spec,
+                              cfg, cmix)
+        return x + mix + ff, new_cache, load
 
     x = x + mix
-    h2 = napply(params["norm2"], x)
-    if spec.mlp == "moe":
-        ff, _ = moe.moe_apply(params["mlp"], h2, cfg)
-    elif spec.mlp == "rwkv_cmix":
-        ff = _cmix_apply(params["mlp"], h2)
-        new_cache["cmix_prev"] = h2[:, -1:, :]
-    else:
-        ff = layers.mlp_apply(params["mlp"], h2, cfg.act)
-    return x + ff, new_cache
+    ff, load = _serve_mlp(params["mlp"], params["norm2"], x, rep, spec, cfg,
+                          cmix)
+    return x + ff, new_cache, load
+
+
+def _loads(per_segment: list) -> jax.Array:
+    """Stacked per-repeat loads of every segment → (expert layers, held)."""
+    return jnp.concatenate([a.reshape(-1, a.shape[-1])
+                            for a in per_segment], axis=0)
 
 
 def prefill(params: dict, tokens_or_embeds: jax.Array, cfg: ModelConfig,
-            max_len: int) -> tuple[jax.Array, dict]:
-    """Prompt forward + cache build.  Returns (last-position logits, cache)."""
+            max_len: int, *, expert_load: bool = False):
+    """Prompt forward + cache build.  Returns (last-position logits, cache),
+    and with ``expert_load`` the pairs that each expert layer routed to
+    each held expert, (expert layers, held) int32."""
     if cfg.frontend_stub and tokens_or_embeds.ndim == 3:
         x = tokens_or_embeds.astype(cfg.np_dtype)
     else:
         x = layers.embedding_apply(params["embed"], tokens_or_embeds)
     cache: dict[str, Any] = {}
+    loads = []
     for si, seg in enumerate(cfg.segments):
         stacked = params[f"segment_{si}"]
+        experts = _expert_stacks(stacked, seg)
 
-        def body(x, rep_params, seg=seg):
-            rep_cache = []
+        def body(carry, rep_params, seg=seg, experts=experts):
+            x, rep = carry
+            rep_cache, rep_load = [], []
             for j, spec in enumerate(seg.unit):
-                x, c = _layer_prefill(rep_params[j], x, spec, cfg, max_len)
+                x, c, load = _layer_prefill(
+                    _with_expert_stacks(rep_params[j], experts[j]), x, rep,
+                    spec, cfg, max_len)
                 rep_cache.append(c)
-            return x, rep_cache
+                if load is not None:
+                    rep_load.append(load)
+            return (x, None if rep is None else rep + 1), (rep_cache,
+                                                           rep_load)
 
-        x, seg_cache = jax.lax.scan(body, x, stacked)
+        # only a segment with expert layers counts its repeats
+        rep0 = jnp.zeros((), jnp.int32) if any(experts) else None
+        (x, _), (seg_cache, seg_load) = jax.lax.scan(body, (x, rep0),
+                                                     stacked)
         cache[f"segment_{si}"] = seg_cache
+        loads += seg_load
 
-    _, napply = layers.make_norm(cfg.norm)
+    _, napply = layers.make_norm(cfg.norm, cfg.norm_eps)
     x = napply(params["final_norm"], x[:, -1:, :])
     emb = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = layers.unembed_apply(emb, x)[:, 0]
+    if expert_load:
+        return logits, cache, _loads(loads)
     return logits, cache
 
 
@@ -318,7 +369,7 @@ def init_params(rng, cfg: ModelConfig) -> dict:
         "embed": layers.embedding_init(keys[0], cfg.vocab_size, cfg.d_model,
                                        cfg.np_dtype),
     }
-    ninit, _ = layers.make_norm(cfg.norm)
+    ninit, _ = layers.make_norm(cfg.norm, cfg.norm_eps)
     params["final_norm"] = ninit(cfg.d_model, cfg.np_dtype)
     if not cfg.tie_embeddings:
         params["unembed"] = layers.embedding_init(
@@ -371,7 +422,7 @@ def forward(params: dict, tokens_or_embeds: jax.Array,
         x, lbs = jax.lax.scan(body, x, stacked)
         total_aux["lb_loss"] = total_aux["lb_loss"] + lbs.sum()
 
-    _, napply = layers.make_norm(cfg.norm)
+    _, napply = layers.make_norm(cfg.norm, cfg.norm_eps)
     x = napply(params["final_norm"], x)
     emb = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = layers.unembed_apply(emb, x)
@@ -394,10 +445,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
 
 
 def decode_step(params: dict, token: jax.Array, cache: dict,
-                length: jax.Array, cfg: ModelConfig
-                ) -> tuple[jax.Array, dict]:
+                length: jax.Array, cfg: ModelConfig, *,
+                expert_load: bool = False):
     """One new token for every sequence.  token: (B,) int32; returns
-    (logits (B, vocab), new_cache).
+    (logits (B, vocab), new_cache), and with ``expert_load`` the pairs per
+    held expert as :func:`prefill` gives them.
 
     Each segment's stacked cache rides through the layer scan in the carry,
     and every layer writes only this token's entries into it, so the step
@@ -406,24 +458,32 @@ def decode_step(params: dict, token: jax.Array, cache: dict,
     x = layers.embedding_apply(params["embed"], token[:, None])
     length = jnp.asarray(length, jnp.int32)
     new_cache: dict[str, Any] = {}
+    loads = []
     for si, seg in enumerate(cfg.segments):
         stacked = params[f"segment_{si}"]
+        experts = _expert_stacks(stacked, seg)
 
-        def body(carry, rep_params, seg=seg):
+        def body(carry, rep_params, seg=seg, experts=experts):
             x, seg_cache, rep = carry
             seg_cache = list(seg_cache)
+            rep_load = []
             for j, spec in enumerate(seg.unit):
-                x, seg_cache[j] = _layer_decode(rep_params[j], x,
-                                                seg_cache[j], rep, length,
-                                                spec, cfg)
-            return (x, seg_cache, rep + 1), None
+                x, seg_cache[j], load = _layer_decode(
+                    _with_expert_stacks(rep_params[j], experts[j]), x,
+                    seg_cache[j], rep, length, spec, cfg)
+                if load is not None:
+                    rep_load.append(load)
+            return (x, seg_cache, rep + 1), rep_load
 
-        (x, new_cache[f"segment_{si}"], _), _ = jax.lax.scan(
+        (x, new_cache[f"segment_{si}"], _), seg_load = jax.lax.scan(
             body, (x, cache[f"segment_{si}"], jnp.zeros((), jnp.int32)),
             stacked)
+        loads += seg_load
 
-    _, napply = layers.make_norm(cfg.norm)
+    _, napply = layers.make_norm(cfg.norm, cfg.norm_eps)
     x = napply(params["final_norm"], x)
     emb = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = layers.unembed_apply(emb, x)[:, 0]
+    if expert_load:
+        return logits, new_cache, _loads(loads)
     return logits, new_cache

@@ -80,7 +80,8 @@ def _layer_param_count(cfg: ModelConfig, spec: LayerSpec,
     elif spec.mixer == "mla":
         m = cfg.mla
         qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-        p += d * m.q_lora_rank + m.q_lora_rank * cfg.num_heads * qk
+        p += (d * m.q_lora_rank + m.q_lora_rank * cfg.num_heads * qk
+              if m.q_lora_rank else d * cfg.num_heads * qk)
         p += d * (m.kv_lora_rank + m.qk_rope_head_dim)
         p += m.kv_lora_rank * cfg.num_heads * (m.qk_nope_head_dim
                                                + m.v_head_dim)
@@ -95,7 +96,7 @@ def _layer_param_count(cfg: ModelConfig, spec: LayerSpec,
         p += (3 if cfg.act == "silu" else 2) * d * cfg.d_ff
     elif spec.mlp == "moe":
         m = cfg.moe
-        n_e = m.top_k if active_only else m.num_experts
+        n_e = min(m.top_k, m.held) if active_only else m.held
         p += d * m.num_experts  # router
         p += (n_e + m.num_shared) * 3 * d * m.d_ff
     elif spec.mlp == "rwkv_cmix":

@@ -243,8 +243,10 @@ def cache_pspec(mesh: Mesh, path: tuple, leaf: Any,
 
     if last in ("k", "v") and len(core) == 4:        # (B, Hkv, S, hd)
         prefs = [dp, [axes.tp, None], [axes.tp, None], [None]]
-    elif last in ("c_kv", "k_pe") and len(core) == 3:  # (B, S, r)
+    elif last == "c_kv" and len(core) == 3:          # (B, S, r)
         prefs = [dp, [axes.tp, None], [None]]
+    elif last == "k_pe" and len(core) == 3:          # (B, rope, S)
+        prefs = [dp, [None], [axes.tp, None]]
     elif last == "h" and len(core) == 3:             # (B, dI, N)
         prefs = [dp, [axes.tp, None], [None]]
     elif last == "conv" and len(core) == 3:          # (B, K-1, dI)

@@ -148,6 +148,55 @@ def test_serve_decode_donates_cache_and_matches_reference():
     assert got == np.asarray(jnp.argmax(full[:, 7:], -1)).tolist()
 
 
+def test_serve_counts_expert_pairs():
+    """A model with expert layers: the server counts the pairs that every
+    prefill and decode step routed (tokens × top-k × expert layers), those
+    that went to a held expert, and the busiest held expert's per layer,
+    from the loads the steps yield; its tokens are those of a plain loop
+    over the model's steps.  A dense model counts none."""
+    import jax.numpy as jnp
+    from repro import trace
+    from repro.configs import load_config, reduced
+    from repro.launch.serve import BatchedServer, Request
+    from repro.models import decode_step, init_params, prefill
+
+    cfg = reduced(load_config("moonlight-16b-a3b"))   # 2 of 4 held, top-2
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    rng = np.random.default_rng(3)
+    reqs = [Request(i, rng.integers(0, cfg.vocab_size, size=(8,))
+                    .astype(np.int32), g) for i, g in enumerate([3, 5])]
+    trace.reset()
+    got = [r.tokens for r in BatchedServer(cfg, params,
+                                           max_len=32).serve(reqs)]
+    c = trace.counts()
+
+    prompts = jnp.asarray(np.stack([r.prompt for r in reqs]))
+    logits, cache, load = prefill(params, prompts, cfg, 32,
+                                  expert_load=True)
+    loads, tok, want = [np.asarray(load)], jnp.argmax(logits, -1), []
+    for step in range(5):
+        want.append(np.asarray(tok))
+        logits, cache, load = decode_step(
+            params, tok, cache, jnp.asarray(8 + step, jnp.int32), cfg,
+            expert_load=True)
+        loads.append(np.asarray(load))
+        tok = jnp.argmax(logits, -1)
+    assert got == [w[:r.max_new_tokens] for w, r in
+                   zip(np.stack(want, 1).tolist(), reqs)]
+    assert loads[0].shape == (2, 2)                   # expert layers, held
+    assert c["moe.pairs_routed"] == (2 * 8 + 2 * 5) * 2 * 2
+    assert c["moe.pairs_held"] == sum(int(a.sum()) for a in loads)
+    assert c["moe.pairs_max_expert"] == sum(int(a.max(1).sum())
+                                            for a in loads)
+    assert 0 < c["moe.pairs_held"] < c["moe.pairs_routed"]
+
+    dense = reduced(load_config("olmo-1b"), max_repeats=2)
+    trace.reset()
+    BatchedServer(dense, init_params(jax.random.PRNGKey(0), dense),
+                  max_len=32).serve(reqs)
+    assert not [k for k in trace.counts() if k.startswith("moe.")]
+
+
 def test_serve_prefill_matches_forward():
     """``BatchedServer.prefill`` returns the model's last-position logits
     for the batch, as the plain prefill forward gives them."""

@@ -167,9 +167,13 @@ def _decode_config(kind: str):
     """Two repeats of a decoder at TPU head widths (128), bf16: OLMo's
     layer with a bf16 or an int8 cache, or DeepSeek-V3's MLA layers with
     the absorbed decode (the naive one decompresses every layer's cache by
-    design)."""
+    design); or Moonlight-16B-A3B whole, at its published widths, as the
+    benchmark serves it (absorbed MLA with no query LoRA, 8 of 64 experts
+    held)."""
     import dataclasses
     from repro.configs.base import MLAConfig, load_config, reduced
+    if kind == "moonlight":
+        return load_config("moonlight-16b-a3b")
     arch = "deepseek-v3-671b" if kind == "mla" else "olmo-1b"
     cfg = reduced(load_config(arch), d_model=512)
     cfg = dataclasses.replace(cfg, dtype="bfloat16", attn_impl="auto")
@@ -199,18 +203,21 @@ def _hbm_buffers(hlo: str) -> list[tuple[str, str]]:
     return out
 
 
-@pytest.mark.parametrize("kind", ["bf16", "int8", "mla"])
+@pytest.mark.parametrize("kind", ["bf16", "int8", "mla", "moonlight"])
 def test_decode_step_updates_cache_in_place(one_chip, kind):
     """``jit_decode_step`` as the server builds it (cache donated) aliases
     every cache leaf to its output, materialises no HBM buffer with the
-    shape of one layer's cache, and needs less scratch than one layer's K: the
+    shape of one layer's cache (in any dtype: no float32 copy of the
+    latent cache either), and needs less scratch than one layer's K: the
     layer scan writes this token's entries into the carried cache and
-    never copies a layer or the stack.  One layer's K is 64 MiB here, too
-    large for the compiler to stage a copy of it in VMEM."""
+    never copies a layer or the stack.  One layer's K is 64 MiB here (the
+    latent ``c_kv`` 105 MiB at the Moonlight cell's batch of 128 and 840
+    positions), too large for the compiler to stage a copy of it in
+    VMEM."""
     from repro.launch.serve import BatchedServer
     from repro.models import init_cache, init_params
     cfg = _decode_config(kind)
-    batch, max_len = 32, 2048
+    batch, max_len = (128, 840) if kind == "moonlight" else (32, 2048)
 
     def sds(tree):
         return jax.tree_util.tree_map(
