@@ -12,11 +12,14 @@ head-group plumbing so models never see alignment constraints.
 from __future__ import annotations
 
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 from . import dataflow_matmul as _mm
 from . import flash_attention as _fa
+from . import grouped_matmul as _gmm
 from . import rmsnorm as _rn
 from . import spmv as _spmv
 from . import ref as ref  # re-exported for tests/benchmarks
@@ -111,3 +114,53 @@ def spmv(values, col_ids, x) -> jax.Array:
 
 
 csr_to_bsr = _spmv.csr_to_bsr
+
+
+def grouped_block_rows(rows: int, groups: int) -> int:
+    """Row tile of a grouped matmul over ``rows`` rows that ``groups``
+    groups share: twice a group's mean share, as a power of two from 16
+    (bf16's sublane tile) to 512.  A decode step's dozen rows an expert
+    take one tile of 32; a 4,096-token prefill block's hundreds, tiles of
+    512."""
+    return min(512, max(16, 1 << math.ceil(math.log2(2 * rows / groups))))
+
+
+def grouped_rows(rows: int, groups: int, block_rows: int) -> int:
+    """Rows of the tile-aligned layout of ``rows`` rows in ``groups``
+    groups: every group's padding to whole tiles counted."""
+    return _gmm.num_tiles(rows, groups, block_rows) * block_rows
+
+
+group_tiles = _gmm.group_tiles
+
+#: VMEM for one call's weight blocks, double buffered
+_GMM_WEIGHT_VMEM = 24 * 1024 * 1024
+
+
+def grouped_block_cols(K: int, N: int, weights: int, itemsize: int) -> int:
+    """Column tile of a grouped matmul of K rows of weights by N columns,
+    ``weights`` such matrices a call: the widest multiple of 128 lanes
+    dividing N whose blocks fit :data:`_GMM_WEIGHT_VMEM` double buffered
+    (N whole if it is not a multiple of 128)."""
+    if N % 128:
+        return N
+    lanes = N // 128
+    fits = [c for c in range(1, lanes + 1) if lanes % c == 0 and
+            2 * weights * K * 128 * c * itemsize <= _GMM_WEIGHT_VMEM]
+    return 128 * max(fits, default=1)
+
+
+def grouped_matmul(x: jax.Array, w: jax.Array, tiles: jax.Array,
+                   layer: jax.Array, *, block_rows: int,
+                   w_up: jax.Array | None = None) -> jax.Array:
+    """Grouped matmul of tile-aligned rows ``x`` against groups
+    ``layer · groups`` onwards of the stacked ``w`` (see
+    kernels/grouped_matmul.py); ``tiles`` from :func:`group_tiles`.  K is
+    whole, N in tiles of :func:`grouped_block_cols`."""
+    K, N = w.shape[1:]
+    block_cols = grouped_block_cols(K, N, 1 if w_up is None else 2,
+                                    w.dtype.itemsize)
+    return _gmm.grouped_matmul(x, w, tiles, layer, w_up,
+                               groups=tiles.shape[0] - 1,
+                               block_rows=block_rows, block_cols=block_cols,
+                               interpret=_interpret())

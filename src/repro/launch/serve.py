@@ -70,7 +70,11 @@ class BatchedServer:
     ``moe.pairs_held`` (the pairs routed to an expert this model holds)
     and ``moe.pairs_max_expert`` (the sum over layer-steps of the busiest
     held expert's pairs); each step yields its pairs per layer and held
-    expert, read with the token the loop already waits for.
+    expert, read with the token the loop already waits for.  It also
+    counts ``moe.grouped.pallas`` and ``moe.grouped.ragged_dot``: the
+    expert-layer dispatches (per prefill block and per decode step) that
+    ran on each path of the grouped matmul, the path the model takes on
+    this backend (:func:`repro.models.moe.grouped_path`).
 
     The decode step donates its cache argument: each step writes this
     token's entries into the cache it was given, so the cache a caller
@@ -189,7 +193,19 @@ class BatchedServer:
         trace.count("serve.cache_donated", donated)
         if moe:
             self._count_pairs(loads)
+            self._count_dispatches(len(requests), S, gen)
         return outs
+
+    def _count_dispatches(self, batch: int, prompt: int, gen: int) -> None:
+        """``moe.grouped.<path>``: a batch's expert-layer dispatches, its
+        prefill's blocks and one a decode step, on the path the steps were
+        traced with; the other path counts 0."""
+        from ..models import moe
+        n = self._moe_layers * (moe.grouped_blocks(batch * prompt)
+                                + gen * moe.grouped_blocks(batch))
+        path = moe.grouped_path()
+        for p in ("pallas", "ragged_dot"):
+            trace.count(f"moe.grouped.{p}", n if p == path else 0)
 
     def _count_pairs(self, loads: list[tuple[int, np.ndarray]]) -> None:
         """The ``moe.*`` counters of a batch's steps: ``(tokens, pairs per

@@ -17,10 +17,13 @@ here.  Two dispatches:
 
 * **grouped** (serving: prefill and decode, ``layer`` given): the
   (token, held expert) pairs are sorted by expert and each expert's FFN
-  runs once over its own rows (``jax.lax.ragged_dot``) — every pair is
-  computed exactly once, none is dropped, no expert sees a token that did
-  not choose it.  The experts' weights come stacked over the layers of a
-  segment, and ``layer`` picks this one.
+  runs once over its own rows — every pair is computed exactly once, none
+  is dropped, no expert sees a token that did not choose it.  The
+  experts' weights come stacked over the layers of a segment, and
+  ``layer`` picks this one.  On a TPU the matmuls are the Pallas grouped
+  matmul (``kernels/grouped_matmul.py``), which reads each held expert's
+  weights once a call; elsewhere ``jax.lax.ragged_dot``
+  (:func:`grouped_path`).
 * **capacity** (training ``forward``): per-expert buffers of
   ``C = ceil(k·T/E · capacity_factor)`` rows, overflow pairs dropped
   (their residual passes through — standard Switch behaviour).  The
@@ -37,6 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import layers
+from ..kernels import ops as kops
 
 #: tokens per grouped dispatch: a longer input (a long prefill) runs in
 #: blocks of this many tokens, so that its k·T sorted rows and their FFN
@@ -107,42 +111,91 @@ def _held_key(top_ids: jax.Array, m) -> jax.Array:
     return jnp.where((local >= 0) & (local < m.held), local, m.held)
 
 
+def grouped_path() -> str:
+    """The grouped dispatch's matmuls on the default backend: ``"pallas"``
+    (:func:`repro.kernels.ops.grouped_matmul`) where the kernel compiles
+    natively, on a TPU; ``"ragged_dot"`` (``jax.lax.ragged_dot``)
+    elsewhere, so a CPU never interprets a kernel inside every layer."""
+    return "pallas" if jax.default_backend() == "tpu" else "ragged_dot"
+
+
+def grouped_blocks(tokens: int) -> int:
+    """Grouped dispatches of one expert layer over ``tokens`` tokens."""
+    return -(-tokens // GROUP_TOKENS)
+
+
 def _grouped(params: dict, xt: jax.Array, top_ids: jax.Array,
              top_w: jax.Array, m, layer) -> tuple[jax.Array, jax.Array]:
     """Every (token, held expert) pair once: ``(y (T, d) f32, pairs per held
     expert (held,) int32)``.  The expert weights are stacked over layers
-    and this is layer ``layer``: the grouped matmuls take the whole stack,
-    every other layer's groups empty (a layer's slice of the stack would be
-    copied out of it for the custom call)."""
+    and this is layer ``layer``: the grouped matmuls take the whole stack
+    (a layer's slice of the stack would be copied out of it for the
+    custom call)."""
     T, d = xt.shape
     k = top_ids.shape[1]
     pairs = T * k
     key = _held_key(top_ids, m).reshape(pairs)
     # a counting sort by expert (pairs held elsewhere last), stable: each
-    # pair's row ``back`` in the grouped order, and the pair at each row
-    counts = jnp.bincount(key, length=m.held + 1).astype(jnp.int32)
+    # pair's rank among its expert's pairs
     onehot = (key[:, None] == jnp.arange(m.held + 1)).astype(jnp.int32)
-    rank = (jnp.cumsum(onehot, axis=0) * onehot).sum(axis=1) - 1
+    seen = jnp.cumsum(onehot, axis=0)
+    counts = seen[-1]
+    rank = (seen * onehot).sum(axis=1) - 1
+    sizes = counts[:m.held]
+    n_all = params["w_gate"].shape[0] * m.held
+    w = [params[n].reshape((n_all,) + params[n].shape[2:])
+         for n in ("w_gate", "w_up", "w_down")]
+    grouped = (_grouped_pallas if grouped_path() == "pallas"
+               else _grouped_ragged)
+    out, back = grouped(xt, w, key, rank, counts, m, layer, k)
+    # each pair's output back in token order, one of a token's k pairs at a
+    # time (no (T, k, d) float32 buffer); a row past the held pairs belongs
+    # to no group and holds anything: select, never scale
+    back = back.reshape(T, k)
+    held = (key < m.held).reshape(T, k)
+    y = jnp.zeros((T, d), jnp.float32)
+    for j in range(k):
+        y += jnp.where(held[:, j, None], out[back[:, j]].astype(jnp.float32)
+                       * top_w[:, j, None], 0.0)
+    return y, sizes
+
+
+def _grouped_ragged(xt, w, key, rank, counts, m, layer, k):
+    """The held experts' SwiGLU by ``ragged_dot`` over all the sorted
+    pairs, every other layer's groups of the stack empty: ``(out (T·k,
+    d), each pair's row)``."""
+    pairs = key.shape[0]
     back = (jnp.cumsum(counts) - counts)[key] + rank
     order = jnp.zeros((pairs,), jnp.int32).at[back].set(
         jnp.arange(pairs, dtype=jnp.int32))
-    sizes = counts[:m.held]
-    n_all = params["w_gate"].shape[0] * m.held
-    w = {n: params[n].reshape((n_all,) + params[n].shape[2:])
-         for n in ("w_gate", "w_up", "w_down")}
     groups = jax.lax.dynamic_update_slice(
-        jnp.zeros((n_all,), jnp.int32), sizes, (layer * m.held,))
+        jnp.zeros((w[0].shape[0],), jnp.int32), counts[:m.held],
+        (layer * m.held,))
     rows = xt[order // k]
-    gate = jax.lax.ragged_dot(rows, w["w_gate"], groups)
-    up = jax.lax.ragged_dot(rows, w["w_up"], groups)
+    gate = jax.lax.ragged_dot(rows, w[0], groups)
+    up = jax.lax.ragged_dot(rows, w[1], groups)
     h = (jax.nn.silu(gate.astype(jnp.float32))
          * up.astype(jnp.float32)).astype(xt.dtype)
-    out = jax.lax.ragged_dot(h, w["w_down"], groups)       # (T·k, d)
-    # each pair's output back in token order; a row past the held pairs
-    # belongs to no group and holds anything: select, never scale
-    yk = out[back].reshape(T, k, d).astype(jnp.float32)
-    held = (key < m.held).reshape(T, k, 1)
-    return jnp.where(held, yk * top_w[..., None], 0.0).sum(axis=1), sizes
+    return jax.lax.ragged_dot(h, w[2], groups), back
+
+
+def _grouped_pallas(xt, w, key, rank, counts, m, layer, k):
+    """The held experts' SwiGLU by the grouped-matmul kernel, in two calls
+    (gate and up fused): ``(out (rows, d), each pair's row)``.  Each held
+    expert's rows start at a whole row tile, and the pairs held elsewhere
+    get no row (their ``back`` is any row)."""
+    pairs = key.shape[0]
+    tm = kops.grouped_block_rows(pairs, m.num_experts)
+    rows = kops.grouped_rows(pairs, m.held, tm)
+    tiles = kops.group_tiles(counts[:m.held], tm)
+    first = jnp.append(tiles[:m.held] * tm, rows)
+    back = first[key] + rank
+    src = jnp.zeros((rows,), jnp.int32).at[back].set(
+        jnp.arange(pairs, dtype=jnp.int32) // k, mode="drop")
+    h = kops.grouped_matmul(xt[src], w[0], tiles, layer, w_up=w[1],
+                           block_rows=tm)
+    out = kops.grouped_matmul(h, w[2], tiles, layer, block_rows=tm)
+    return out, jnp.minimum(back, rows - 1)
 
 
 def _grouped_blocks(params, xt, top_ids, top_w, m, layer):
@@ -150,7 +203,7 @@ def _grouped_blocks(params, xt, top_ids, top_w, m, layer):
     T, d = xt.shape
     if T <= GROUP_TOKENS:
         return _grouped(params, xt, top_ids, top_w, m, layer)
-    n = -(-T // GROUP_TOKENS)
+    n = grouped_blocks(T)
     pad = n * GROUP_TOKENS - T
     # padded tokens are routed to no held expert
     ids = jnp.pad(top_ids, ((0, pad), (0, 0)),

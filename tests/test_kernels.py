@@ -223,3 +223,89 @@ def test_decoupled_gather_repeated_indices():
     want = decoupled_gather_ref(idx, table)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul (the held experts of a stacked expert layer)
+# ---------------------------------------------------------------------------
+
+#: (rows, routed experts the row tile is sized for, rows of each group
+#: (or a count of groups, each row routed to one of twice as many), repeats
+#: in the stack, layer, dtype, gate and up fused, K, N)
+_GMM_CASES = {
+    "empty-groups": (8, 8, [3, 0, 5, 0], 3, 0, jnp.float32, True, 128, 256),
+    "one-group-every-row": (100, 4, [0, 100, 0], 2, 1, jnp.bfloat16, False,
+                            128, 256),
+    "all-rows-elsewhere": (64, 8, [0, 0, 0, 0], 2, 1, jnp.float32, True,
+                           128, 128),
+    "groups-straddle-tiles": (96, 16, [17, 40, 1, 33], 4, 3, jnp.float32,
+                              False, 128, 256),
+    "decode-768": (768, 64, 8, 3, 2, jnp.bfloat16, True, 256, 384),
+    "prefill-4096k": (4096 * 2, 8, 4, 2, 0, jnp.bfloat16, True,
+                      128, 256),
+    "n-tiles": (40, 8, [9, 31], 2, 1, jnp.float32, True, 128, 512),
+}
+#: weight VMEM of a case, small enough to cut N into tiles
+_GMM_VMEM = {"n-tiles": 2 * 2 * 128 * 256 * 4}
+
+
+@pytest.mark.parametrize("case", list(_GMM_CASES))
+def test_grouped_matmul_sweep(case, monkeypatch):
+    """The kernel over a stack of repeats x groups, this call's groups at
+    ``layer``, against ``jax.lax.ragged_dot`` over the same groups packed
+    and a float64 numpy product per group: every group's rows, whatever
+    their count (none, all, across row tiles), N whole or in tiles, and
+    nothing of another repeat's groups."""
+    import jax
+    from repro.kernels import ops
+    P, experts, sizes, L, layer, dtype, fused, K, N = _GMM_CASES[case]
+    if case in _GMM_VMEM:
+        monkeypatch.setattr(ops, "_GMM_WEIGHT_VMEM", _GMM_VMEM[case])
+    n_tiles = N // ops.grouped_block_cols(K, N, 1 + fused,
+                                          jnp.dtype(dtype).itemsize)
+    assert (n_tiles > 1) == (case in _GMM_VMEM)
+    rng = np.random.default_rng(len(case))
+    if isinstance(sizes, int):      # half the rows held elsewhere
+        sizes = np.bincount(rng.integers(0, 2 * sizes, P),
+                            minlength=2 * sizes)[:sizes]
+    sizes = np.asarray(sizes, np.int32)
+    G = len(sizes)
+    tm = ops.grouped_block_rows(P, experts)
+    tiles = ops.group_tiles(jnp.asarray(sizes), tm)
+    M = ops.grouped_rows(P, G, tm)
+    x = _RNG.normal(size=(P, K)).astype(np.float32)
+    ws = [_RNG.normal(size=(L * G, K, N)).astype(np.float32) / np.sqrt(K)
+          for _ in range(2 if fused else 1)]
+    x, ws = (np.asarray(jnp.asarray(a).astype(dtype), np.float32)
+             for a in (x, ws))
+    # the rows in the kernel's layout: group e from tile tiles[e]
+    first = np.asarray(tiles)[:G] * tm
+    ends = np.cumsum(sizes) - sizes
+    laid = np.zeros((M, K), np.float32)
+    for e in range(G):
+        laid[first[e]:first[e] + sizes[e]] = x[ends[e]:ends[e] + sizes[e]]
+    got = np.asarray(ops.grouped_matmul(
+        jnp.asarray(laid).astype(dtype), jnp.asarray(ws[0]).astype(dtype),
+        tiles, jnp.int32(layer), block_rows=tm,
+        w_up=jnp.asarray(ws[1]).astype(dtype) if fused else None),
+        np.float32)
+    assert got.shape == (M, N)
+
+    stack_sizes = np.zeros(L * G, np.int32)
+    stack_sizes[layer * G:(layer + 1) * G] = sizes
+    ragged = [np.asarray(jax.lax.ragged_dot(
+        jnp.asarray(x), jnp.asarray(w), jnp.asarray(stack_sizes),
+        preferred_element_type=jnp.float32)) for w in ws]
+
+    def swiglu(a):
+        return a[0] if len(a) == 1 else a[0] / (1 + np.exp(-a[0])) * a[1]
+
+    ragged = swiglu(ragged)
+    rtol, atol = (1e-5, 1e-4) if dtype == jnp.float32 else (2e-2, 5e-2)
+    for e in range(G):
+        rows = slice(ends[e], ends[e] + sizes[e])
+        want = swiglu([x[rows].astype(np.float64) @ w[layer * G + e]
+                       for w in ws])
+        out = got[first[e]:first[e] + sizes[e]]
+        np.testing.assert_allclose(out, want, rtol=rtol, atol=atol)
+        np.testing.assert_allclose(out, ragged[rows], rtol=rtol, atol=atol)

@@ -197,6 +197,93 @@ def test_serve_counts_expert_pairs():
     assert not [k for k in trace.counts() if k.startswith("moe.")]
 
 
+#: the grouped dispatch on the tiny Moonlight: (tokens, repeat, dtype,
+#: every token routed to held expert 0)
+_GROUPED_CASES = {
+    "decode-first-repeat": (8, 0, "float32", False),
+    "two-blocks-last-repeat": (4100, 1, "float32", False),
+    "skew-bf16": (40, 1, "bfloat16", True),
+}
+
+
+@pytest.mark.parametrize("case", list(_GROUPED_CASES))
+def test_grouped_kernel_path_matches_ragged_dot(case, monkeypatch):
+    """``moe._grouped`` with the Pallas grouped matmul forced on (in
+    interpret mode here) against the ``ragged_dot`` path a CPU takes, on
+    the tiny Moonlight (2 of 4 experts held, top-2) with its experts
+    stacked over the segment's repeats: the same loads, and the same
+    output up to the rounding of gate and up (bf16 rounds them before the
+    SwiGLU on the ragged path only).  A prompt past ``GROUP_TOKENS`` runs
+    in two blocks; under skew one group holds every token."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    from repro.configs import load_config, reduced
+    from repro.models import init_params, moe
+
+    T, rep, dtype, skew = _GROUPED_CASES[case]
+    cfg = dataclasses.replace(reduced(load_config("moonlight-16b-a3b")),
+                              dtype=dtype)
+    stacked = init_params(jax.random.PRNGKey(4), cfg)["segment_1"][0]["mlp"]
+    params = {n: (a if n in ("w_gate", "w_up", "w_down") else
+                  jax.tree_util.tree_map(lambda v: v[rep], a))
+              for n, a in stacked.items()}
+    if skew:
+        params["router_bias"] = params["router_bias"].at[0].set(100.0)
+    x = jax.random.normal(jax.random.key(5), (1, T, cfg.d_model))
+
+    def apply(path):
+        monkeypatch.setattr(moe, "grouped_path", lambda: path)
+        y, aux = jax.jit(lambda p, x, r: moe.moe_apply(p, x, cfg, layer=r))(
+            params, x, jnp.int32(rep))
+        return np.asarray(y, np.float32), np.asarray(aux["load"])
+
+    (want, want_load), (got, load) = apply("ragged_dot"), apply("pallas")
+    np.testing.assert_array_equal(load, want_load)
+    assert load.sum() > 0
+    if skew:
+        assert load[0] == T
+    tol = 1e-5 if dtype == "float32" else 2e-2 * np.abs(want).max()
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("path", ["platform", "pallas"])
+def test_serve_counts_grouped_dispatches(path, monkeypatch):
+    """The server counts each expert-layer dispatch on the grouped matmul's
+    path: expert layers x (prefill blocks + decode steps), here a prefill
+    of 2 x 8 tokens in blocks of 8, and 0 on the other path.  The path is
+    the platform's (``ragged_dot`` on a CPU) unless the test forces the
+    kernel, which then serves the same tokens."""
+    from repro import trace
+    from repro.configs import load_config, reduced
+    from repro.launch.serve import BatchedServer, Request
+    from repro.models import init_params, moe
+
+    cfg = reduced(load_config("moonlight-16b-a3b"))   # 2 expert layers
+    params = init_params(jax.random.PRNGKey(3), cfg)
+    rng = np.random.default_rng(3)
+    reqs = [Request(i, rng.integers(0, cfg.vocab_size, size=(8,))
+                    .astype(np.int32), g) for i, g in enumerate([3, 5])]
+    monkeypatch.setattr(moe, "GROUP_TOKENS", 8)
+
+    def serve():
+        trace.reset()
+        tokens = [r.tokens for r in BatchedServer(cfg, params,
+                                                  max_len=32).serve(reqs)]
+        return tokens, trace.counts()
+
+    want, _ = serve()
+    if path == "pallas":
+        monkeypatch.setattr(moe, "grouped_path", lambda: "pallas")
+    got, c = serve()
+    used = moe.grouped_path()
+    other, = {"pallas", "ragged_dot"} - {used}
+    assert used == ("ragged_dot" if path == "platform" else "pallas")
+    assert c[f"moe.grouped.{used}"] == 2 * (2 + 5)
+    assert c[f"moe.grouped.{other}"] == 0
+    assert got == want
+
+
 def test_serve_prefill_matches_forward():
     """``BatchedServer.prefill`` returns the model's last-position logits
     for the batch, as the plain prefill forward gives them."""

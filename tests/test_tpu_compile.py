@@ -203,21 +203,11 @@ def _hbm_buffers(hlo: str) -> list[tuple[str, str]]:
     return out
 
 
-@pytest.mark.parametrize("kind", ["bf16", "int8", "mla", "moonlight"])
-def test_decode_step_updates_cache_in_place(one_chip, kind):
-    """``jit_decode_step`` as the server builds it (cache donated) aliases
-    every cache leaf to its output, materialises no HBM buffer with the
-    shape of one layer's cache (in any dtype: no float32 copy of the
-    latent cache either), and needs less scratch than one layer's K: the
-    layer scan writes this token's entries into the carried cache and
-    never copies a layer or the stack.  One layer's K is 64 MiB here (the
-    latent ``c_kv`` 105 MiB at the Moonlight cell's batch of 128 and 840
-    positions), too large for the compiler to stage a copy of it in
-    VMEM."""
+def _served_decode_step(one_chip, cfg, batch, max_len):
+    """``(compiled jit_decode_step as the server builds it, cache shapes)``
+    of ``cfg`` at ``batch`` rows and ``max_len`` positions."""
     from repro.launch.serve import BatchedServer
     from repro.models import init_cache, init_params
-    cfg = _decode_config(kind)
-    batch, max_len = (128, 840) if kind == "moonlight" else (32, 2048)
 
     def sds(tree):
         return jax.tree_util.tree_map(
@@ -232,8 +222,25 @@ def test_decode_step_updates_cache_in_place(one_chip, kind):
     assert step.options.donate_argnums == (2,)
     compiled = jax.jit(step.fn, donate_argnums=step.options.donate_argnums
                        ).lower(*args).compile()
+    assert compiled.as_text().startswith("HloModule jit_decode_step")
+    return compiled, cache
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8", "mla", "moonlight"])
+def test_decode_step_updates_cache_in_place(one_chip, kind):
+    """``jit_decode_step`` as the server builds it (cache donated) aliases
+    every cache leaf to its output, materialises no HBM buffer with the
+    shape of one layer's cache (in any dtype: no float32 copy of the
+    latent cache either), and needs less scratch than one layer's K: the
+    layer scan writes this token's entries into the carried cache and
+    never copies a layer or the stack.  One layer's K is 64 MiB here (the
+    latent ``c_kv`` 105 MiB at the Moonlight cell's batch of 128 and 840
+    positions), too large for the compiler to stage a copy of it in
+    VMEM."""
+    batch, max_len = (128, 840) if kind == "moonlight" else (32, 2048)
+    compiled, cache = _served_decode_step(one_chip, _decode_config(kind),
+                                          batch, max_len)
     hlo = compiled.as_text()
-    assert hlo.startswith("HloModule jit_decode_step")
 
     leaves = jax.tree_util.tree_leaves(cache)
     nbytes = [a.size * a.dtype.itemsize for a in leaves]
@@ -247,3 +254,29 @@ def test_decode_step_updates_cache_in_place(one_chip, kind):
             if d in layer and op != "bitcast"] == []
     assert memory.temp_size_in_bytes < max(
         n // a.shape[0] for n, a in zip(nbytes, leaves))
+
+
+def test_moonlight_decode_step_runs_grouped_kernel(one_chip, monkeypatch):
+    """Moonlight's decode step on the path a TPU takes (the test steers the
+    path and the kernel's native lowering, which a CPU backend would not
+    pick): every expert layer's grouped matmuls are the Pallas kernel (two
+    calls a layer, gate and up fused), no ``ragged-dot`` is left, and no
+    HBM buffer has the shape of one layer's held experts, so nothing is
+    copied out of the stacked weights; the cache is still updated in
+    place."""
+    from repro.kernels import ops
+    from repro.models import moe
+    monkeypatch.setattr(moe, "grouped_path", lambda: "pallas")
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = _decode_config("moonlight")
+    compiled, cache = _served_decode_step(one_chip, cfg, 128, 840)
+    hlo = compiled.as_text()
+    assert "ragged-dot" not in hlo, re.findall(r".{80}ragged.{80}", hlo)[:3]
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+                          r"grouped_matmul", hlo)) == 2
+    m, d = cfg.moe, cfg.d_model
+    experts = {f"{m.held},{d},{m.d_ff}", f"{m.held},{m.d_ff},{d}"}
+    assert [(op, s) for op, s in _hbm_buffers(hlo) if s in experts] == []
+    leaves = jax.tree_util.tree_leaves(cache)
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in leaves)
