@@ -1,7 +1,7 @@
 """Chaos smoke: the fault-injection acceptance scenarios as a benchmark.
 
 Runs the three headline failure drills from the chaos harness
-(``repro.serve.faults``) against fresh stores and checks the resilience
+(``repro.runtime.faults``) against fresh stores and checks the resilience
 contract end to end:
 
 - **worker_kill** — a pool worker SIGKILLs itself mid-chunk; the daemon
@@ -110,7 +110,7 @@ def _spawn_daemon(sock: str, store: str, extra_env=None):
 
 def _drill_worker_kill(rc, work: str, n: int, ref: dict) -> dict:
     """SIGKILL one pool worker mid-chunk; serve through the daemon."""
-    from repro.serve import faults
+    from repro.runtime import faults
     from repro.serve.client import (get_stats, shutdown,
                                     simulate_dataflow_served)
     store = _fresh_store(rc, work, "store_wk")
@@ -140,7 +140,7 @@ def _drill_worker_kill(rc, work: str, n: int, ref: dict) -> dict:
 
 def _drill_corrupt_record(rc, work: str, n: int, ref: dict) -> dict:
     """Damage one record at publish; quarantine + re-resolve heals it."""
-    from repro.serve import faults
+    from repro.runtime import faults
     store = _fresh_store(rc, work, "store_cr")
     log = os.path.join(work, "cr.log")
     faults.install(faults.FaultPlan(
@@ -165,7 +165,7 @@ def _drill_corrupt_record(rc, work: str, n: int, ref: dict) -> dict:
 
 def _drill_daemon_restart(rc, work: str, n: int, ref: dict) -> dict:
     """SIGKILL the daemon mid-stream; fail over, then journal-resume."""
-    from repro.serve import faults
+    from repro.runtime import faults
     from repro.serve.client import (ServeUnavailable, get_stats,
                                     shutdown, simulate_dataflow_served)
     from repro.core.simulator import acp_cache

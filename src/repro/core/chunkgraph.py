@@ -1,4 +1,4 @@
-"""The chunk-graph executor: sharded trace resolution on a process pool.
+"""The chunk graph: sharded trace resolution on a process pool.
 
 The streaming resolver of :mod:`repro.core.simulator` visits a kernel's
 iteration range chunk by chunk with *carried* state — the on-PL cache's
@@ -14,32 +14,54 @@ into a **chunk graph** whose expensive nodes are independent:
   lines" recency stacks, an associative monoid needing no incoming
   state — and its hit flags up to a small boundary-ambiguity table
   (the chunk's first ``ways`` first-touches per set, the only verdicts
-  an incoming state can change).  Earlier revisions ran phases A and B
-  as two full replays, which made 2-way sharding an honest slowdown
-  (0.19× recorded in BENCH_sim.json); the fused pass does the work
-  exactly once.  Effects are additionally persisted as rescache
-  *effect records* (``<key>.eNNNNN.npz``) so a re-shard composes
-  stored effects instead of waiting for phase-A messages at all.
+  an incoming state can change).  Effects are also persisted as
+  rescache *effect records* (``<key>.eNNNNN.npz``) so a re-shard
+  composes stored effects instead of waiting for phase-A messages.
 * **Compose (master, cheap)** — a serial scan over the tiny per-chunk
-  effect snapshots (:func:`~repro.core.simulator.compose_stacks`) —
-  stored effect records when present, phase-A messages otherwise —
+  effect snapshots (:func:`~repro.core.simulator.compose_stacks`)
   yields every chunk's exact *incoming* cache state.
-* **Finalize (parallel, tiny)** — each worker patches its chunk's
+* **Finalize (parallel, tiny)** — the worker patches its chunk's
   ambiguous verdicts against the incoming state
-  (:meth:`~repro.core.simulator._SharedResolver.finalize_replay`)
-  and installs the composed outgoing stacks — bit-identical to a full
-  warm replay, at the cost of a few hundred boundary lookups.
+  (:meth:`~repro.core.simulator._SharedResolver.finalize_replay`) —
+  bit-identical to a full warm replay, at the cost of a few hundred
+  boundary lookups.
 * **Phase C (parallel)** — backing-store draws.  The draw stream is
   position-exact (one PCG64 double per draw), so the master turns the
-  per-chunk miss counts into per-chunk draw *offsets* and each worker
-  fast-forwards a fresh seeded RNG with ``advance`` — draw-for-draw
-  identical to the streaming pass.  The per-op latency matrices are
-  committed to the rescache as ordinary v3 chunk records (or handed
-  back inline when the artifact is above the size cap).
-* **Fold + solve (master, overlapped)** — the master consumes chunks in
-  order, folds them into per-stage arrays, and runs every (memory model
-  × FIFO depth) lane's wavefront solve with the depth-incremental warm
-  start — concurrently with the workers resolving ahead.
+  per-chunk backing trips into per-chunk draw *offsets* and the worker
+  fast-forwards a seeded RNG with ``advance`` — draw-for-draw identical
+  to the streaming pass.  The per-op latency matrices are committed to
+  the rescache as v3 chunk records (or handed back inline when the
+  artifact is above the size cap).
+
+Two schedulers run this protocol — the library pool of
+:func:`simulate_dataflow_sharded` (``simulate_dataflow_many(...,
+workers=N)``) and the resolution daemon of :mod:`repro.serve.daemon` —
+and both are built from the same two pieces here:
+
+* :func:`worker_main` — **the one worker loop**.  It takes ``("job",
+  jid, payload)``, ``("task", jid, k, lo, hi)``, ``("state", jid, k,
+  state)``, ``("draws", jid, k, offsets)``, ``("forget", jid)`` and
+  ``("stop",)``, and answers ``effect``, ``replay``, ``done`` or
+  ``error``, each carrying ``(wid, jid, k)``.  A :class:`ChunkJob`
+  holds one job's resolver and record writers and the scratch of each
+  chunk in flight, so the phases of different chunks and jobs may
+  interleave on one worker.  :func:`start_worker` gives each worker
+  its own task queue and its own result pipe, which the scheduler reads
+  with :func:`recv_replies`: a worker killed while writing a reply
+  (an OOM kill) tears only its own pipe, which reads as end of file and
+  is dropped, and no other worker's replies wait behind it.  A worker
+  whose scheduler died exits on its own.
+* :class:`ChunkSequencer` — **the one master-side sequencer**: effects
+  in, incoming states out in chunk order; replay deltas in, draw
+  offsets and hit/miss cumulants out in chunk order; and the log of
+  every message sent for a chunk in flight, which :meth:`resend
+  <ChunkSequencer.resend>` replays verbatim to another worker
+  (resolution is deterministic, so a resend is bit-identical).
+
+Each scheduler keeps its own policy: where a chunk runs, when to
+speculate, the retry budget, and — in the pool — the in-order fold and
+wavefront solve overlapped with the workers resolving ahead (the
+daemon streams records to its clients instead, which fold and solve).
 
 The result is bit-identical to the streaming engine (same canonical
 access order, same replacement decisions, same draw stream — asserted
@@ -56,16 +78,23 @@ caller transparently falls back to the streaming path.
 from __future__ import annotations
 
 import multiprocessing
+import multiprocessing.connection
 import os
 import queue
 import time
 import traceback
-from collections import deque
 from typing import Mapping, Sequence
 
 import numpy as np
 
-#: Outstanding chunks per worker before the master stops dispatching
+from . import engine as _eng
+from . import rescache as _rc
+from .simulator import (SimResult, _cache_group_key,
+                        _dataflow_many_stream, _lat_itemsize, _LaneSolver,
+                        _OpFolder, _ResolutionPlan, _ServeLost,
+                        _SharedResolver, compose_stacks)
+
+#: Outstanding chunks per worker before a scheduler stops dispatching
 #: (bounds queue memory: at most ``workers * _WINDOW`` unconsumed
 #: per-op matrices are in flight).
 _WINDOW = 2
@@ -102,9 +131,30 @@ def default_workers(*, cpus: int | None = None, jobs: int = 1,
     return max(2, cpus // max(1, jobs))
 
 
+def default_speculate_after_s() -> float:
+    """Seconds a chunk may run before a scheduler dispatches a
+    speculative duplicate: ``$REPRO_SPECULATE_AFTER_S``, default 30
+    (also when unset, empty or unparsable); 0 disables speculation."""
+    try:
+        return float(os.environ.get("REPRO_SPECULATE_AFTER_S") or 30)
+    except ValueError:
+        return 30.0
+
+
+def rescache_cfg() -> dict:
+    """This process's store configuration, which :func:`worker_main`
+    re-applies in a spawned worker."""
+    return {
+        "enabled": _rc._cfg.enabled,
+        "directory": _rc._dir(),
+        "memory_mb": _rc._cfg.memory_mb,
+        "artifact_mb": _rc._cfg.artifact_mb,
+        "disk_mb": _rc._cfg.disk_mb,
+    }
+
+
 def _compose_state(older, newer):
     """Compose two per-geometry state maps (``None`` = empty cache)."""
-    from .simulator import compose_stacks
     if older is None:
         return newer
     out = {}
@@ -118,116 +168,296 @@ def _compose_state(older, newer):
     return out
 
 
-def _worker_main(payload_bytes: bytes, task_q, result_q) -> None:
-    """One pool worker: processes its chunks' A/B/C phases, exchanging
-    tiny state messages with the master (see the module docstring)."""
-    current = -1
+# ---------------------------------------------------------------------------
+# Worker side
+# ---------------------------------------------------------------------------
+
+class ChunkJob:
+    """One job on one worker: the shared resolver for the payload's live
+    models, their v3 chunk writers and effect-record keys, and the
+    :class:`~repro.core.simulator._ChunkScratch` of each chunk between
+    its phases, keyed by chunk index.
+
+    ``payload`` holds ``stages``, ``mems`` (the live models), ``seed``,
+    ``n_iters``, ``keys`` (model -> v3 key or ``None``) and optionally
+    ``capture`` (build the hit/visit planes; default on)."""
+
+    def __init__(self, payload: Mapping):
+        self.mems = payload["mems"]
+        r = self.resolver = _SharedResolver(
+            payload["stages"], self.mems, payload["seed"],
+            capture=payload.get("capture", True))
+        keys = {mn: k for mn, k in payload["keys"].items()
+                if k is not None}
+        writers = {mn: _rc.ChunkWriter(
+            key, r.K, payload["n_iters"],
+            itemsize=_lat_itemsize(self.mems[mn]))
+            for mn, key in keys.items()}
+        self.writers = {mn: w for mn, w in writers.items() if not w.dead}
+        self.effect_keys = {mn: key for mn, key in keys.items()
+                            if r.cache_keys[mn] is not None}
+        self.scratch: dict[int, object] = {}
+
+    def effect(self, k: int, lo: int, hi: int) -> tuple[dict, int]:
+        """Phases A+B fused: one empty-cache replay gives the chunk's
+        own effect (persisted as effect records) and its hit flags up
+        to the ambiguity table :meth:`finalize` patches."""
+        effects, n_addrs, self.scratch[k] = \
+            self.resolver.chunk_effects_fused(lo, hi)
+        with _eng.phase("effect"):
+            for mn, ekey in self.effect_keys.items():
+                geo = self.resolver.cache_keys[mn]
+                if geo in effects:
+                    _rc.put_effect(ekey, k, effects[geo], n_addrs)
+        return effects, n_addrs
+
+    def finalize(self, k: int, state: Mapping | None) -> dict:
+        """Patch the chunk's verdicts against its composed incoming
+        state; returns the per-geometry replay deltas."""
+        return self.resolver.finalize_replay(state, self.scratch[k])
+
+    def draws(self, k: int, offsets: Mapping) -> tuple[dict, dict]:
+        """Phase C: position each model's draw stream at its offset,
+        materialize the latencies and commit the chunk record (or hand
+        the matrix back inline past the artifact cap).  Returns
+        ``(cums, inline)`` per model: the record's cumulative counters,
+        and ``None`` or ``{"ops", "hits", "visits"}``."""
+        r = self.resolver
+        sc = self.scratch.pop(k)
+        for mn, off in offsets.items():
+            r.import_resume(mn, {}, {"draws": off["base"]})
+        r.finish(sc.lo, sc.hi, fold=False, scratch=sc)
+        cums: dict[str, dict] = {}
+        inline: dict[str, dict | None] = {}
+        for mn in self.mems:
+            geo = r.cache_keys[mn]
+            cum = {"draws": r.draws[mn]}
+            states = {}
+            if geo is not None:
+                cum["hits"] = offsets[mn]["hits_after"]
+                cum["misses"] = offsets[mn]["misses_after"]
+                cum["max_tag"] = sc.end[geo][1]
+                states["cache"] = sc.end[geo][0]
+            cums[mn] = cum
+            hb = vb = None
+            if r.last_hits.get(mn) is not None:
+                hb = _rc.pack_flags(r.last_hits[mn])
+                vb = _rc.pack_flags(r.last_visits[mn])
+            w = self.writers.get(mn)
+            if w is not None and k < w.max_chunks:
+                w.add(k, sc.hi - sc.lo,
+                      np.ascontiguousarray(r.last_ops[mn]),
+                      hb, vb, states, cum)
+                inline[mn] = None  # the master reads the record
+            else:
+                inline[mn] = {"ops": _rc.shrink_ops(r.last_ops[mn]),
+                              "hits": hb, "visits": vb}
+        return cums, inline
+
+
+def worker_main(wid: int | None, C: int, task_q, result_conn,
+                rescache_cfg: dict) -> None:
+    """One worker process of either scheduler (see the module
+    docstring for its messages).  Every reply is ``(kind, wid, jid, k,
+    *payload, wall_s)``: ``effect`` carries ``(effects, n_addrs)``,
+    ``replay`` the deltas, ``done`` ``(cums, inline, walls)``; an
+    ``error`` carries the traceback instead of a wall, and the worker
+    keeps serving its other jobs.  ``wid`` is the daemon's worker slot
+    and ``None`` in the library pool, whose fault hooks therefore match
+    no ``target`` filter."""
+    jid = k = -1
     try:
         import cloudpickle
-        p = cloudpickle.loads(payload_bytes)
-        from . import engine as _eng
-        from . import rescache as _rc
-        from ..serve import faults as _faults
-        from .simulator import _SharedResolver, _lat_itemsize
-        _rc.configure(**p["rescache_cfg"])
-        _rc.CHUNK_ITERS = p["C"]
-        resolver = _SharedResolver(p["stages"], p["mems"], p["seed"],
-                                   capture=p["capture"])
-        writers = {mn: _rc.ChunkWriter(
-            key, resolver.K, p["n_iters"],
-            itemsize=_lat_itemsize(p["mems"][mn]))
-            for mn, key in p["keys"].items() if key is not None}
-        writers = {mn: w for mn, w in writers.items() if not w.dead}
-        pending: deque = deque()
-        mailbox: dict[tuple, tuple] = {}
+        from ..runtime import faults
+        _rc.configure(**rescache_cfg)
+        _rc.CHUNK_ITERS = C
+    except Exception:  # noqa: BLE001 — forwarded verbatim
+        result_conn.send(("error", wid, jid, k, traceback.format_exc()))
+        return
+    jobs: dict[int, ChunkJob] = {}
+    scheduler = multiprocessing.parent_process()
+    while True:
+        try:
+            m = task_q.get(timeout=1.0)
+        except queue.Empty:
+            if scheduler is not None and not scheduler.is_alive():
+                return  # orphaned (the scheduler was killed): exit
+            continue
+        op = m[0]
+        if op == "stop":
+            return
+        t0 = time.perf_counter()
+        try:
+            if op == "job":
+                jid = m[1]
+                jobs[jid] = ChunkJob(cloudpickle.loads(m[2]))
+                continue
+            if op == "forget":
+                jobs.pop(m[1], None)
+                continue
+            _, jid, k, *args = m
+            job = jobs[jid]
+            if op == "task":
+                if faults.active():  # chaos: die mid-chunk
+                    faults.maybe_kill("worker_kill", worker=wid, chunk=k)
+                reply = ("effect", *job.effect(k, *args))
+            elif op == "state":
+                reply = ("replay", job.finalize(k, *args))
+            else:  # "draws"
+                if faults.active():
+                    # phase C is the heavy phase (draw materialization
+                    # + record write): a straggler here stalls the
+                    # in-order commit — what speculation absorbs
+                    faults.maybe_sleep("straggler", worker=wid, chunk=k)
+                reply = ("done", *job.draws(k, *args), _eng.walls())
+                _eng.reset_walls()
+            result_conn.send((reply[0], wid, jid, k, *reply[1:],
+                              time.perf_counter() - t0))
+        except Exception:  # noqa: BLE001 — the scheduler fails the job
+            result_conn.send(("error", wid, jid, k, traceback.format_exc()))
 
-        def next_msg(kind: str, k: int):
-            """Wait for the master's (kind, k) reply, buffering tasks
-            and replies that belong to this worker's later chunks."""
-            want = (kind, k)
-            while want not in mailbox:
-                m = task_q.get()
-                if m[0] == "task":
-                    pending.append(m)
-                elif m[0] == "stop":
-                    return None
-                else:
-                    mailbox[(m[0], m[1])] = m
-            return mailbox.pop(want)
 
-        def next_task():
-            if pending:
-                return pending.popleft()
-            while True:
-                m = task_q.get()
-                if m[0] in ("task", "stop"):
-                    return m
-                mailbox[(m[0], m[1])] = m
+def start_worker(ctx, wid: int | None, C: int, cfg: dict):
+    """Start one :func:`worker_main` process; returns ``(process,
+    task_queue, result_connection)``.  The worker holds the only write
+    end of its result pipe, so the connection reads end of file once
+    the worker is gone."""
+    task_q = ctx.Queue()
+    reader, writer = ctx.Pipe(duplex=False)
+    proc = ctx.Process(target=worker_main,
+                       args=(wid, C, task_q, writer, cfg), daemon=True)
+    with _eng.cpu_children():
+        proc.start()
+    writer.close()
+    return proc, task_q, reader
 
-        while True:
-            msg = next_task()
-            if msg[0] == "stop":
-                return
-            _, k, lo, hi = msg
-            current = k
-            if _faults.active():  # chaos: die mid-chunk
-                _faults.maybe_kill("worker_kill", chunk=k)
-            # A+B fused: one empty-cache replay yields the chunk's own
-            # effect AND its hit flags up to the boundary-ambiguity
-            # table finalize_replay patches below — the second full
-            # replay the unfused executor paid is gone
-            effects, n_addrs = resolver.chunk_effects_fused(lo, hi)
-            with _eng.phase("effect"):
-                for mn, ekey in p.get("effect_keys", {}).items():
-                    geo = resolver.cache_keys[mn]
-                    if geo is not None and geo in effects:
-                        _rc.put_effect(ekey, k, effects[geo], n_addrs)
-            result_q.put(("effect", k, effects, n_addrs))
-            # B: patch the fused verdicts against the composed
-            # incoming state and install the outgoing stacks
-            m = next_msg("state", k)
-            if m is None:
-                return
-            deltas = resolver.finalize_replay(m[2])
-            result_q.put(("replay", k, deltas))
-            # C: position the draw streams, materialize latencies
-            m = next_msg("draws", k)
-            if m is None:
-                return
-            if _faults.active():  # chaos: straggle in the heavy phase
-                _faults.maybe_sleep("straggler", chunk=k)
-            for mn, cum in m[2].items():
-                resolver.import_resume(mn, {}, {"draws": cum["base"]})
-                geo = resolver.cache_keys[mn]
+
+def recv_replies(conns: list, timeout: float) -> list[tuple]:
+    """The replies ready within ``timeout`` on the workers' result
+    connections ``conns`` (one list slot a worker, ``None`` once
+    closed), at most one a connection.  A connection at end of file
+    (its worker gone, with any reply it was still writing torn) is
+    closed and its slot set to ``None``."""
+    live = [c for c in conns if c is not None]
+    out = []
+    for c in multiprocessing.connection.wait(live, timeout):
+        try:
+            out.append(c.recv())
+        except (EOFError, OSError):
+            conns[conns.index(c)] = None
+            c.close()
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Master side
+# ---------------------------------------------------------------------------
+
+class ChunkSequencer:
+    """The serial scans of one run's chunk graph, on the master.
+
+    ``mems`` are the live models; resolution starts at chunk
+    ``first_live`` from ``state`` (geometry -> ``(stacks, max_tag)``, or
+    ``None`` for cold caches), ``cum_draws`` (model -> draws consumed)
+    and ``geo_cum`` (geometry -> ``(hits, misses)``).  The scheduler
+    reports each worker reply (:meth:`effect`, :meth:`replay`,
+    :meth:`done`) and sends what :meth:`task` and :meth:`pump` return,
+    to the chunk's worker; every message sent for a chunk stays in its
+    log until ``done``, for :meth:`resend`.  Duplicates of a chunk's
+    replies (a resent or speculative copy) are ignored."""
+
+    def __init__(self, jid: int, mems: Mapping, first_live: int,
+                 state: dict | None, cum_draws: Mapping[str, int],
+                 geo_cum: Mapping[tuple, tuple[int, int]]):
+        self.jid = jid
+        self.mems = dict(mems)
+        self.geos = {mn: _cache_group_key(m) for mn, m in mems.items()}
+        self.state_at: dict[int, dict | None] = {first_live: state}
+        self.composed = first_live  # incoming states known up to here
+        self.effects: dict[int, dict] = {}
+        self.n_addrs: dict[int, int] = {}
+        self.deltas: dict[int, dict] = {}
+        self.cum_draws = dict(cum_draws)
+        self.geo_cum = dict(geo_cum)
+        self.state_sent = self.draws_sent = first_live
+        self.log: dict[int, list[tuple]] = {}
+
+    def task(self, k: int, lo: int, hi: int) -> tuple:
+        """Chunk ``k``'s task message; opens its log."""
+        msg = ("task", self.jid, k, lo, hi)
+        self.log[k] = [msg]
+        return msg
+
+    def effect(self, k: int, effects: dict, n_addrs: int) -> None:
+        """A chunk's own effect (a worker's, or a stored effect record):
+        compose every incoming state it completes, in chunk order."""
+        if k < self.composed or k in self.effects:
+            return  # duplicate from a resent chunk
+        self.effects[k] = effects
+        self.n_addrs[k] = n_addrs
+        while self.composed in self.effects:
+            k = self.composed
+            self.state_at[k + 1] = _compose_state(self.state_at[k],
+                                                  self.effects.pop(k))
+            self.composed += 1
+
+    def replay(self, k: int, deltas: dict) -> None:
+        if k >= self.draws_sent:  # else: a resent chunk's duplicate
+            self.deltas[k] = deltas
+
+    def done(self, k: int) -> None:
+        self.log.pop(k, None)
+
+    def pump(self, upto: int) -> list[tuple]:
+        """The state and draws messages now ready for chunks below
+        ``upto`` (the dispatched ones), in chunk order."""
+        out = []
+        while self.state_sent < upto and self.state_sent in self.state_at:
+            k = self.state_sent
+            out.append(("state", self.jid, k, self.state_at[k] or {}))
+            self.log[k].append(out[-1])
+            self.state_sent += 1
+        while self.draws_sent < upto and self.draws_sent in self.deltas:
+            k = self.draws_sent
+            d = self.deltas.pop(k)
+            offsets = {}
+            for mn, mem in self.mems.items():
+                geo = self.geos[mn]
+                entry = {"base": self.cum_draws[mn]}
+                if mem.backing_hit_rate > 0.0:
+                    # draws consumed this chunk: every backing trip
+                    # (misses + write-around stores) for cached
+                    # models, every participating access otherwise
+                    self.cum_draws[mn] += d[geo][2] \
+                        if geo is not None else self.n_addrs[k]
                 if geo is not None:
-                    resolver.caches[geo].hits = cum["hits_after"]
-                    resolver.caches[geo].misses = cum["misses_after"]
-            resolver.finish(lo, hi, fold=False)
-            ops_payload = {}
-            for mn in p["mems"]:
-                w = writers.get(mn)
-                if w is not None and lo // p["C"] < w.max_chunks:
-                    hb = vb = None
-                    if resolver.last_hits.get(mn) is not None:
-                        hb = _rc.pack_flags(resolver.last_hits[mn])
-                        vb = _rc.pack_flags(resolver.last_visits[mn])
-                    states, cum = resolver.export_resume(mn)
-                    w.add(lo // p["C"], hi - lo,
-                          np.ascontiguousarray(resolver.last_ops[mn]),
-                          hb, vb, states, cum)
-                    ops_payload[mn] = None  # master reads the record
-                else:
-                    # no writer, or past the artifact's stored-prefix
-                    # budget: hand the matrix back inline
-                    ops_payload[mn] = _rc.shrink_ops(
-                        resolver.last_ops[mn])
-            cums = {mn: resolver.export_resume(mn)[1]
-                    for mn in p["mems"]}
-            walls = _eng.walls()
-            _eng.reset_walls()
-            result_q.put(("done", k, cums, ops_payload, walls))
-    except Exception:  # noqa: BLE001 - forwarded to the master verbatim
-        result_q.put(("error", current, traceback.format_exc()))
+                    h, m = self.geo_cum[geo]
+                    entry["hits_after"] = h + d[geo][0]
+                    entry["misses_after"] = m + d[geo][1]
+                offsets[mn] = entry
+            for geo, (dh, dm, _) in d.items():
+                h, m = self.geo_cum[geo]
+                self.geo_cum[geo] = (h + dh, m + dm)
+            out.append(("draws", self.jid, k, offsets))
+            self.log[k].append(out[-1])
+            del self.n_addrs[k]
+            self.draws_sent += 1
+        # a state snapshot is dead once it was sent and composed into
+        # its successor — prune so a thousand-chunk run keeps
+        # O(workers) snapshots, not O(chunks)
+        for j in [j for j in self.state_at
+                  if j < self.state_sent and j + 1 in self.state_at]:
+            del self.state_at[j]
+        return out
+
+    def in_phase_c(self, k: int) -> bool:
+        """Task, state and draws all sent and no ``done`` yet."""
+        return len(self.log.get(k, ())) == 3
+
+    def resend(self, k: int) -> list[tuple]:
+        """Every message sent so far for chunk ``k`` (not yet done), in
+        order — replayed verbatim to a respawned or speculative worker."""
+        return list(self.log[k])
 
 
 def simulate_dataflow_sharded(
@@ -248,12 +478,6 @@ def simulate_dataflow_sharded(
     ``simulate_dataflow_many(..., workers=N)``.  Falls back to the
     streaming engine whenever sharding cannot help (no live resolution,
     too few chunks) or the stage list will not serialize."""
-    from . import engine as _eng
-    from . import rescache as _rc
-    from .simulator import (SimResult, _LaneSolver, _OpFolder,
-                            _ResolutionPlan, _ServeLost,
-                            _dataflow_many_stream)
-
     mems = dict(mems)
 
     def _stream(rescache_override):
@@ -285,52 +509,34 @@ def simulate_dataflow_sharded(
     first_live = plan.resume // C
     if not plan.live or n_chunks - first_live < 2 or workers < 2:
         return _stream(use_rescache)
-    # every live cached model with a v3 key also persists its chunks'
-    # cache-effect monoids as effect records (tiny, content-determined)
-    # — the next shard of this artifact composes them from the store
-    # and never waits on the phase-A message chain
-    effect_keys = {}
-    if _rc.enabled(use_rescache):
-        effect_keys = {
-            mn: plan.keys[mn] for mn in plan.live
-            if plan.keys.get(mn) is not None
-            and plan.resolver.cache_keys[mn] is not None}
+    keys = {mn: plan.keys[mn] for mn in plan.live
+            if plan.keys.get(mn) is not None}
     try:
         import cloudpickle
         payload = cloudpickle.dumps({
-            "stages": list(stages),
-            "mems": plan.live,
-            "seed": seed,
-            "n_iters": n_iters,
-            "C": C,
-            "capture": bool(plan.writers),
-            "keys": {mn: plan.keys[mn] for mn in plan.writers},
-            "effect_keys": effect_keys,
-            "rescache_cfg": {
-                "enabled": _rc._cfg.enabled,
-                "directory": _rc._dir(),
-                "memory_mb": _rc._cfg.memory_mb,
-                "artifact_mb": _rc._cfg.artifact_mb,
-                "disk_mb": _rc._cfg.disk_mb,
-            },
-        })
+            "stages": list(stages), "mems": plan.live, "seed": seed,
+            "n_iters": n_iters, "keys": keys,
+            "capture": bool(plan.writers)})
     except Exception:  # unpicklable traces: shard is impossible
         return _stream(use_rescache)
 
     W = min(workers, n_chunks - first_live)
     ctx = multiprocessing.get_context("spawn")
-    result_q = ctx.Queue()
-    task_qs = [ctx.Queue() for _ in range(W)]
-    procs = [ctx.Process(target=_worker_main,
-                         args=(payload, task_qs[w], result_q),
-                         daemon=True)
-             for w in range(W)]
-    with _eng.cpu_children():
-        for pr in procs:
-            pr.start()
+    cfg = rescache_cfg()
+    task_qs: list = [None] * W
+    procs: list = [None] * W
+    conns: list = [None] * W
 
-    #: chunk -> worker; seeded round-robin, rewritten when a dead
-    #: worker's in-flight chunks are re-dispatched
+    def spawn(w: int) -> None:
+        if conns[w] is not None:
+            conns[w].close()  # a dead worker's pipe, torn reply and all
+        procs[w], task_qs[w], conns[w] = start_worker(ctx, None, C, cfg)
+        task_qs[w].put(("job", 0, payload))
+
+    for w in range(W):
+        spawn(w)
+
+    #: chunk -> worker; seeded round-robin
     owner_of: dict[int, int] = {}
 
     def owner(k: int) -> int:
@@ -343,7 +549,7 @@ def simulate_dataflow_sharded(
     depth_order = sorted(set(fifo_depths), reverse=True)
     resolver = plan.resolver
 
-    def solve_chunk(k: int, ops_by_model) -> None:
+    def solve_chunk(k: int, inline) -> None:
         lo = k * C
         hi = min(lo + C, n_iters)
         for mn in mems:
@@ -353,8 +559,8 @@ def simulate_dataflow_sharded(
             elif k < first_live:
                 L = plan.live_ops(mn, lo, hi)
                 _rc.note_chunks(served=1)
-            elif ops_by_model[mn] is not None:
-                L = ops_by_model[mn]
+            elif inline[mn] is not None:
+                L = inline[mn]["ops"]
             else:
                 # refresh: the worker just (re)wrote this record; a
                 # stale partial tail may still sit in the master's LRU
@@ -373,58 +579,41 @@ def simulate_dataflow_sharded(
                 warm = solvers[(mn, d)].solve_chunk(
                     res, warm=warm if depth_incremental else None)
 
-    # master bookkeeping: effect composition, draw prefixes, dispatch
-    state_at: dict[int, dict | None] = {
-        first_live: ({geo: sim.export_stacks()
-                      for geo, sim in resolver.caches.items()}
-                     if plan.resume > 0 else None)}
-    effects: dict[int, dict] = {}
-    n_addrs: dict[int, int] = {}
+    seq = ChunkSequencer(
+        0, plan.live, first_live,
+        {geo: sim.export_stacks() for geo, sim in resolver.caches.items()}
+        if plan.resume > 0 else None,
+        resolver.draws,
+        {geo: (sim.hits, sim.misses)
+         for geo, sim in resolver.caches.items()})
     # stored effect records seed the state chain ahead of the workers:
     # walk forward from the resume point while every geometry's effect
-    # is on disk, so pump_sends never waits on a phase-A message for a
-    # chunk this store has seen before (snapshots are ~KB each and the
-    # send-side prune below keeps the live set O(workers))
-    if effect_keys and resolver.caches:
-        need: dict[tuple, str] = {}
-        for mn, ekey in effect_keys.items():
-            need.setdefault(resolver.cache_keys[mn], ekey)
-        if set(need) == set(resolver.caches):
-            k = first_live
-            while k < n_chunks:
-                recs = {geo: _rc.get_effect(ekey, k)
-                        for geo, ekey in need.items()}
-                if any(r is None for r in recs.values()):
-                    break
-                state_at[k + 1] = _compose_state(
-                    state_at[k],
-                    {geo: (r[0], r[1]) for geo, r in recs.items()})
-                n_addrs[k] = next(iter(recs.values()))[2]
-                k += 1
-    deltas: dict[int, dict] = {}
-    done: dict[int, dict] = {}
-    cum_draws = dict(resolver.draws)
-    geo_cum = {geo: (sim.hits, sim.misses)
-               for geo, sim in resolver.caches.items()}
+    # is on disk, so no state waits on a phase-A message for a chunk
+    # this store has seen before
+    need: dict[tuple, str] = {}
+    for mn, key in keys.items():
+        if resolver.cache_keys[mn] is not None:
+            need.setdefault(resolver.cache_keys[mn], key)
+    if need and set(need) == set(resolver.caches):
+        for k in range(first_live, n_chunks):
+            recs = {geo: _rc.get_effect(ekey, k)
+                    for geo, ekey in need.items()}
+            if any(r is None for r in recs.values()):
+                break
+            seq.effect(k, {geo: (r[0], r[1]) for geo, r in recs.items()},
+                       next(iter(recs.values()))[2])
+    done: dict[int, tuple] = {}
     final_cums: dict[str, dict] = {}
-    #: master-side replay log for worker-death recovery: every state /
-    #: draws message stays addressable until its chunk's ``done``
-    #: arrives, so a respawned worker can be fed the exact same
-    #: messages (bit-identical replay; bounded by ``W * _WINDOW``)
-    sent_state: dict[int, dict] = {}
-    sent_draws: dict[int, dict] = {}
     retries = 0
 
     # speculative straggler re-dispatch (the StragglerPolicy
     # bounded-staleness rule applied to chunk dispatch): a phase-C
     # chunk whose wall exceeds the SpeculationPolicy threshold is
-    # replayed in full (task + state + draws) on a second live worker.
-    # The master's fold stalls at the straggling chunk while its peers
-    # drain to idle, so the duplicate lands on an idle worker;
-    # resolution is deterministic, so the first "done" wins and the
-    # loser's messages die on the ordinary duplicate guards below.
-    spec_after = float(os.environ.get("REPRO_SPECULATE_AFTER_S",
-                                      "30") or 0)
+    # resent in full on a second live worker.  The master's fold
+    # stalls at the straggling chunk while its peers drain to idle, so
+    # the duplicate lands on an idle worker; the first "done" wins and
+    # the loser's replies die on the sequencer's duplicate guards.
+    spec_after = default_speculate_after_s()
     spec_policy = None
     if W > 1 and spec_after > 0:
         from ..runtime.fault_tolerance import SpeculationPolicy
@@ -438,8 +627,6 @@ def simulate_dataflow_sharded(
         min(5.0, max(0.25, spec_policy.min_wait_s / 2))
 
     dispatched = first_live
-    state_sent = first_live
-    draws_sent = first_live
     solved = 0
     failure: str | None = None
     try:
@@ -448,68 +635,32 @@ def simulate_dataflow_sharded(
             while dispatched < min(limit, n_chunks):
                 k = dispatched
                 task_qs[owner(k)].put(
-                    ("task", k, k * C, min((k + 1) * C, n_iters)))
+                    seq.task(k, k * C, min((k + 1) * C, n_iters)))
                 dispatched += 1
 
-        def pump_sends() -> None:
-            nonlocal state_sent, draws_sent
-            while state_sent < dispatched and state_sent in state_at:
-                k = state_sent
-                sent_state[k] = state_at[k] or {}
-                task_qs[owner(k)].put(("state", k, sent_state[k]))
-                state_sent += 1
-            while draws_sent < dispatched and draws_sent in deltas:
-                k = draws_sent
-                msg = {}
-                for mn, mem in plan.live.items():
-                    geo = resolver.cache_keys[mn]
-                    entry = {"base": cum_draws[mn]}
-                    if mem.backing_hit_rate > 0.0:
-                        # draws consumed this chunk: every backing trip
-                        # (misses + write-around stores) for cached
-                        # models, every participating access otherwise
-                        cum_draws[mn] += deltas[k][geo][2] \
-                            if geo is not None else n_addrs[k]
-                    if geo is not None:
-                        h, m = geo_cum[geo]
-                        entry["hits_after"] = h + deltas[k][geo][0]
-                        entry["misses_after"] = m + deltas[k][geo][1]
-                    msg[mn] = entry
-                for geo, d in deltas[k].items():
-                    h, m = geo_cum[geo]
-                    geo_cum[geo] = (h + d[0], m + d[1])
-                sent_draws[k] = msg
-                task_qs[owner(k)].put(("draws", k, msg))
-                draws_t[k] = time.monotonic()
-                del deltas[k]  # fully consumed: keep the master O(W)
-                n_addrs.pop(k, None)
-                effects.pop(k, None)  # duplicate after a retry replay
-                draws_sent += 1
-            # a state snapshot is dead once it was sent and composed
-            # into its successor — prune so a thousand-chunk run keeps
-            # O(workers) snapshots, not O(chunks)
-            for j in [j for j in state_at
-                      if j < state_sent and j + 1 in state_at]:
-                del state_at[j]
+        def pump() -> None:
+            for m in seq.pump(dispatched):
+                task_qs[owner_of[m[2]]].put(m)
+                if m[0] == "draws":
+                    draws_t[m[2]] = time.monotonic()
 
         dispatch_upto(first_live + W * _WINDOW)
-        pump_sends()
+        pump()
         # chunks below the resume point solve immediately from records
         while solved < first_live:
             solve_chunk(solved, None)
             solved += 1
         while solved < n_chunks:
             if solved in done:
-                cums, ops = done.pop(solved)
+                cums, inline = done.pop(solved)
                 final_cums.update(cums)
-                solve_chunk(solved, ops)
+                solve_chunk(solved, inline)
                 solved += 1
                 dispatch_upto(solved + W * _WINDOW)
-                pump_sends()
+                pump()
                 continue
-            try:
-                msg = result_q.get(timeout=poll_s)
-            except queue.Empty:
+            msgs = recv_replies(conns, poll_s)
+            if not msgs:
                 if spec_policy is not None:
                     now = time.monotonic()
                     for k in sorted(draws_t):
@@ -525,11 +676,8 @@ def simulate_dataflow_sharded(
                         if not alts:
                             continue
                         w2 = alts[k % len(alts)]
-                        task_qs[w2].put(
-                            ("task", k, k * C,
-                             min((k + 1) * C, n_iters)))
-                        task_qs[w2].put(("state", k, sent_state[k]))
-                        task_qs[w2].put(("draws", k, sent_draws[k]))
+                        for m in seq.resend(k):
+                            task_qs[w2].put(m)
                         spec_owner[k] = w2
                         spec_policy.issued += 1
                         _rc.note_speculation()
@@ -538,9 +686,8 @@ def simulate_dataflow_sharded(
                 if not dead:
                     continue
                 # died without posting (OOM kill, segfault): respawn
-                # the slot and replay its in-flight chunks' messages
-                # verbatim — resolution is deterministic, so the retry
-                # is bit-identical — under a bounded budget
+                # the slot and resend its in-flight chunks' messages
+                # under a bounded budget
                 for k in [k for k, w in spec_owner.items()
                           if w in dead]:
                     spec_owner.pop(k)  # spec copy lost with its worker
@@ -555,55 +702,36 @@ def simulate_dataflow_sharded(
                         f"budget exhausted ({retries} > {RETRY_BUDGET})")
                     break
                 for w in dead:
-                    task_qs[w] = ctx.Queue()
-                    procs[w] = ctx.Process(
-                        target=_worker_main,
-                        args=(payload, task_qs[w], result_q),
-                        daemon=True)
-                    with _eng.cpu_children():
-                        procs[w].start()
-                for k in sorted(redo):
-                    w = owner_of[k]
-                    task_qs[w].put(
-                        ("task", k, k * C, min((k + 1) * C, n_iters)))
-                    if k < state_sent:
-                        task_qs[w].put(("state", k, sent_state[k]))
-                    if k < draws_sent:
-                        task_qs[w].put(("draws", k, sent_draws[k]))
+                    spawn(w)
+                for k in redo:
+                    for m in seq.resend(k):
+                        task_qs[owner_of[k]].put(m)
                 continue
-            kind = msg[0]
-            if kind == "error":
-                failure = msg[2]
+            for msg in msgs:
+                kind, _, _, k = msg[:4]
+                if kind == "error":
+                    failure = msg[4]
+                    break
+                if kind == "effect":
+                    seq.effect(k, msg[4], msg[5])
+                elif kind == "replay":
+                    seq.replay(k, msg[4])
+                elif kind == "done":
+                    t0 = draws_t.pop(k, None)
+                    if spec_policy is not None:
+                        if k in spec_owner:
+                            spec_policy.wins += 1  # a duplicate was live
+                        if t0 is not None:
+                            spec_policy.observe(time.monotonic() - t0)
+                    spec_owner.pop(k, None)
+                    if k >= solved:
+                        if k not in done:  # not a speculative dup
+                            _eng.merge_walls(msg[6])
+                        done[k] = (msg[4], msg[5])
+                        seq.done(k)
+            if failure is not None:
                 break
-            if kind == "effect":
-                _, k, eff, na = msg
-                if k + 1 in state_at or k < draws_sent:
-                    continue  # duplicate from a retried chunk
-                effects[k] = eff
-                n_addrs[k] = na
-                while (k + 1 not in state_at) and k in state_at \
-                        and k in effects:
-                    state_at[k + 1] = _compose_state(state_at[k],
-                                                     effects.pop(k))
-                    k += 1
-            elif kind == "replay":
-                if msg[1] >= draws_sent:  # else: retry duplicate
-                    deltas[msg[1]] = msg[2]
-            elif kind == "done":
-                t0 = draws_t.pop(msg[1], None)
-                if spec_policy is not None:
-                    if msg[1] in spec_owner:
-                        spec_policy.wins += 1  # a duplicate was live
-                    if t0 is not None:
-                        spec_policy.observe(time.monotonic() - t0)
-                spec_owner.pop(msg[1], None)
-                if msg[1] >= solved:
-                    if msg[1] not in done:  # not a speculative dup
-                        _eng.merge_walls(msg[4])
-                    done[msg[1]] = (msg[2], msg[3])
-                    sent_state.pop(msg[1], None)
-                    sent_draws.pop(msg[1], None)
-            pump_sends()
+            pump()
         if failure is not None:
             raise RuntimeError(
                 f"chunk-graph worker failed:\n{failure}")
@@ -623,6 +751,9 @@ def simulate_dataflow_sharded(
             pr.join(timeout=5)
             if pr.is_alive():
                 pr.terminate()
+        for c in conns:
+            if c is not None:
+                c.close()
 
     global _POOL_RUNS
     _POOL_RUNS += 1

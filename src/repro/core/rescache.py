@@ -243,10 +243,7 @@ def _faults():
     """The armed fault-injection plan's module, or ``None`` — a cheap
     check (module import is cached; ``active()`` reads one env var
     once) so production writes pay nothing."""
-    try:
-        from ..serve import faults as _f
-    except ImportError:  # pragma: no cover - serve is part of the tree
-        return None
+    from ..runtime import faults as _f
     return _f if _f.active() else None
 
 
@@ -977,11 +974,8 @@ def census() -> dict[str, Any]:
                     pass
             elif f.endswith(".quarantine"):
                 quarantine_files += 1
-    try:
-        from ..serve import faults as _fa
-        injected = _fa.stats()
-    except ImportError:  # pragma: no cover
-        injected = {}
+    from ..runtime import faults as _fa
+    injected = _fa.stats()
     return {"dir": d, "artifacts": len(keys), "chunks": chunks,
             "bytes": total,
             "effects": {"count": effect_count, "bytes": effect_bytes,

@@ -865,6 +865,31 @@ class _ResolvedChunk:
     lat_add: list[np.ndarray]  # per-stage extra finish latency (int32[n])
 
 
+@dataclasses.dataclass(slots=True, eq=False)
+class _ChunkScratch:
+    """One chunk's replay output, held from its replay until
+    :meth:`_SharedResolver.finish` consumes it: the flattened
+    participating-access stream (``n_addrs``, ``flat_p``,
+    ``store_flat``), the burst words and the per-geometry hit flags.
+    On the fused path it first holds the fused pass's verdicts and
+    ambiguity tables (``fused``) until
+    :meth:`~_SharedResolver.finalize_replay` patches them, which also
+    records the end-of-chunk stacks (``end``) the chunk's record
+    resumes from.  The streaming resolver keeps the last one as
+    ``_SharedResolver.scratch``; the chunk-graph worker holds one per
+    chunk in flight, so phases of different chunks may interleave."""
+
+    lo: int
+    hi: int
+    n_addrs: int
+    flat_p: np.ndarray
+    store_flat: np.ndarray
+    burst_words: Any
+    hits_by_key: dict | None = None
+    fused: tuple | None = None
+    end: dict | None = None
+
+
 def _backing_latencies(mem: MemoryModel, rng: np.random.Generator,
                        count: int) -> np.ndarray:
     """One backing-store draw per access reaching past the on-PL cache
@@ -1196,7 +1221,9 @@ class _SharedResolver(_OpFolder):
 
     After each :meth:`resolve` the per-op latency matrices are kept in
     ``last_ops`` (one ``(n, K)`` int32 matrix per model) — the rescache
-    artifact payload.
+    artifact payload.  Between a chunk's replay and its :meth:`finish`
+    the chunk's :class:`_ChunkScratch` is ``scratch`` (streaming) or a
+    value the caller holds (the fused phases of the chunk graph).
     """
 
     def __init__(self, stages: Sequence[SimStage],
@@ -1226,6 +1253,7 @@ class _SharedResolver(_OpFolder):
         self.last_hits: dict[str, np.ndarray | None] = {}
         self.last_visits: dict[str, np.ndarray | None] = {}
         self._lat_flat: dict[str, np.ndarray] = {}
+        self.scratch: _ChunkScratch | None = None
 
     # -- resume-state transport ------------------------------------------
 
@@ -1327,7 +1355,8 @@ class _SharedResolver(_OpFolder):
                 effects[key] = sim.export_stacks()
             return effects, len(addrs)
 
-    def chunk_effects_fused(self, lo: int, hi: int) -> tuple[dict, int]:
+    def chunk_effects_fused(self, lo: int, hi: int,
+                            ) -> tuple[dict, int, _ChunkScratch]:
         """Phases A **and** B in one replay: each geometry replays the
         chunk once from an empty cache via
         :meth:`BatchedCacheSim.fused_lookup`, which yields the chunk's
@@ -1335,7 +1364,12 @@ class _SharedResolver(_OpFolder):
         ambiguity table that :meth:`finalize_replay` later patches
         against the composed incoming state — exact, with no second
         replay.  This is what makes 2-core sharding break even: the
-        unfused executor paid two full cache replays per chunk."""
+        unfused executor paid two full cache replays per chunk.
+
+        Returns ``(effects, n_addrs, scratch)``; the chunk's
+        :class:`_ChunkScratch` goes back into :meth:`finalize_replay`
+        and then :meth:`finish`.  The resolver keeps none of it, so
+        several chunks may be between phases at once."""
         with _engine.phase("replay"):
             addrs, store_flat, flat_p, burst_words = \
                 self._flat_chunk(lo, hi)
@@ -1358,25 +1392,25 @@ class _SharedResolver(_OpFolder):
                     h[cached], amb = sim.fused_lookup(addrs[cached])
                 effects[key] = sim.export_stacks()
                 fused[key] = (h, amb, cached)
-            self._fused = (fused, effects)
-            self._store_flat = store_flat
-            self._flat_p = flat_p
-            self._burst_words = burst_words
-            self._n_addrs = len(addrs)
-            return effects, len(addrs)
+            scratch = _ChunkScratch(lo, hi, len(addrs), flat_p,
+                                    store_flat, burst_words,
+                                    fused=(fused, effects))
+            return effects, len(addrs), scratch
 
     def finalize_replay(self, state_map: Mapping | None,
+                        scratch: _ChunkScratch,
                         ) -> dict[tuple, tuple[int, int, int]]:
         """Phase B after :meth:`chunk_effects_fused`: patch the fused
-        pass's boundary-ambiguous verdicts against the incoming stacks
-        (``state_map``: geometry key -> ``(stacks, max_tag)``, ``None``
-        entries = empty cache), install each geometry's *outgoing*
-        state (incoming ∘ own effect), and return the same per-geometry
+        pass's boundary-ambiguous verdicts in ``scratch`` against the
+        incoming stacks (``state_map``: geometry key -> ``(stacks,
+        max_tag)``, ``None`` entries = empty cache), install each
+        geometry's *outgoing* state (incoming ∘ own effect) and keep a
+        copy of it in ``scratch.end``, and return the same per-geometry
         ``(hits, misses, backing_trips)`` deltas :meth:`replay`
         returns — bit-identical, without the second replay."""
         with _engine.phase("replay"):
-            fused, effects = self._fused
-            n_all = self._n_addrs
+            fused, effects = scratch.fused
+            n_all = scratch.n_addrs
             hits_by_key: dict[tuple, np.ndarray] = {}
             deltas: dict[tuple, tuple[int, int, int]] = {}
             for key, sim in self.caches.items():
@@ -1403,14 +1437,17 @@ class _SharedResolver(_OpFolder):
                 ncached = n_all if cached is None else int(cached.sum())
                 deltas[key] = (nh, ncached - nh, n_all - nh)
                 hits_by_key[key] = h
-            self._hits_by_key = hits_by_key
-            self._fused = None
+            scratch.hits_by_key = hits_by_key
+            scratch.end = {key: sim.export_stacks()
+                           for key, sim in self.caches.items()}
+            scratch.fused = None
             return deltas
 
     def replay(self, lo: int, hi: int) -> dict[tuple,
                                                tuple[int, int, int]]:
         """Cache replay for one chunk against the carried (or imported)
-        state: stores the per-geometry hit flags for :meth:`finish` and
+        state: keeps the chunk's :class:`_ChunkScratch` (the per-geometry
+        hit flags among it) in ``scratch`` for :meth:`finish` and
         returns each geometry's chunk-local ``(hits, misses,
         backing_trips)`` deltas.  ``backing_trips`` counts every access
         that reaches the backing store — cache misses *plus*
@@ -1429,11 +1466,9 @@ class _SharedResolver(_OpFolder):
                 hits_by_key[key] = h
                 deltas[key] = (sim.hits - h0, sim.misses - m0,
                                int(len(h) - h.sum()))
-            self._store_flat = store_flat
-            self._hits_by_key = hits_by_key
-            self._n_addrs = len(addrs)
-            self._flat_p = flat_p
-            self._burst_words = burst_words
+            self.scratch = _ChunkScratch(lo, hi, len(addrs), flat_p,
+                                         store_flat, burst_words,
+                                         hits_by_key)
             return deltas
 
     def resolve(self, lo: int, hi: int) -> dict[str, _ResolvedChunk]:
@@ -1443,24 +1478,26 @@ class _SharedResolver(_OpFolder):
         self.replay(lo, hi)
         return self.finish(lo, hi)
 
-    def finish(self, lo: int, hi: int,
-               fold: bool = True) -> dict[str, _ResolvedChunk]:
-        """Consume the backing-store draws for the chunk last replayed
-        and build the per-op latency matrices (plus the capture planes);
+    def finish(self, lo: int, hi: int, fold: bool = True,
+               scratch: _ChunkScratch | None = None,
+               ) -> dict[str, _ResolvedChunk]:
+        """Consume the backing-store draws for a replayed chunk —
+        ``scratch``, else the one :meth:`replay` kept — and build the
+        per-op latency matrices (plus the capture planes);
         ``fold=False`` skips the per-stage fold (the sharded executor's
         workers hand the raw matrices to the master, which folds)."""
         with _engine.phase("fold"):
-            return self._finish(lo, hi, fold)
+            return self._finish(lo, hi, fold, scratch or self.scratch)
 
-    def _finish(self, lo: int, hi: int,
-                fold: bool = True) -> dict[str, _ResolvedChunk]:
+    def _finish(self, lo: int, hi: int, fold: bool,
+                sc: _ChunkScratch) -> dict[str, _ResolvedChunk]:
         n = hi - lo
         K = self.K
-        addrs_n = self._n_addrs
-        flat_p = self._flat_p
-        store_flat = self._store_flat
-        hits_by_key = self._hits_by_key
-        burst_words = self._burst_words
+        addrs_n = sc.n_addrs
+        flat_p = sc.flat_p
+        store_flat = sc.store_flat
+        hits_by_key = sc.hits_by_key
+        burst_words = sc.burst_words
 
         def _lat_for(name: str, mem: MemoryModel) -> np.ndarray:
             """Per-participating-access latencies, consuming this
@@ -1552,7 +1589,8 @@ class _SharedResolver(_OpFolder):
         The draw *stream* consumed per lane is position-for-position the
         one the scalar reference consumes."""
         out = {}
-        store = self._store_flat
+        sc = self.scratch
+        store = sc.store_flat
         for name, mem in self.mems.items():
             counted = None
             if mem.posted_writes and len(store) and store.any():
@@ -1570,7 +1608,7 @@ class _SharedResolver(_OpFolder):
             key = self.cache_keys[name]
             if key is None:
                 # every valid access pays a backing trip
-                n = self._n_addrs
+                n = sc.n_addrs
                 a = (mem.port_latency - 1) // so
                 b = (mem.dram_latency - 1) // so
                 if rate <= 0.0:
@@ -1590,7 +1628,7 @@ class _SharedResolver(_OpFolder):
             # cache hits meet the assumed (hit) latency: zero stall; a
             # backing trip faster than the assumed latency stalls nothing
             # (clamp, not credit)
-            hit = self._hits_by_key[key]
+            hit = sc.hits_by_key[key]
             hc = mem.cache.hit_cycles
             a = max(mem.port_latency - hc, 0) // so
             b = max(mem.dram_latency - hc, 0) // so

@@ -1,5 +1,9 @@
 """Deterministic fault injection for the resolution/serving stack.
 
+Standard library only, so every layer may call its hooks: the store
+(:mod:`repro.core.rescache`), the chunk-graph worker
+(:mod:`repro.core.chunkgraph`) and the serving stack above them.
+
 A :class:`FaultPlan` is a seeded list of :class:`FaultSpec` entries,
 each naming a fault *kind*, a 1-based occurrence index ``at`` (fire on
 the Nth matching event), and optional filters (worker id, chunk index,
@@ -15,8 +19,12 @@ plan is armed** (a cached module check, no I/O):
 ========================  =====================================================
 kind                      site / effect
 ========================  =====================================================
-``worker_kill``           pool worker, start of a chunk task: SIGKILL itself
-``straggler``             pool worker, start of phase C: sleep ``delay_s``
+``worker_kill``           chunk-graph worker, start of a chunk task: SIGKILL
+                          itself once its replies are written (``worker``
+                          is the daemon's slot; library pool workers have
+                          none, so match no ``target``)
+``straggler``             chunk-graph worker, start of phase C: sleep
+                          ``delay_s``
 ``daemon_kill``           daemon, after committing chunk N: SIGKILL itself
 ``corrupt_chunk``         rescache ``put_chunk``: bit-flip bytes of the
                           just-written record (detected later by checksum)

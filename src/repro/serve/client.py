@@ -33,7 +33,8 @@ import time
 
 import numpy as np
 
-from . import faults, protocol
+from ..runtime import faults
+from . import protocol
 
 
 class ServeUnavailable(RuntimeError):

@@ -1,7 +1,8 @@
 """The resolution daemon: one global scheduler, many tenants.
 
-``ResolutionDaemon`` owns a spawn-pool of :mod:`repro.serve.worker`
-processes and a single work-stealing scheduler feeding them.  Clients
+``ResolutionDaemon`` owns a spawn-pool of chunk-graph workers
+(:func:`repro.core.chunkgraph.worker_main`, the library pool's worker
+loop) and a single work-stealing scheduler feeding them.  Clients
 (:mod:`repro.serve.client`) submit *resolution requests* — the live
 (un-served) models of one ``simulate_dataflow_many`` grid — over a
 local socket; the daemon answers with a stream of per-chunk completion
@@ -29,18 +30,23 @@ one credit per dispatched chunk; a request whose residue would push the
 global queue or its client's outstanding-chunks budget past the caps is
 rejected with a ``busy``/retry-after instead of queueing unboundedly.
 
-Failure semantics: a dead worker is respawned and its in-flight chunks'
-phase messages are replayed verbatim (resolution is deterministic, so
-the retry is bit-identical) under a per-job retry budget — beyond it
-the job fails loudly.  A disconnected client's requests detach; chunks
-no other client needs are cancelled (never dispatched), chunks already
-in flight or shared keep running, and the job's results remain
-attachable until the daemon retires it.
+Failure semantics: each worker replies on its own pipe, so a worker
+killed mid-reply tears nothing but that pipe, which the scheduler drops.
+A dead worker is respawned and its in-flight chunks'
+phase messages are resent verbatim from each job's
+:class:`~repro.core.chunkgraph.ChunkSequencer` log (resolution is
+deterministic, so the retry is bit-identical) under a per-job retry
+budget — beyond it the job fails loudly.  A disconnected client's
+requests detach; chunks no other client needs are cancelled (never
+dispatched), chunks already in flight or shared keep running, and the
+job's results remain attachable until the daemon retires it.
 
-The daemon is a *scheduling* layer only: workers run the same resolver,
-the same cache-effect monoid composition, and the same PCG64 draw
-positioning as the library engines, so results are bit-identical by
-construction.
+The daemon is a *scheduling* layer only: each job's serial scans are a
+:class:`~repro.core.chunkgraph.ChunkSequencer` and its chunks run in
+the chunk graph's worker loop — the library pool's own pieces — so
+results are bit-identical by construction.  What the daemon decides
+itself is where and when: admission, fairness, deadlines, speculation,
+retries and the in-order commit that streams chunks to clients.
 """
 
 from __future__ import annotations
@@ -54,27 +60,18 @@ import traceback
 import itertools
 from collections import OrderedDict
 
-from . import faults, protocol
+from ..core import rescache as _rc
+from ..core.simulator import _cache_group_key
+from ..core.chunkgraph import (RETRY_BUDGET, ChunkSequencer, _WINDOW,
+                               default_speculate_after_s, recv_replies,
+                               rescache_cfg, start_worker)
+from ..runtime import faults
+from . import protocol
 from .journal import Journal
-from .worker import worker_main
 
 #: per-process daemon-instance counter for the pidfile token (two
 #: daemon objects in one process must still conflict on one socket)
 _INSTANCE_IDS = itertools.count(1)
-
-#: Outstanding chunks per worker (matches the chunk-graph executor).
-_WINDOW = 2
-
-
-def _mk_rescache_cfg():
-    from ..core import rescache as _rc
-    return {
-        "enabled": _rc._cfg.enabled,
-        "directory": _rc._dir(),
-        "memory_mb": _rc._cfg.memory_mb,
-        "artifact_mb": _rc._cfg.artifact_mb,
-        "disk_mb": _rc._cfg.disk_mb,
-    }
 
 
 class _Request:
@@ -106,34 +103,24 @@ class _Request:
 
 
 class _Job:
-    """One in-flight resolution: the chunk-graph master state for one
-    content-key set, shared by every attached request."""
+    """One in-flight resolution for one content-key set, shared by every
+    attached request: its chunk-graph sequencer, its dispatch and commit
+    pointers, and the committed chunks' history for late attachers."""
 
-    def __init__(self, jid, keyset, payload, keys, mems, seed, n_iters):
-        from ..core.simulator import _cache_group_key
+    def __init__(self, jid, keyset, payload, keys, seed, n_iters,
+                 first_live, seq: ChunkSequencer):
         self.jid = jid
         self.keyset = keyset
         self.payload = payload
         self.keys = keys          # job model name -> v3 key
-        self.mems = mems
         self.seed = seed
         self.n_iters_hint = n_iters
-        self.geos = {mn: _cache_group_key(m) for mn, m in mems.items()}
-        self.first_live = 0
+        self.seq = seq
+        self.first_live = first_live
         self.sched_upto = 0       # chunks demanded so far
-        self.next_k = 0           # dispatch pointer
-        self.state_sent = 0
-        self.draws_sent = 0
-        self.committed = 0        # in-order commit watermark
-        self.state_at: dict[int, dict | None] = {}
-        self.effects: dict[int, dict] = {}
-        self.n_addrs: dict[int, int] = {}
-        self.deltas: dict[int, dict] = {}
+        self.next_k = first_live  # dispatch pointer
+        self.committed = first_live  # in-order commit watermark
         self.done_buf: dict[int, tuple] = {}
-        self.sent_state: dict[int, dict] = {}
-        self.sent_draws: dict[int, dict] = {}
-        self.cum_draws: dict[str, int] = {}
-        self.geo_cum: dict[tuple, tuple[int, int]] = {}
         self.cums_hist: dict[int, dict] = {}
         self.inline_hist: OrderedDict[int, tuple[int, dict]] = \
             OrderedDict()         # k -> (nbytes, inline)
@@ -172,8 +159,6 @@ class ResolutionDaemon:
                  journal: bool = True,
                  speculate_after_s: float | None = None,
                  speculate_factor: float = 4.0):
-        from ..core import rescache as _rc
-        from ..core.chunkgraph import RETRY_BUDGET
         from ..runtime.fault_tolerance import SpeculationPolicy
         if not _rc.enabled(None) or not _rc._dir():
             raise RuntimeError(
@@ -190,7 +175,6 @@ class ResolutionDaemon:
             else retry_budget
         self.throttle_s = throttle_s
         self.inline_cap = inline_history_mb * (1 << 20)
-        self._rc = _rc
         self._events: queue.Queue = queue.Queue()
         self._stop_evt = threading.Event()
         self._jobs: dict[int, _Job] = {}
@@ -214,11 +198,7 @@ class ResolutionDaemon:
         self._base: dict[str, int] = {}   # journaled pre-restart totals
         self._restarts = 0
         if speculate_after_s is None:
-            try:
-                speculate_after_s = float(
-                    os.environ.get("REPRO_SPECULATE_AFTER_S", "30"))
-            except ValueError:
-                speculate_after_s = 30.0
+            speculate_after_s = default_speculate_after_s()
         self._spec_policy = None if speculate_after_s <= 0 else \
             SpeculationPolicy(min_wait_s=speculate_after_s,
                               latency_factor=speculate_factor,
@@ -259,20 +239,11 @@ class ResolutionDaemon:
 
     def start(self) -> None:
         self._guard_pidfile()
-        ctx = multiprocessing.get_context("spawn")
-        self._ctx = ctx
-        self._result_q = ctx.Queue()
-        cfg = _mk_rescache_cfg()
-        self._cfg = cfg
-        self._task_qs = [ctx.Queue() for _ in range(self.workers)]
-        self._procs = [ctx.Process(
-            target=worker_main,
-            args=(w, self.C, self._task_qs[w], self._result_q, cfg),
-            daemon=True) for w in range(self.workers)]
-        from ..core.engine import cpu_children
-        with cpu_children():
-            for p in self._procs:
-                p.start()
+        self._ctx = multiprocessing.get_context("spawn")
+        self._cfg = rescache_cfg()
+        self._procs, self._task_qs, self._result_conns = map(list, zip(*(
+            start_worker(self._ctx, w, self.C, self._cfg)
+            for w in range(self.workers))))
         self._known = [set() for _ in range(self.workers)]
         self._load = [0] * self.workers
         self._busy_s = [0.0] * self.workers
@@ -386,6 +357,9 @@ class ResolutionDaemon:
             # blocked; don't let its exit finalizer hang the process
             q.cancel_join_thread()
             q.close()
+        for c in getattr(self, "_result_conns", []):
+            if c is not None:
+                c.close()
 
     # -- socket side ---------------------------------------------------------
 
@@ -424,17 +398,11 @@ class ResolutionDaemon:
         while not self._stop_evt.is_set():
             busy = any(j.live() for j in self._jobs.values()) \
                 or self._inflight
-            try:
-                msg = self._result_q.get(timeout=0.05 if busy else 0.25)
-            except queue.Empty:
-                msg = None
-            if msg is not None:
-                self._on_worker_msg(msg)
-            while True:
-                try:
-                    self._on_worker_msg(self._result_q.get_nowait())
-                except queue.Empty:
-                    break
+            timeout = 0.05 if busy else 0.25
+            while msgs := recv_replies(self._result_conns, timeout):
+                for msg in msgs:
+                    self._on_worker_msg(msg)
+                timeout = 0
             while True:
                 try:
                     ev = self._events.get_nowait()
@@ -627,36 +595,34 @@ class ResolutionDaemon:
         return None
 
     def _new_job(self, msg, keys) -> _Job | None:
-        _rc = self._rc
         self._jid += 1
-        j = _Job(self._jid, frozenset(keys.values()), msg["payload"],
-                 keys, dict(msg["mems"]), int(msg["seed"]),
-                 int(msg["n_iters"]))
+        mems = dict(msg["mems"])
         full = [(_rc.prefix(k, self.C))[0] for k in keys.values()]
-        j.first_live = min(full) if full else 0
-        if j.first_live > 0:
-            recs = {mn: _rc.get_chunk(k, j.first_live - 1, refresh=True)
-                    for mn, k in j.keys.items()}
+        first_live = min(full) if full else 0
+        recs = {}
+        if first_live > 0:
+            recs = {mn: _rc.get_chunk(k, first_live - 1, refresh=True)
+                    for mn, k in keys.items()}
             if any(rec is None for rec in recs.values()):
-                j.first_live = 0
-        if j.first_live > 0:
-            state = {}
-            for mn, rec in recs.items():
-                j.cum_draws[mn] = int(rec.cum.get("draws", 0))
-                geo = j.geos[mn]
-                if geo is not None:
-                    state[geo] = (rec.states["cache"],
-                                  int(rec.cum.get("max_tag", -1)))
-                    j.geo_cum[geo] = (int(rec.cum.get("hits", 0)),
-                                      int(rec.cum.get("misses", 0)))
-            j.state_at[j.first_live] = state
-        else:
-            j.state_at[0] = None
-            j.cum_draws = {mn: 0 for mn in j.keys}
-            j.geo_cum = {g: (0, 0) for g in j.geos.values()
-                         if g is not None}
-        j.next_k = j.state_sent = j.draws_sent = j.first_live
-        j.committed = j.first_live
+                first_live, recs = 0, {}
+        # resume from the stored prefix: the last record's cache stacks,
+        # draw positions and hit/miss counters
+        geos = {mn: _cache_group_key(m) for mn, m in mems.items()}
+        state = {} if recs else None
+        cum_draws = {mn: 0 for mn in keys}
+        geo_cum = {g: (0, 0) for g in geos.values() if g is not None}
+        for mn, rec in recs.items():
+            cum_draws[mn] = int(rec.cum.get("draws", 0))
+            if geos[mn] is not None:
+                state[geos[mn]] = (rec.states["cache"],
+                                   int(rec.cum.get("max_tag", -1)))
+                geo_cum[geos[mn]] = (int(rec.cum.get("hits", 0)),
+                                     int(rec.cum.get("misses", 0)))
+        seq = ChunkSequencer(self._jid, mems, first_live, state,
+                             cum_draws, geo_cum)
+        j = _Job(self._jid, frozenset(keys.values()), msg["payload"],
+                 keys, int(msg["seed"]), int(msg["n_iters"]), first_live,
+                 seq)
         self._jobs[j.jid] = j
         self._by_keyset[j.keyset] = j.jid
         return j
@@ -687,13 +653,12 @@ class ResolutionDaemon:
             if self.throttle_s:
                 time.sleep(self.throttle_s)
             k = j.next_k
-            if j.jid not in self._known[w]:
-                self._task_qs[w].put(("job", j.jid, j.payload))
-                self._known[w].add(j.jid)
-            # full canonical chunks always: traces pad past their end,
-            # so records never need a partial tail (see worker module)
-            self._task_qs[w].put(("task", j.jid, k, k * self.C,
-                                  (k + 1) * self.C))
+            self._ensure_job(w, j)
+            # full canonical chunks always: traces pad with -1 past
+            # their end, so every committed record is a full chunk and
+            # a shorter client's run is a prefix of the same bits
+            self._task_qs[w].put(j.seq.task(k, k * self.C,
+                                            (k + 1) * self.C))
             self._inflight[(j.jid, k)] = w
             self._dispatch_t[(j.jid, k)] = time.monotonic()
             self._load[w] += 1
@@ -707,47 +672,22 @@ class ResolutionDaemon:
             self._pump(j)
 
     def _pump(self, j: _Job) -> None:
-        """Send composed states and draw offsets for chunks whose
-        predecessors have reported — the serial scans of the chunk
-        graph, identical to the chunk-graph master."""
-        while j.state_sent < j.next_k and j.state_sent in j.state_at:
-            k = j.state_sent
-            w = self._inflight.get((j.jid, k))
-            if w is None:
-                break
-            j.sent_state[k] = j.state_at[k] or {}
-            self._task_qs[w].put(("state", j.jid, k, k * self.C,
-                                  (k + 1) * self.C, j.sent_state[k]))
-            j.state_sent += 1
-        while j.draws_sent < j.next_k and j.draws_sent in j.deltas:
-            k = j.draws_sent
-            w = self._inflight.get((j.jid, k))
-            if w is None:
-                break
-            msg = {}
-            for mn, mem in j.mems.items():
-                geo = j.geos[mn]
-                entry = {"base": j.cum_draws[mn]}
-                if mem.backing_hit_rate > 0.0:
-                    j.cum_draws[mn] += j.deltas[k][geo][2] \
-                        if geo is not None else j.n_addrs[k]
-                if geo is not None:
-                    h, m = j.geo_cum[geo]
-                    entry["hits_after"] = h + j.deltas[k][geo][0]
-                    entry["misses_after"] = m + j.deltas[k][geo][1]
-                msg[mn] = entry
-            for geo, d in j.deltas[k].items():
-                h, m = j.geo_cum[geo]
-                j.geo_cum[geo] = (h + d[0], m + d[1])
-            j.sent_draws[k] = msg
-            self._task_qs[w].put(("draws", j.jid, k, msg))
-            del j.deltas[k]
-            j.n_addrs.pop(k, None)
-            j.effects.pop(k, None)
-            j.draws_sent += 1
-        for i in [i for i in j.state_at
-                  if i < j.state_sent and i + 1 in j.state_at]:
-            del j.state_at[i]
+        """Send the composed states and draw offsets the job's
+        sequencer has ready, each to its chunk's worker."""
+        for m in j.seq.pump(j.next_k):
+            self._task_qs[self._inflight[(j.jid, m[2])]].put(m)
+
+    def _ensure_job(self, w: int, j: _Job) -> None:
+        if j.jid not in self._known[w]:
+            self._task_qs[w].put(("job", j.jid, j.payload))
+            self._known[w].add(j.jid)
+
+    def _resend(self, w: int, j: _Job, k: int) -> None:
+        """Replay chunk ``k``'s message log on worker ``w``."""
+        self._ensure_job(w, j)
+        for m in j.seq.resend(k):
+            self._task_qs[w].put(m)
+        self._load[w] += 1
 
     # -- worker replies ------------------------------------------------------
 
@@ -773,21 +713,10 @@ class ResolutionDaemon:
                     self._load[wid] = max(0, self._load[wid] - 1)
             return
         if kind == "effect":
-            eff, na = rest[0], rest[1]
-            if k + 1 in j.state_at or k < j.draws_sent:
-                return  # duplicate from a retried chunk
-            from ..core.chunkgraph import _compose_state
-            j.effects[k] = eff
-            j.n_addrs[k] = na
-            while (k + 1 not in j.state_at) and k in j.state_at \
-                    and k in j.effects:
-                j.state_at[k + 1] = _compose_state(j.state_at[k],
-                                                   j.effects.pop(k))
-                k += 1
+            j.seq.effect(k, rest[0], rest[1])
             self._pump(j)
         elif kind == "replay":
-            if k >= j.draws_sent:
-                j.deltas[k] = rest[0]
+            j.seq.replay(k, rest[0])
             self._pump(j)
         elif kind == "done":
             key = (j.jid, k)
@@ -808,8 +737,7 @@ class ResolutionDaemon:
                     self._spec_policy.wins += 1
                     self._stats["speculative_wins"] += 1
                 j.done_buf[k] = (rest[0], rest[1])
-                j.sent_state.pop(k, None)
-                j.sent_draws.pop(k, None)
+                j.seq.done(k)
                 self._commit(j)
 
     def _commit(self, j: _Job) -> None:
@@ -832,7 +760,7 @@ class ResolutionDaemon:
                     j.inline_bytes -= ob
                     j.inline_dropped.add(old)
             j.committed += 1
-            self._rc.note_chunks(cold=1)
+            _rc.note_chunks(cold=1)
             for r in list(j.requests):
                 if not r.done and r.next_notify == k \
                         and k < r.n_chunks:
@@ -958,7 +886,7 @@ class ResolutionDaemon:
                 self._dispatch_t[key] = time.monotonic()
             else:
                 redo.append(key + (w,))
-        self._rc.note_worker_retries(len(redo))
+        _rc.note_worker_retries(len(redo))
         self._stats["chunk_retries"] += len(redo)
         for w in dead:
             # the old queue's feeder thread may be wedged on a pipe
@@ -966,15 +894,10 @@ class ResolutionDaemon:
             old = self._task_qs[w]
             old.cancel_join_thread()
             old.close()
-            self._task_qs[w] = self._ctx.Queue()
-            self._procs[w] = self._ctx.Process(
-                target=worker_main,
-                args=(w, self.C, self._task_qs[w], self._result_q,
-                      self._cfg),
-                daemon=True)
-            from ..core.engine import cpu_children
-            with cpu_children():
-                self._procs[w].start()
+            if self._result_conns[w] is not None:
+                self._result_conns[w].close()  # torn reply and all
+            self._procs[w], self._task_qs[w], self._result_conns[w] = \
+                start_worker(self._ctx, w, self.C, self._cfg)
             self._known[w] = set()
             self._load[w] = 0
         over_budget = set()
@@ -984,8 +907,8 @@ class ResolutionDaemon:
                 self._inflight.pop((jid, k), None)
                 self._dispatch_t.pop((jid, k), None)
                 continue
-            if k < j.committed:
-                # a speculative copy already committed this chunk; the
+            if k < j.committed or k in j.done_buf:
+                # a speculative copy already finished this chunk; the
                 # straggler died afterwards — nothing to redo
                 self._inflight.pop((jid, k), None)
                 self._dispatch_t.pop((jid, k), None)
@@ -997,20 +920,8 @@ class ResolutionDaemon:
                     j, f"worker(s) {dead} died; retry budget "
                        f"exhausted ({j.retries} > {self.retry_budget})")
                 continue
-            if jid not in self._known[w]:
-                self._task_qs[w].put(("job", jid, j.payload))
-                self._known[w].add(jid)
-            self._task_qs[w].put(("task", jid, k, k * self.C,
-                                  (k + 1) * self.C))
-            if k < j.state_sent:
-                self._task_qs[w].put(("state", jid, k, k * self.C,
-                                      (k + 1) * self.C,
-                                      j.sent_state[k]))
-            if k < j.draws_sent:
-                self._task_qs[w].put(("draws", jid, k,
-                                      j.sent_draws[k]))
+            self._resend(w, j, k)
             self._dispatch_t[(jid, k)] = time.monotonic()
-            self._load[w] += 1
 
     def _check_deadlines(self, now: float) -> None:
         """Fail requests past their deadline (1 Hz).  The request's
@@ -1032,7 +943,7 @@ class ResolutionDaemon:
     def _check_stragglers(self, now: float) -> None:
         """Speculative re-dispatch (1 Hz): a chunk whose wall exceeds
         the policy threshold gets a duplicate dispatch on another
-        worker — task, state, and draws replayed verbatim, which is
+        worker — its task, state and draws resent verbatim, which is
         only possible once all three were sent (a phase-C straggler:
         the heavy phase).  Both copies compute identical bits; the
         first ``done`` commits, the loser's is discarded by the
@@ -1047,7 +958,7 @@ class ResolutionDaemon:
             j = self._jobs.get(jid)
             if j is None or j.failed:
                 continue
-            if k not in j.sent_state or k not in j.sent_draws:
+            if not j.seq.in_phase_c(k):
                 continue  # not yet in phase C: nothing to replay
             t0 = self._dispatch_t.get(key)
             if t0 is None or not pol.overdue(now - t0):
@@ -1060,16 +971,8 @@ class ResolutionDaemon:
             if not cands:
                 break
             w2 = min(cands, key=lambda i: self._load[i])
-            if jid not in self._known[w2]:
-                self._task_qs[w2].put(("job", jid, j.payload))
-                self._known[w2].add(jid)
-            self._task_qs[w2].put(("task", jid, k, k * self.C,
-                                   (k + 1) * self.C))
-            self._task_qs[w2].put(("state", jid, k, k * self.C,
-                                   (k + 1) * self.C, j.sent_state[k]))
-            self._task_qs[w2].put(("draws", jid, k, j.sent_draws[k]))
+            self._resend(w2, j, k)
             self._spec[key] = w2
-            self._load[w2] += 1
             pol.issued += 1
             self._stats["speculative_dispatches"] += 1
 
@@ -1123,5 +1026,5 @@ class ResolutionDaemon:
             "faults_injected": faults.stats(),
             "jobs_completed": s["jobs_completed"],
             "requests": list(self._req_log),
-            "census": self._rc.census(),
+            "census": _rc.census(),
         }

@@ -397,3 +397,173 @@ def test_gc_removes_orphans_and_enforces_cap(small_chunks):
                              use_rescache=False)
     again = simulate_dataflow(stages, acp_cache(), 5000, fifo_depth=16)
     _assert_same(again, cold)
+
+
+# ---------------------------------------------------------------------------
+# The one chunk-resolution protocol: worker job object and sequencer
+# ---------------------------------------------------------------------------
+
+def _npz_arrays(path):
+    with np.load(path) as z:
+        return {k: z[k] for k in z.files}
+
+
+@pytest.mark.parametrize("order", ["pool", "daemon"])
+def test_chunk_job_matches_streaming(order, small_chunks, tmp_path):
+    """The worker's per-job object, driven in process through the
+    sequencer in the pool's phase order (A0 B0 C0 A1 B1 C1 …) or the
+    daemon's interleaved one (A0 A1 … B0 B1 … C0 C1 …), gives the
+    streaming resolver's replay deltas and latency matrices, and chunk
+    records equal to the streaming engine's, array for array."""
+    from test_engine import _two_stage
+
+    from repro.core.chunkgraph import ChunkJob, ChunkSequencer
+    from repro.core.simulator import _SharedResolver
+    C, n = rc.CHUNK_ITERS, 2048
+    stages = _two_stage(n, 4, store_heavy=True)
+
+    def mems():
+        return {"A": acp_cache(), "H": hp_cache(), "U": acp(),
+                "W": MemoryModel(name="w", cache=CacheConfig(
+                    write_allocate=False))}
+
+    ref = _SharedResolver(stages, mems(), seed=0, capture=True)
+    want_deltas, want_ops = {}, {}
+    for k in range(n // C):
+        want_deltas[k] = ref.replay(k * C, (k + 1) * C)
+        ref.finish(k * C, (k + 1) * C, fold=False)
+        want_ops[k] = {mn: L.copy() for mn, L in ref.last_ops.items()}
+    stream_dir = str(tmp_path / "stream")
+    rc.clear()
+    rc.configure(enabled=True, directory=stream_dir)
+    simulate_dataflow_many(stages, mems(), n, fifo_depths=(8,))
+
+    job_dir = str(tmp_path / "job")
+    rc.clear()
+    rc.configure(enabled=True, directory=job_dir)
+    m = mems()
+    keys = {mn: rc.resolution_key("dataflow", stages, mem, 0)
+            for mn, mem in m.items()}
+    job = ChunkJob({"stages": stages, "mems": m, "seed": 0,
+                    "n_iters": n, "keys": keys})
+    seq = ChunkSequencer(0, m, 0, None, {mn: 0 for mn in m},
+                         {g: (0, 0) for g in job.resolver.caches})
+    got_deltas, cums = {}, {}
+
+    def deliver(msgs):
+        for kind, _, k, *args in msgs:
+            if kind == "task":
+                seq.effect(k, *job.effect(k, *args))
+            elif kind == "state":
+                got_deltas[k] = job.finalize(k, *args)
+                seq.replay(k, got_deltas[k])
+            else:
+                cums[k], inline = job.draws(k, *args)
+                assert all(v is None for v in inline.values())
+                seq.done(k)
+
+    chunks = range(n // C)
+    if order == "pool":
+        for k in chunks:
+            deliver([seq.task(k, k * C, (k + 1) * C)])
+            deliver(seq.pump(k + 1))  # state k
+            deliver(seq.pump(k + 1))  # draws k
+    else:
+        deliver([seq.task(k, k * C, (k + 1) * C) for k in chunks])
+        deliver(seq.pump(len(chunks)))  # every state
+        deliver(seq.pump(len(chunks)))  # every draws
+    assert sorted(cums) == list(chunks)
+    assert got_deltas == want_deltas
+    for mn, key in keys.items():
+        for k in chunks:
+            rec = rc.get_chunk(key, k)
+            assert np.array_equal(rec.ops.astype(np.int32),
+                                  want_ops[k][mn]), (mn, k)
+            name = os.path.basename(rc._chunk_path(job_dir, key, k))
+            a = _npz_arrays(os.path.join(job_dir, name))
+            b = _npz_arrays(os.path.join(stream_dir, name))
+            assert sorted(a) == sorted(b), (mn, k)
+            for f in a:
+                assert a[f].dtype == b[f].dtype, (mn, k, f)
+                assert np.array_equal(a[f], b[f]), (mn, k, f)
+
+
+def _seq_fixture():
+    """A sequencer over four chunks of a tiny pipeline, with the chunks'
+    own effects and the replay deltas a warm streaming replay gives."""
+    from repro.core.chunkgraph import ChunkSequencer, _compose_state
+    from repro.core.simulator import _SharedResolver
+    C, K = 512, 4
+    stages = _pipeline(C * K, seed=3)
+    mems = {"A": acp_cache(), "U": acp()}
+    own = _SharedResolver(stages, mems, 0)
+    effects = [own.chunk_effects(k * C, (k + 1) * C) for k in range(K)]
+    warm = _SharedResolver(stages, mems, 0)
+    deltas = [warm.replay(k * C, (k + 1) * C) for k in range(K)]
+    states = [None]
+    for eff, _ in effects:
+        states.append(_compose_state(states[-1], eff))
+    seq = ChunkSequencer(7, mems, 0, None, {mn: 0 for mn in mems},
+                         {g: (0, 0) for g in own.caches})
+    tasks = [seq.task(k, k * C, (k + 1) * C) for k in range(K)]
+    return seq, tasks, effects, deltas, states
+
+
+def _same_state(a, b):
+    assert sorted(a or {}) == sorted(b or {})
+    for g in a or {}:
+        assert np.array_equal(a[g][0], b[g][0]) and a[g][1] == b[g][1]
+
+
+@pytest.mark.parametrize("case", ["out_of_order", "duplicate", "resend"])
+def test_sequencer(case):
+    """The master-side sequencer: effects arriving out of order compose
+    in chunk order; a duplicate effect or replay (a resent chunk's) is
+    ignored; a chunk's resend log is exactly the messages first sent
+    for it, until its ``done``."""
+    seq, tasks, effects, deltas, states = _seq_fixture()
+    K = len(tasks)
+    if case == "out_of_order":
+        sent = []
+        for k in (2, 0, 3, 1):
+            seq.effect(k, *effects[k])
+            sent += seq.pump(K)
+        assert [(m[0], m[2]) for m in sent] == \
+            [("state", 0), ("state", 1), ("state", 2), ("state", 3)]
+        for k, m in enumerate(sent):
+            assert m[1] == 7
+            _same_state(m[3], states[k])
+        return
+    for k in range(K):
+        seq.effect(k, *effects[k])
+    if case == "duplicate":
+        bogus = (effects[3][0], effects[1][1] + 12345)
+        first = seq.pump(K)
+        seq.effect(1, *bogus)  # resent chunk's effect: composed already
+        for k in range(K):
+            seq.replay(k, deltas[k])
+        draws = seq.pump(K)
+        seq.replay(0, deltas[1])  # after its draws went out
+        seq.effect(0, *bogus)
+        assert seq.pump(K) == []
+        for k, m in enumerate(first):
+            _same_state(m[3], states[k])
+        cum = {"A": 0, "U": 0}
+        for k, m in enumerate(draws):
+            assert m[:3] == ("draws", 7, k)
+            assert m[3]["A"]["base"] == cum["A"]
+            assert m[3]["U"]["base"] == cum["U"]
+            geo = seq.geos["A"]
+            cum["A"] += deltas[k][geo][2]
+            cum["U"] += effects[k][1]
+        return
+    seq.replay(0, deltas[0])
+    sent = seq.pump(2)  # states 0, 1 and draws 0
+    assert seq.resend(0) == [tasks[0], sent[0], sent[2]]
+    assert seq.in_phase_c(0)
+    assert seq.resend(1) == [tasks[1], sent[1]]
+    assert not seq.in_phase_c(1)
+    assert seq.resend(2) == [tasks[2]]
+    seq.done(0)
+    assert not seq.in_phase_c(0)
+    assert seq.resend(1) == [tasks[1], sent[1]]

@@ -314,14 +314,14 @@ def test_chunk_effects_fused_equals_replay(store_heavy):
     for lo in range(0, n, c):
         hi = min(n, lo + c)
         d_seq = seq.replay(lo, hi)
-        eff, na = fus.chunk_effects_fused(lo, hi)
+        eff, na, sc = fus.chunk_effects_fused(lo, hi)
         assert set(eff) == set(fus.caches)
-        assert na == fus._n_addrs
-        d_fus = fus.finalize_replay(states)
+        assert na == sc.n_addrs
+        d_fus = fus.finalize_replay(states, sc)
         assert d_fus == d_seq
         for key in seq.caches:
-            assert np.array_equal(fus._hits_by_key[key],
-                                  seq._hits_by_key[key]), (lo, key)
+            assert np.array_equal(sc.hits_by_key[key],
+                                  seq.scratch.hits_by_key[key]), (lo, key)
             assert np.array_equal(fus.caches[key].export_stacks()[0],
                                   seq.caches[key].export_stacks()[0])
         states = {key: fus.caches[key].export_stacks()
@@ -335,7 +335,7 @@ def test_chunk_effects_fused_matches_chunk_effects():
     r1 = _SharedResolver(_two_stage(n, 5), {"A": acp_cache()}, seed=0)
     r2 = _SharedResolver(_two_stage(n, 5), {"A": acp_cache()}, seed=0)
     e1, na1 = r1.chunk_effects(0, n)
-    e2, na2 = r2.chunk_effects_fused(0, n)
+    e2, na2, _ = r2.chunk_effects_fused(0, n)
     assert na1 == na2 and set(e1) == set(e2)
     for k in e1:
         assert np.array_equal(e1[k][0], e2[k][0])

@@ -1,4 +1,4 @@
-"""Chaos harness: deterministic fault injection (repro.serve.faults)
+"""Chaos harness: deterministic fault injection (repro.runtime.faults)
 driven end-to-end through the resolution/serving stack.
 
 The contract under test: **every fault scenario ends bit-identical to a
@@ -31,7 +31,7 @@ import pytest
 
 from repro.core import rescache as rc
 from repro.core.simulator import (acp, acp_cache, simulate_dataflow_many)
-from repro.serve import faults
+from repro.runtime import faults
 
 from _serve_client import pipeline
 
@@ -263,6 +263,107 @@ def test_chaos_worker_sigkill_mid_chunk(store, monkeypatch, tmp_path):
     assert st["failures"]["worker_restarts"] >= 1
     assert st["failures"]["chunk_retries"] >= 1
     assert st["jobs_completed"] == 1
+
+
+def test_torn_reply_is_dropped_not_awaited(monkeypatch):
+    """A worker killed while it writes a reply (an OOM kill mid-send)
+    leaves a torn message in its own result pipe only: the scheduler's
+    read gives end of file and drops the connection instead of blocking
+    for ever, and a whole reply on another worker's pipe still comes
+    through."""
+    import multiprocessing
+    import signal
+
+    import cloudpickle
+
+    from repro.core.chunkgraph import recv_replies, rescache_cfg, \
+        start_worker
+    C = 1 << 17
+    ctx = multiprocessing.get_context("spawn")
+    cfg = rescache_cfg()
+    procs, task_qs, conns = map(list, zip(
+        *(start_worker(ctx, w, C, cfg) for w in range(2))))
+    payload = cloudpickle.dumps({"stages": pipeline(2 * C),
+                                 "mems": {"U": acp()}, "seed": 0,
+                                 "n_iters": 2 * C, "keys": {"U": None}})
+    try:
+        for w in range(2):
+            task_qs[w].put(("job", 0, payload))
+            task_qs[w].put(("task", 0, w, w * C, (w + 1) * C))
+            task_qs[w].put(("state", 0, w, {}))
+            task_qs[w].put(("draws", 0, w, {"U": {"base": 0}}))
+        for w in range(2):  # effect and replay of each worker's chunk
+            for kind in ("effect", "replay"):
+                assert conns[w].poll(120)
+                assert conns[w].recv()[0] == kind
+        # the done reply carries the 393 KB inline latency matrix: it
+        # overfills the pipe, so worker 0 is mid-send once bytes show
+        assert conns[0].poll(120)
+        os.kill(procs[0].pid, signal.SIGKILL)
+        procs[0].join(timeout=60)
+        got = []
+        t0 = time.monotonic()
+        while (conns[0] is not None or not got) \
+                and time.monotonic() - t0 < 120:
+            got += recv_replies(conns, 1.0)
+        assert conns[0] is None  # torn pipe dropped
+        assert [(m[0], m[1], m[3]) for m in got] == [("done", 1, 1)]
+        assert got[0][5]["U"]["ops"].shape == (C, 3)
+        assert got[0][5]["U"]["ops"].nbytes > 4 * (1 << 16)
+    finally:
+        for w in range(2):
+            task_qs[w].put(("stop",))
+            task_qs[w].cancel_join_thread()
+            procs[w].join(timeout=30)
+            if procs[w].is_alive():
+                procs[w].terminate()
+        for c in conns:
+            if c is not None:
+                c.close()
+
+
+def _start_worker_and_vanish(q):
+    """A scheduler that starts one worker, reports its pid and dies
+    without stopping it, once the worker is serving (it has answered a
+    task of a job it never got with an error)."""
+    import multiprocessing
+
+    from repro.core.chunkgraph import rescache_cfg, start_worker
+    proc, task_q, conn = start_worker(multiprocessing.get_context("spawn"),
+                                      0, 1 << 10, rescache_cfg())
+    task_q.put(("task", 0, 0, 0, 1))
+    assert conn.recv()[0] == "error"
+    q.put(proc.pid)
+    q.close()
+    q.join_thread()
+    os._exit(0)
+
+
+def test_orphaned_worker_exits():
+    """A worker whose scheduler was killed (the daemon's SIGKILL drill,
+    an OOM kill) exits instead of waiting for tasks for ever."""
+    import multiprocessing
+    ctx = multiprocessing.get_context("spawn")
+    q = ctx.Queue()
+    helper = ctx.Process(target=_start_worker_and_vanish, args=(q,))
+    helper.start()
+    pid = q.get(timeout=120)
+    helper.join(timeout=60)
+
+    def gone() -> bool:
+        try:
+            with open(f"/proc/{pid}/stat") as f:
+                return f.read().rsplit(")", 1)[1].split()[0] == "Z"
+        except FileNotFoundError:
+            return True
+
+    t0 = time.monotonic()
+    while not gone() and time.monotonic() - t0 < 120:
+        time.sleep(0.2)
+    alive = not gone()
+    if alive:
+        os.kill(pid, 9)
+    assert not alive, "the orphaned worker kept running"
 
 
 def test_chaos_straggler_speculative_dispatch(store, monkeypatch,
