@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from . import dataflow_matmul as _mm
 from . import flash_attention as _fa
 from . import grouped_matmul as _gmm
+from . import mla_decode as _mla
 from . import rmsnorm as _rn
 from . import spmv as _spmv
 from . import ref as ref  # re-exported for tests/benchmarks
@@ -164,3 +165,40 @@ def grouped_matmul(x: jax.Array, w: jax.Array, tiles: jax.Array,
                                groups=tiles.shape[0] - 1,
                                block_rows=block_rows, block_cols=block_cols,
                                interpret=_interpret())
+
+
+#: positions in a block of the MLA decode kernel
+MLA_BLOCK = _mla.BLOCK
+
+#: VMEM for one MLA decode call's blocks and row state
+_MLA_VMEM = 24 * 1024 * 1024
+
+
+def mla_decode_block_rows(B: int, H: int, r: int, rope: int,
+                          itemsize: int) -> int:
+    """Batch rows a tile of the MLA decode kernel: the largest power of
+    two dividing B whose tile fits :data:`_MLA_VMEM` with its cache blocks
+    and queries double buffered, and its output (double buffered) and
+    accumulator in float32."""
+    row = (2 * (MLA_BLOCK + H) * (r + rope) * itemsize + 3 * H * r * 4)
+    rows = B & -B
+    while rows > 1 and rows * row > _MLA_VMEM:
+        rows //= 2
+    return rows
+
+
+def mla_decode(q_lat: jax.Array, q_pe: jax.Array, c_kv: jax.Array,
+               k_pe: jax.Array, rep: jax.Array, length: jax.Array, *,
+               scale: float) -> jax.Array:
+    """Absorbed-MLA decode attention over layer ``rep`` of the stacked
+    latent cache (see kernels/mla_decode.py): ``q_lat`` (B, H, r) and
+    ``q_pe`` (B, H, rope) against ``c_kv`` (repeats, B, S, r) and ``k_pe``
+    (repeats, B, rope, S'), positions ``0 .. length``; ``o_lat`` (B, H, r)
+    float32.  The queries enter the products in the cache's dtype."""
+    B, H, r = q_lat.shape
+    rows = mla_decode_block_rows(B, H, r, q_pe.shape[-1],
+                                 c_kv.dtype.itemsize)
+    return _mla.mla_decode(q_lat.astype(c_kv.dtype),
+                           q_pe.astype(k_pe.dtype), c_kv, k_pe, rep, length,
+                           scale=scale, block_rows=rows,
+                           interpret=_interpret())

@@ -74,7 +74,13 @@ class BatchedServer:
     counts ``moe.grouped.pallas`` and ``moe.grouped.ragged_dot``: the
     expert-layer dispatches (per prefill block and per decode step) that
     ran on each path of the grouped matmul, the path the model takes on
-    this backend (:func:`repro.models.moe.grouped_path`).
+    this backend (:func:`repro.models.moe.grouped_path`).  A model with
+    absorbed-MLA layers counts ``attn.mla_decode.pallas`` and
+    ``attn.mla_decode.xla``, its MLA layer-steps of decode on each path of
+    the latent attention (:func:`repro.models.attention.mla_decode_path`),
+    and ``attn.mla_decode.blocks_live`` / ``.blocks_total``, the
+    128-position blocks of the rows' latent caches those layer-steps read
+    and the blocks the caches hold.
 
     The decode step donates its cache argument: each step writes this
     token's entries into the cache it was given, so the cache a caller
@@ -93,6 +99,10 @@ class BatchedServer:
                                                  for s in seg.unit)
                                for seg in cfg.segments)
         load = {"expert_load": True} if self._moe_layers else {}
+        #: absorbed-MLA layers, whose decode attention has two paths
+        self._mla_layers = sum(
+            seg.repeats * sum(s.mixer == "mla" for s in seg.unit)
+            for seg in cfg.segments) if cfg.mla_absorbed else 0
 
         # named for the trace's modules: jit_prefill_step, jit_decode_step
         def prefill_step(p, t):
@@ -194,7 +204,28 @@ class BatchedServer:
         if moe:
             self._count_pairs(loads)
             self._count_dispatches(len(requests), S, gen)
+        if self._mla_layers:
+            self._count_mla_decode(len(requests), S, gen)
         return outs
+
+    def _count_mla_decode(self, batch: int, prompt: int, gen: int) -> None:
+        """``attn.mla_decode.<path>``: a batch's absorbed-MLA layer-steps
+        on the path the decode step was traced with, the other path 0;
+        ``attn.mla_decode.blocks_live`` and ``.blocks_total``: the
+        128-position blocks of the rows' latent caches that those
+        layer-steps read, and the blocks the caches hold."""
+        from ..models import attention
+        path = attention.mla_decode_path()
+        for p in ("pallas", "xla"):
+            trace.count(f"attn.mla_decode.{p}",
+                        self._mla_layers * gen if p == path else 0)
+        blocks = [attention.mla_decode_blocks(prompt + step, self.max_len)
+                  for step in range(gen)]
+        rows = self._mla_layers * batch
+        trace.count("attn.mla_decode.blocks_live",
+                    rows * sum(n for n, _ in blocks))
+        trace.count("attn.mla_decode.blocks_total",
+                    rows * sum(n for _, n in blocks))
 
     def _count_dispatches(self, batch: int, prompt: int, gen: int) -> None:
         """``moe.grouped.<path>``: a batch's expert-layer dispatches, its

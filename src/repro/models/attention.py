@@ -244,11 +244,13 @@ def gqa_decode_entries(params: dict, x: jax.Array, length: jax.Array,
     return q, {"k": k, "v": v}
 
 
-def gqa_decode_attend(params: dict, q: jax.Array, cache: dict,
-                      length: jax.Array, cfg) -> jax.Array:
-    """Attend over the valid prefix of a layer's cache, which already holds
-    this token's entries at ``length``."""
+def gqa_decode_attend(params: dict, q: jax.Array, stack: dict,
+                      rep: jax.Array, length: jax.Array, cfg) -> jax.Array:
+    """Attend over the valid prefix of layer ``rep``'s cache in the
+    segment's stacked cache ``stack``, which already holds this token's
+    entries at ``length``."""
     B = q.shape[0]
+    cache = layers.layer_view(stack, rep)
     lengths = jnp.full((B,), length + 1, jnp.int32)
     if cfg.kv_cache_dtype == "int8":
         out = _decode_chunked(q[:, :, 0], cache["k"], cache["v"], lengths,
@@ -450,20 +452,41 @@ def mla_decode_entries(params: dict, x: jax.Array, length: jax.Array,
     return (q_nope, q_pe), {"c_kv": c_kv, "k_pe": k_pe.transpose(0, 2, 1)}
 
 
-def mla_decode_attend(params: dict, q: tuple, cache: dict,
+def mla_decode_path() -> str:
+    """The absorbed MLA decode's attention on the default backend:
+    ``"pallas"`` (:func:`repro.kernels.ops.mla_decode`, one pass over the
+    live blocks of the stacked latent cache) where the kernel compiles
+    natively, on a TPU; ``"xla"`` (two einsums over a layer's whole cache)
+    elsewhere, so a CPU never interprets a kernel inside every layer."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def mla_decode_blocks(length: int, max_len: int) -> tuple[int, int]:
+    """``(read, total)``: the 128-position blocks of one row's latent cache
+    that a layer's absorbed decode at ``length`` reads on this backend's
+    path (the kernel reads positions ``0 .. length``, the XLA path all of
+    them), and the blocks the cache has."""
+    total = -(-max_len // kops.MLA_BLOCK)
+    if mla_decode_path() == "pallas":
+        return length // kops.MLA_BLOCK + 1, total
+    return total, total
+
+
+def mla_decode_attend(params: dict, q: tuple, stack: dict, rep: jax.Array,
                       length: jax.Array, cfg) -> jax.Array:
     q_nope, q_pe = q
     if getattr(cfg, "mla_absorbed", False):
-        return _mla_decode_absorbed(params, q_nope, q_pe, cache["c_kv"],
-                                    cache["k_pe"], length, cfg)
+        return _mla_decode_absorbed(params, q_nope, q_pe, stack, rep,
+                                    length, cfg)
     # naive: decompress the whole cache and attend (baseline)
+    cache = layers.layer_view(stack, rep)
     S = cache["c_kv"].shape[1]
     return _mla_attend(params, q_nope, q_pe, cache["c_kv"],
                        cache["k_pe"][:, :, :S].transpose(0, 2, 1), cfg,
                        causal=True, q_offset=length)
 
 
-def _mla_decode_absorbed(params, q_nope, q_pe, c_cache, p_cache, length,
+def _mla_decode_absorbed(params, q_nope, q_pe, stack, rep, length,
                          cfg) -> jax.Array:
     """Absorbed MLA decode (DeepSeek-V2 §Inference): fold W_uk into the
     query and W_uv into the output so attention runs directly in the
@@ -471,11 +494,11 @@ def _mla_decode_absorbed(params, q_nope, q_pe, c_cache, p_cache, length,
     (S·H·(nope+v) GEMM + its S·H·192 materialization) disappears.
 
     Beyond-paper §Perf optimization; numerically identical to the naive
-    path (same linear algebra, reassociated).
+    path (same linear algebra, reassociated).  The attention over layer
+    ``rep`` of the stacked latent cache runs on :func:`mla_decode_path`.
     """
     m = cfg.mla
     B, _, H, _ = q_nope.shape
-    S = c_cache.shape[1]
     r = m.kv_lora_rank
     w_ukv = params["w_ukv"].reshape(r, H, m.qk_nope_head_dim + m.v_head_dim)
     w_uk = w_ukv[:, :, :m.qk_nope_head_dim]          # (r, H, nope)
@@ -484,17 +507,31 @@ def _mla_decode_absorbed(params, q_nope, q_pe, c_cache, p_cache, length,
     # absorb: q_lat (B, H, r) = q_nope · W_uk^T
     q_lat = jnp.einsum("bqhn,rhn->bhr", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32))
+    scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if mla_decode_path() == "pallas":
+        o_lat = kops.mla_decode(q_lat, q_pe[:, 0], stack["c_kv"],
+                                stack["k_pe"], rep, length, scale=scale)
+    else:
+        cache = layers.layer_view(stack, rep)
+        o_lat = _latent_attention(q_lat, q_pe, cache["c_kv"],
+                                  cache["k_pe"], length, scale)
+    out = jnp.einsum("bhr,rhv->bhv", o_lat,
+                     w_uv.astype(jnp.float32))       # (B, H, v)
+    out = out.reshape(B, 1, H * m.v_head_dim).astype(q_nope.dtype)
+    return out @ params["w_o"]
+
+
+def _latent_attention(q_lat, q_pe, c_cache, p_cache, length, scale):
+    """``o_lat`` (B, H, r) of one layer's latent cache in XLA: the scores
+    over the whole cache, masked past ``length``, then their softmax
+    applied to the whole cache."""
+    S = c_cache.shape[1]
     cf = c_cache.astype(jnp.float32)                 # (B, S, r)
     pf = p_cache.astype(jnp.float32)                 # (B, rope, S')
-    scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
     logits = (jnp.einsum("bhr,bsr->bhs", q_lat, cf)
               + jnp.einsum("bqhp,bps->bhs", q_pe.astype(jnp.float32),
                            pf)[..., :S]) * scale
     mask = jnp.arange(S)[None, None, :] <= length
     logits = jnp.where(mask, logits, -1e30)
     w = jax.nn.softmax(logits, axis=-1)              # (B, H, S)
-    o_lat = jnp.einsum("bhs,bsr->bhr", w, cf)        # (B, H, r)
-    out = jnp.einsum("bhr,rhv->bhv", o_lat,
-                     w_uv.astype(jnp.float32))       # (B, H, v)
-    out = out.reshape(B, 1, H * m.v_head_dim).astype(q_nope.dtype)
-    return out @ params["w_o"]
+    return jnp.einsum("bhs,bsr->bhr", w, cf)         # (B, H, r)

@@ -136,3 +136,14 @@ def unembed_apply(params: dict, x: jax.Array) -> jax.Array:
     """Logits in fp32 (loss numerics)."""
     return jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
                       params["table"].astype(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# Stacked layers
+# ---------------------------------------------------------------------------
+
+def layer_view(stack, rep: jax.Array):
+    """One repeat's slice of a stacked (repeats-first) subtree."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, rep, keepdims=False),
+        stack)

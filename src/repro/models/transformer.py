@@ -134,13 +134,6 @@ _ATTN_DECODE = {
 _STATE_DECODE = {"mamba": ssm.mamba_decode, "rwkv": ssm.rwkv6_decode}
 
 
-def _layer_view(stack: Any, rep: jax.Array) -> Any:
-    """One repeat's slice of a stacked (repeats-first) cache subtree."""
-    return jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, rep, keepdims=False),
-        stack)
-
-
 # Rows written around this token's row: a window of one row along the
 # sequence axis makes the TPU compiler lay the whole carried cache out
 # sequence-major, and so copy it in and out of the layer scan every step.
@@ -229,17 +222,18 @@ def _layer_decode(params: dict, x: jax.Array, stack: dict, rep: jax.Array,
         entries_fn, attend_fn = _ATTN_DECODE[spec.mixer]
         q, entries = entries_fn(params["mixer"], h1, length, cfg)
         new_stack["mixer"] = _store(stack["mixer"], entries, rep, length)
-        mix = attend_fn(params["mixer"], q,
-                        _layer_view(new_stack["mixer"], rep), length, cfg)
+        mix = attend_fn(params["mixer"], q, new_stack["mixer"], rep,
+                        length, cfg)
     elif spec.mixer in _STATE_DECODE:
         mix, state = _STATE_DECODE[spec.mixer](
-            params["mixer"], h1, _layer_view(stack["mixer"], rep), cfg)
+            params["mixer"], h1, layers.layer_view(stack["mixer"], rep),
+            cfg)
         new_stack["mixer"] = _store(stack["mixer"], state, rep, length)
     else:
         raise ValueError(spec.mixer)
 
     def cmix(h):
-        prev = _layer_view(stack["cmix_prev"], rep)
+        prev = layers.layer_view(stack["cmix_prev"], rep)
         new_stack["cmix_prev"] = _store(stack["cmix_prev"], h, rep, length)
         return _cmix_apply(params["mlp"], h, prev=prev)
 

@@ -309,3 +309,73 @@ def test_grouped_matmul_sweep(case, monkeypatch):
         out = got[first[e]:first[e] + sizes[e]]
         np.testing.assert_allclose(out, want, rtol=rtol, atol=atol)
         np.testing.assert_allclose(out, ragged[rows], rtol=rtol, atol=atol)
+
+
+# ---------------------------------------------------------------------------
+# absorbed-MLA decode (one pass over the live blocks of a stacked latent cache)
+# ---------------------------------------------------------------------------
+
+#: (query heads, repeats in the stack, repeat, length, cache dtype, NaN in
+#: every dead position of the layer's cache)
+_MLA_CASES = {
+    "length-0": (16, 3, 0, 0, jnp.float32, False),
+    "length-127": (16, 3, 1, 127, jnp.float32, False),
+    "length-128": (16, 3, 1, 128, jnp.float32, False),
+    "length-767": (16, 3, 2, 767, jnp.float32, False),
+    "length-839-partial-block": (16, 3, 1, 839, jnp.float32, False),
+    "one-repeat": (16, 1, 0, 500, jnp.float32, False),
+    "heads-128": (128, 3, 1, 767, jnp.float32, False),
+    "bf16": (16, 3, 1, 600, jnp.bfloat16, False),
+    "nan-dead": (16, 3, 1, 300, jnp.float32, True),
+}
+#: VMEM of a case, small enough for two tiles of 2 batch rows
+_MLA_VMEM = {"length-767": 2 * 2 ** 20, "nan-dead": 2 * 2 ** 20}
+
+
+@pytest.mark.parametrize("case", list(_MLA_CASES))
+def test_mla_decode_sweep(case, monkeypatch):
+    """``_mla_decode_absorbed`` with its latent attention on the kernel
+    against its XLA path, over one repeat of a stack of Moonlight-wide
+    latent caches (r 512, rope 64) of 840 positions, a partial last block:
+    equal within float32 rounding, or within the rounding of the queries
+    and weights to a bf16 cache's dtype; in one or two batch tiles.  With
+    NaN in every position past ``length`` of the layer's cache, the
+    kernel's output is finite and the same."""
+    import dataclasses
+
+    import jax
+    from repro.configs import load_config
+    from repro.configs.base import MLAConfig
+    from repro.kernels import ops
+    from repro.models import attention
+    H, R, rep, length, dtype, nan = _MLA_CASES[case]
+    if case in _MLA_VMEM:
+        monkeypatch.setattr(ops, "_MLA_VMEM", _MLA_VMEM[case])
+    B, S, r, rope = 4, 840, 512, 64
+    assert (ops.mla_decode_block_rows(B, H, r, rope, 4) < B) == (
+        case in _MLA_VMEM)
+    cfg = dataclasses.replace(
+        load_config("moonlight-16b-a3b"), d_model=64, num_heads=H,
+        dtype="float32", mla=MLAConfig(
+            q_lora_rank=0, kv_lora_rank=r, qk_nope_head_dim=32,
+            qk_rope_head_dim=rope, v_head_dim=32))
+    params = attention.mla_init(jax.random.PRNGKey(len(case)), cfg)
+    q = (_rand((B, 1, H, 32), jnp.float32), _rand((B, 1, H, rope),
+                                                  jnp.float32))
+    stack = {"c_kv": _rand((R, B, S, r), dtype),
+             "k_pe": _rand((R, B, rope, 896), dtype)}
+
+    def attend(path, stack):
+        monkeypatch.setattr(attention, "mla_decode_path", lambda: path)
+        return np.asarray(attention.mla_decode_attend(
+            params, q, stack, jnp.int32(rep), jnp.int32(length), cfg))
+
+    want, got = attend("xla", stack), attend("pallas", stack)
+    tol = (1e-5 if dtype == jnp.float32 else 2e-2) * np.abs(want).max()
+    np.testing.assert_allclose(got, want, rtol=0, atol=tol)
+    if nan:
+        dead = {"c_kv": stack["c_kv"].at[rep, :, length + 1:].set(np.nan),
+                "k_pe": stack["k_pe"].at[rep, :, :, length + 1:].set(np.nan)}
+        poisoned = attend("pallas", dead)
+        assert np.isfinite(poisoned).all()
+        np.testing.assert_array_equal(poisoned, got)

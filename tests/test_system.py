@@ -284,6 +284,52 @@ def test_serve_counts_grouped_dispatches(path, monkeypatch):
     assert got == want
 
 
+@pytest.mark.parametrize("path", ["platform", "pallas", "gqa"])
+def test_serve_counts_mla_decode(path, monkeypatch):
+    """The server counts each absorbed-MLA layer's decode steps on the
+    latent attention's path: MLA layers x decode steps (here 3 x 5), and 0
+    on the other path, with the 128-position blocks the steps read no more
+    than the blocks the caches hold.  The path is the platform's (``xla``
+    on a CPU) unless the test forces the kernel, which then serves the
+    same tokens and reads only the live blocks.  A model without MLA
+    counts none of these."""
+    from repro import trace
+    from repro.configs import load_config, reduced
+    from repro.launch.serve import BatchedServer, Request
+    from repro.models import attention, init_params
+
+    arch = "smollm-135m" if path == "gqa" else "moonlight-16b-a3b"
+    cfg = reduced(load_config(arch))          # Moonlight: 3 MLA layers
+    params = init_params(jax.random.PRNGKey(4), cfg)
+    rng = np.random.default_rng(4)
+    reqs = [Request(i, rng.integers(0, cfg.vocab_size, size=(130,))
+                    .astype(np.int32), g) for i, g in enumerate([3, 5])]
+
+    def serve():
+        trace.reset()
+        tokens = [r.tokens for r in BatchedServer(cfg, params,
+                                                  max_len=320).serve(reqs)]
+        return tokens, trace.counts()
+
+    want, c = serve()
+    if path == "gqa":
+        assert not [k for k in c if k.startswith("attn.mla_decode")]
+        return
+    if path == "pallas":
+        monkeypatch.setattr(attention, "mla_decode_path", lambda: "pallas")
+        got, c = serve()
+        assert got == want
+    used = attention.mla_decode_path()
+    other, = {"pallas", "xla"} - {used}
+    assert used == ("xla" if path == "platform" else "pallas")
+    assert c[f"attn.mla_decode.{used}"] == 3 * 5
+    assert c[f"attn.mla_decode.{other}"] == 0
+    # 2 rows x 3 layers x 5 steps at lengths 130-134: blocks 0-1 of 3
+    assert c["attn.mla_decode.blocks_total"] == 2 * 3 * 5 * 3
+    live = 2 if path == "pallas" else 3
+    assert c["attn.mla_decode.blocks_live"] == 2 * 3 * 5 * live
+
+
 def test_serve_prefill_matches_forward():
     """``BatchedServer.prefill`` returns the model's last-position logits
     for the batch, as the plain prefill forward gives them."""

@@ -280,3 +280,45 @@ def test_moonlight_decode_step_runs_grouped_kernel(one_chip, monkeypatch):
     leaves = jax.tree_util.tree_leaves(cache)
     assert compiled.memory_analysis().alias_size_in_bytes == sum(
         a.size * a.dtype.itemsize for a in leaves)
+
+
+def test_moonlight_decode_step_runs_mla_kernel(one_chip, monkeypatch):
+    """Moonlight's decode step on the path a TPU takes (steered as above):
+    each segment's layer runs the absorbed attention as one ``mla_decode``
+    custom call over the segment's stacked latent cache (the 26-repeat
+    segment's in its scan body; the one-repeat scan is unrolled), so no
+    instruction, fused or not, is shaped with the cache's positions but
+    the whole stacked leaves themselves: no einsum over a layer's whole
+    cache and no copy of a layer's ``c_kv`` or ``k_pe``; every cache leaf
+    is still aliased."""
+    from repro.kernels import ops
+    from repro.models import attention, moe
+    monkeypatch.setattr(moe, "grouped_path", lambda: "pallas")
+    monkeypatch.setattr(attention, "mla_decode_path", lambda: "pallas")
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = _decode_config("moonlight")
+    compiled, cache = _served_decode_step(one_chip, cfg, 128, 840)
+    hlo = compiled.as_text()
+
+    calls, comp = [], None
+    for line in hlo.splitlines():
+        if line and not line.startswith(" "):
+            comp = line.split(" ", 1)[0]
+        elif re.search(r"custom_call_target=\"tpu_custom_call\"[^\n]*"
+                       r"mla_decode", line):
+            calls.append(comp)
+    bodies = set(re.findall(r"while\([^\n]*?body=(%[\w.\-]+)", hlo))
+    assert len(calls) == len(set(calls)) == len(cfg.segments)
+    assert len(set(calls) & bodies) == 1
+
+    leaves = jax.tree_util.tree_leaves(cache)
+    stacked = {",".join(map(str, a.shape)) for a in leaves}
+    positions = {str(d) for a in leaves for d in a.shape[2:]} - {
+        str(cfg.mla.kv_lora_rank), str(cfg.mla.qk_rope_head_dim)}
+    shaped = {m.group(1) for m in re.finditer(r"= \w+\[([\d,]*)\]", hlo)
+              if positions & set(m.group(1).split(","))}
+    assert shaped <= stacked, shaped - stacked
+    layer = {",".join(map(str, a.shape[1:])) for a in leaves}
+    assert [(op, d) for op, d in _hbm_buffers(hlo) if d in layer] == []
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in leaves)
